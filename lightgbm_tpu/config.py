@@ -305,11 +305,7 @@ _PARAMS: Dict[str, Tuple[str, Any, Tuple[str, ...], Optional[Tuple[float, float]
     # buffers are DELETED at dispatch — a stale Python reference read
     # after the call is a bug; tpu_debug_checks names the donating
     # site, and the donation-discipline linter (tools/analyze) flags
-    # the static shape of that mistake. Known-bad combo, refused with
-    # a warning: "true" on a non-TPU backend while a persistent
-    # compilation cache is configured — this jaxlib's CPU client
-    # corrupts the heap executing donating executables reloaded from
-    # the cache (docs/perf.md "Iteration floor").
+    # the static shape of that mistake.
     "tpu_donate": _P("str", "auto"),
     "tpu_mesh_shape": _P("str", ""),
     "tpu_double_precision_hist": _P("bool", False),
@@ -891,54 +887,34 @@ def coerce_tristate(value: Any, name: str = "parameter") -> str:
     return v
 
 
-# the one directory the persistent compile cache is pointed at; set-once
-# per process (jax's cache is a process-global — flipping it mid-run
-# would silently split the cache)
-_COMPILE_CACHE_DIR: Optional[str] = None
-
-
 def setup_compile_cache(path) -> None:
     """Point jax's persistent compilation cache at ``path`` (the
     ``tpu_compile_cache_dir`` warm-start knob): a second same-shape run
     in a fresh process reloads every XLA program from disk instead of
     recompiling, collapsing cold-start ``engine_init_s`` /
-    first-iteration compile time. Idempotent; an empty path is a no-op;
-    a second DIFFERENT path warns and keeps the first (the cache dir is
-    process-global in jax)."""
-    global _COMPILE_CACHE_DIR
+    first-iteration compile time. An empty path is a no-op. The cache
+    dir is process-global in jax and part of every entry's key, so a
+    cache that is already live stays where it is: one named by
+    ``JAX_COMPILATION_CACHE_DIR`` (jax reads that itself) or by an
+    earlier call wins, and a different ``path`` warns and is ignored."""
     path = str(path or "").strip()
     if not path:
         return
-    if _COMPILE_CACHE_DIR is not None:
-        if _COMPILE_CACHE_DIR != path:
+    import jax
+    live = jax.config.jax_compilation_cache_dir
+    if live:
+        if live != path:
             log.warning(
                 f"tpu_compile_cache_dir={path!r} ignored: the persistent "
-                f"compile cache is already at {_COMPILE_CACHE_DIR!r} "
-                f"(process-global; restart to move it)")
+                f"compile cache is already at {live!r} (process-global: "
+                f"JAX_COMPILATION_CACHE_DIR or the first path set wins)")
         return
-    import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-    except Exception as e:    # older jax without this config name
-        log.warning(f"tpu_compile_cache_dir: persistent compilation "
-                    f"cache unavailable on this jax ({e})")
-        return
-    # the cache is LIVE from here: record it before the optional tuning
-    # below, so a partial failure can never leave an active cache that
-    # a later different path would silently re-point
-    _COMPILE_CACHE_DIR = path
-    try:
-        # cache even quick compiles: the warm-start contract is "second
-        # run compiles nothing", not "second run compiles only the big
-        # ones" — and entry write cost is trivial next to any compile
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-    except Exception as e:    # tuning knobs absent: cache still works
-        log.warning(f"tpu_compile_cache_dir: cache enabled but "
-                    f"min-compile-time/entry-size tuning unavailable "
-                    f"({e}); small programs may not be cached")
+    jax.config.update("jax_compilation_cache_dir", path)
+    # cache even quick compiles: the warm-start contract is "second
+    # run compiles nothing", not "second run compiles only the big
+    # ones" — and entry write cost is trivial next to any compile
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 def parse_config_file(path: str) -> Dict[str, str]:

@@ -15,6 +15,8 @@ import subprocess
 import tempfile
 from typing import Optional
 
+from ..utils import log
+
 _CACHED: dict = {}
 
 
@@ -47,8 +49,16 @@ def load_native(name: str = "text_parser.cpp",
                 check=True, capture_output=True, timeout=120)
             os.replace(tmp, so)
         lib = ctypes.CDLL(so)
-    except Exception:       # no g++ / sandboxed tmp / bad toolchain
-        lib = None
+    except (OSError, subprocess.SubprocessError) as e:
+        # no g++ / sandboxed tmp / bad toolchain: the Python fallback
+        # is this module's contract, but it is many times slower, so
+        # the failure is said once (``_CACHED`` keeps the None) with
+        # what the compiler wrote
+        stderr = getattr(e, "stderr", None) or b""
+        log.warning(
+            f"native {' '.join((name, *extra_flags))} unavailable "
+            f"({type(e).__name__}: {e}); using the Python fallback. "
+            f"{stderr.decode(errors='replace')[-2000:]}".rstrip())
     _CACHED[key] = lib
     return lib
 

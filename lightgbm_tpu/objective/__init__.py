@@ -60,6 +60,26 @@ class Objective:
 
     # -- multi-host BoostFromAverage sync (the reference's
     # Network::GlobalSyncUpByMean; SURVEY.md §2.3) ----------------------
+    def init_score_all_processes(self, label, weight) -> float:
+        """``init_score`` over EVERY process's rows, for engines whose
+        processes each hold only a row shard: the mean statistic is
+        gathered (bit-exact float64, so a gang starts where one process
+        over the same rows would) and every rank gets the same score.
+        Percentile-based init scores cannot be synced this way; they
+        warn and use the local shard."""
+        stats = self.init_mean_stats(label, weight)
+        if stats is None:
+            from ..utils import log
+            log.warning(
+                "boost_from_average for this objective is a percentile "
+                "statistic that cannot be synced across hosts; using "
+                "this process's local shard only")
+            return self.init_score(label, weight)
+        from ..parallel.multihost import allgather_float64
+        tot = allgather_float64(np.asarray(stats, np.float64))
+        return self.init_from_mean(
+            float(tot[:, 0].sum()) / max(float(tot[:, 1].sum()), 1e-30))
+
     def init_mean_stats(self, label, weight):
         """``(weighted_sum, weight_total)`` such that
         ``init_from_mean(weighted_sum / weight_total)`` reproduces

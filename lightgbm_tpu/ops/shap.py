@@ -509,8 +509,8 @@ def sharded_scan_kernel(mesh, D, U, NN, n_feat, K, dtype):
     fn = _SHARDED_SCAN.get(key)
     if fn is None:
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
+        from ..parallel import mesh as mesh_lib
         from ..serve.shard import TREE_AXIS
 
         body = _scan_body(D, U, NN, n_feat, K, dtype)
@@ -520,8 +520,15 @@ def sharded_scan_kernel(mesh, D, U, NN, n_feat, K, dtype):
 
         def run(stacked):
             specs = {k: PartitionSpec(TREE_AXIS) for k in stacked}
-            return shard_map(local, mesh=mesh, in_specs=(specs,),
-                             out_specs=PartitionSpec())(stacked)
+            # check_vma=False, as at every other shard_map site of
+            # the package: the scan's carry starts as zeros (the same
+            # on every shard) and leaves the body varying over
+            # TREE_AXIS, which the varying-manual-axes check refuses;
+            # the psum in ``local`` is what makes the output
+            # replicated, and the check cannot see through the scan
+            return mesh_lib.shard_map(
+                local, mesh=mesh, in_specs=(specs,),
+                out_specs=PartitionSpec(), check_vma=False)(stacked)
 
         fn = jax.jit(run)
         _SHARDED_SCAN[key] = fn
@@ -556,12 +563,7 @@ def forest_shap_batch(trees, X, n_feat, K=1, row_chunk=131072,
     import contextlib
     ctx = contextlib.ExitStack()
     if force_f64:
-        # jax.enable_x64 only exists on newer jax; the pinned runtime
-        # ships it under jax.experimental
-        x64_ctx = getattr(jax, "enable_x64", None)
-        if x64_ctx is None:
-            from jax.experimental import enable_x64 as x64_ctx
-        ctx.enter_context(x64_ctx())
+        ctx.enter_context(jax.enable_x64(True))
         if jax.default_backend() != "cpu":
             ctx.enter_context(
                 jax.default_device(jax.devices("cpu")[0]))

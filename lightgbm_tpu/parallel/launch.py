@@ -223,6 +223,7 @@ def sync_bin_mappers(X_local: np.ndarray, params: Dict,
     from jax.experimental import multihost_utils
 
     from ..io.binning import mappers_from_params
+    from .multihost import allgather_float64
 
     p = params
     total_cnt = int(p.get("bin_construct_sample_cnt", 200000))
@@ -246,8 +247,7 @@ def sync_bin_mappers(X_local: np.ndarray, params: Dict,
     slot = max(1, int(g_cnt.max()))
     samp = np.full((slot, F), np.nan, np.float64)
     samp[:k] = np.asarray(X_local, np.float64)[idx]
-    g_samp = np.asarray(multihost_utils.process_allgather(samp)) \
-        .reshape(nproc, slot, F)
+    g_samp = allgather_float64(samp)           # [nproc, slot, F]
     union = np.concatenate([g_samp[r, :g_cnt[r]] for r in range(nproc)])
     # total_sample_cnt semantics: the union IS the sample; sparse
     # implicit-zero accounting applies within it only
@@ -489,8 +489,9 @@ def train_distributed(params: Dict,
         default; on real multi-host hardware run one process per host
         yourself via :func:`run_worker` instead).
       platform: force a JAX platform in the workers ("cpu" default —
-        this environment exposes one TPU chip, which cannot be shared
-        by N processes; pass None on a real pod).
+        a TPU chip belongs to one process and cannot be shared by N
+        local workers; pass None on a real pod, one process per host,
+        from a parent that has not touched jax).
       timeout: seconds to wait for the workers (per attempt).
       max_restarts: automatic gang restarts after a worker death or
         timeout. Each restart terminates the gang, waits an

@@ -22,27 +22,10 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # public API location varies across JAX versions
-    _shard_map_impl = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import \
-        shard_map as _shard_map_impl  # type: ignore
 
-import inspect as _inspect
-
-_SHARD_MAP_PARAMS = frozenset(
-    _inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f, **kwargs):
-    """shard_map with the replication-check kwarg normalized: newer JAX
-    renamed ``check_rep`` -> ``check_vma``; accept either spelling and
-    translate to whatever the installed runtime supports."""
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map_impl(f, **kwargs)
+# the one spelling every shard_map call site of the package goes
+# through (tests patch it here to see which programs are sharded)
+shard_map = jax.shard_map
 
 DATA_AXIS = "data"
 FEATURE_AXIS = "feature"

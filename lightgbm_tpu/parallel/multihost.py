@@ -123,6 +123,21 @@ def init_multihost(coordinator_address: Optional[str] = None,
              f"{jax.local_device_count()} local devices")
 
 
+def allgather_float64(arr):
+    """``process_allgather`` of a float64 array, bit for bit:
+    ``[process_count, *arr.shape]``. The gather goes through jax, which
+    without x64 would round float64 to float32 — a gang would then
+    differ from one process in the last digits of whatever it derives
+    from the gathered values (bin boundaries, the boost-from-average
+    score). The values travel as their raw 32-bit words instead."""
+    import numpy as np
+    from jax.experimental import multihost_utils
+    a = np.atleast_1d(np.ascontiguousarray(arr, dtype=np.float64))
+    words = multihost_utils.process_allgather(a.view(np.uint32))
+    return np.ascontiguousarray(words).view(np.float64).reshape(
+        (-1,) + a.shape)
+
+
 def is_multihost() -> bool:
     """NB: initializes the local backend if nothing has yet — only call
     AFTER init_multihost (or in single-process jobs)."""

@@ -71,17 +71,12 @@ class CompileWatch:
         return self
 
     def __exit__(self, *exc) -> None:
-        # stop counting FIRST: even if the unregister below fails, the
-        # listener goes inert rather than polluting later watches — and
+        # stop counting FIRST, then take only OUR listener off —
         # never clear_event_listeners(), which would wipe listeners we
         # do not own
         self._active = False
-        try:
-            # unregister lives in jax._src.monitoring on the pinned jax
-            from jax._src import monitoring as _m
-            _m._unregister_event_listener_by_callback(self._listener)
-        except Exception:
-            pass
+        from jax import monitoring
+        monitoring.unregister_event_listener(self._listener)
 
 
 def donation_enabled(config) -> bool:
@@ -91,41 +86,17 @@ def donation_enabled(config) -> bool:
     the boosting carries in place instead of copying them through
     every dispatch (docs/perf.md "Iteration floor"). "auto" donates on
     the TPU backend only — the profiled ``%copy`` waste lives there
-    and CPU tier-1 runs keep today's copy semantics; "true" forces it
-    on any backend (this jaxlib's CPU client honors donation, which is
-    what makes the donation-on/off bit-identity tests real); "false"
-    disables it everywhere (the ``bench.py --no-donate`` A/B arm).
-
-    KNOWN-BAD COMBINATION, forced off with a warning: a non-TPU
-    backend with a persistent compilation cache configured. This
-    jaxlib's (0.4.37) CPU client intermittently corrupts the heap
-    executing a donating executable DESERIALIZED from the cache —
-    segfaults/aborts detonating later in unrelated native code.
-    Reproduced: donating train runs pass 100% against a cold cache and
-    crash most multi-train processes against a warm one; donation off
-    or cache off are each individually stable. TPU PJRT keeps both
-    (donation + persistent cache is the standard accelerator
-    combination upstream)."""
+    and CPU tier-1 runs keep copy semantics; "true" forces it on any
+    backend (the CPU client honors donation, which is what makes the
+    donation-on/off bit-identity tests real); "false" disables it
+    everywhere (the ``bench.py --no-donate`` A/B arm). Donation and
+    the persistent compilation cache combine freely on both backends
+    (docs/perf.md "Iteration floor" has the check that showed it)."""
     v = str(getattr(config, "tpu_donate", "auto"))
-    if v == "false":
-        return False
+    if v != "auto":
+        return v == "true"
     import jax
-    if jax.default_backend() == "tpu":
-        return True                           # auto and true alike
-    if v != "true":
-        return False
-    if getattr(jax.config, "jax_compilation_cache_dir", None):
-        from . import log
-        log.warning(
-            "tpu_donate=true ignored: this backend "
-            f"({jax.default_backend()}) intermittently crashes "
-            "executing donating executables reloaded from the "
-            "persistent compilation cache "
-            f"({jax.config.jax_compilation_cache_dir}); unset the "
-            "cache (jax_compilation_cache_dir) to force donation "
-            "off-TPU — docs/perf.md 'Iteration floor'")
-        return False
-    return True
+    return jax.default_backend() == "tpu"
 
 
 def donation_guard(fn, site: str):

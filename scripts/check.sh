@@ -56,7 +56,7 @@ python bench.py --rows 300000 --iters 5 --smoke --metrics-json "$OBS_JSON"
 # line below so scripts/obs_trend.py watches it run-over-run
 STREAM_DRYRUN=1
 XLA_FLAGS="--xla_force_host_platform_device_count=2 ${XLA_FLAGS:-}" \
-JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-/tmp/lightgbm_tpu_jax_cache}" \
+JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.jax_cache}" \
 python -c "import __graft_entry__ as g; g.dryrun_multichip(2, only=('streaming',))" \
   || STREAM_DRYRUN=0
 
@@ -73,7 +73,7 @@ python -c "import __graft_entry__ as g; g.dryrun_multichip(2, only=('streaming',
 CHAOS_SMOKE=1
 CHAOS_JSON=/tmp/_check_chaos_smoke.log
 rm -f "$CHAOS_JSON"
-JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-/tmp/lightgbm_tpu_jax_cache}" \
+JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.jax_cache}" \
 python benchmarks/chaos_bench.py --smoke 2>&1 | tee "$CHAOS_JSON" \
   || CHAOS_SMOKE=0
 ELASTIC_SMOKE=$(python - "$CHAOS_JSON" elastic_smoke <<'PY'
@@ -121,7 +121,7 @@ PY
 SERVE_SMOKE=1
 SERVE_JSON=/tmp/_check_serve_smoke.log
 rm -f "$SERVE_JSON"
-JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-/tmp/lightgbm_tpu_jax_cache}" \
+JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.jax_cache}" \
 python benchmarks/serve_bench.py --smoke 2>&1 | tee "$SERVE_JSON" \
   || SERVE_SMOKE=0
 # mixed predict+explain leg riding the same smoke (device SHAP through

@@ -1,0 +1,231 @@
+"""Compile the main path's TPU-only programs for a DESCRIBED v5e chip.
+
+The suite pins the CPU, and the program picks its fast paths by
+``jax.default_backend() == "tpu"``: the Pallas histogram and compaction
+kernels, the partition move, the ingest chunk program, the one-hot
+forest traversal. None of that runs here. The TPU compiler is
+installed, though, and compiles for a chip that is described and not
+attached (``/opt/skills/guides/on-chip-measurement`` section 2). These
+tests hand it the real widths — what interpret mode cannot show: tiling,
+VMEM budgets, whether a kernel can sit inside a manual-axes region — so
+every later PR is checked against the chip's compiler at no chip time.
+A compile that passes is not a chip run; ``chip_smoke.py`` is.
+
+Rules this file keeps (the guide says why): the topology is described
+inside a module-scoped, non-autouse fixture and nowhere at import time;
+everything built from it is built in a fixture or a test; all cases
+live in this ONE file (one xdist worker loads libtpu); the persistent
+compilation cache is off around them (a described-chip executable is
+written but cannot be read back without the chip).
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+N = 1 << 20          # Higgs-1M rows
+SLAB = 1 << 17       # one kernel slab
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:   # no libtpu here, or another holds it
+            pytest.skip(f"no v5e:2x2 topology can be described here: "
+                        f"{type(e).__name__}: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Code under trace that asks ``jax.default_backend()`` sees the CPU
+    here and would take its CPU branch (ops/split.py's prefix sum):
+    steer it in the test, never through an option of the program."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(lowered):
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    # one v5e chip has 16 GiB; a program's own temporaries past half of
+    # it would leave no room for the data it works on
+    assert mem.temp_size_in_bytes < 8 * 2**30, mem
+    return compiled.as_text()
+
+
+# ---------------------------------------------------------------------
+# kernels
+# ---------------------------------------------------------------------
+@pytest.mark.parametrize("int_mode", [False, True],
+                         ids=["f32", "int8"])
+@pytest.mark.parametrize("F,B,K", [
+    (28, 256, 32),    # Higgs: single feature block
+    (136, 256, 32),   # MSLR width: feature-blocked grid (F*B > 8192)
+    (28, 64, 32),     # narrow bins
+])
+def test_multi_leaf_histogram_compiles(one_chip, F, B, K, int_mode):
+    from lightgbm_tpu.ops.pallas_histogram import multi_leaf_histogram
+    s = functools.partial(_sds, one_chip)
+    R = 4096 if F * B <= 8192 else 2048      # learner/serial.py's caps
+    text = _compiled_text(multi_leaf_histogram.lower(
+        s((F, SLAB), jnp.int8), s((3, SLAB), jnp.float32),
+        s((SLAB,), jnp.int32), s((K,), jnp.int32),
+        num_bins=B, rows_per_block=R, int_mode=int_mode))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("F,C", [(28, 3), (200, 4)])
+def test_compact_rows_compiles(one_chip, F, C):
+    from lightgbm_tpu.ops.compact import (compact_rows,
+                                          compaction_out_cols)
+    s = functools.partial(_sds, one_chip)
+    R = 1024
+    out_cols = compaction_out_cols(int(SLAB * 0.3), R, 4096)
+    text = _compiled_text(compact_rows.lower(
+        s((F, SLAB), jnp.int8), s((C, SLAB), jnp.float32),
+        s((SLAB,), jnp.int32), s((SLAB // R,), jnp.int32),
+        s((SLAB // R,), jnp.int32), out_cols=out_cols,
+        rows_per_block=R))
+    assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------
+# the tree grower at Higgs-1M: n=2^20, F=28, B=256, L=127, Kb=32
+# ---------------------------------------------------------------------
+def _grow_cfg(**kw):
+    from lightgbm_tpu.learner.serial import GrowConfig
+    return GrowConfig(num_leaves=127, num_bins=256, rows_per_block=4096,
+                      leaf_batch=32, use_pallas=True, **kw)
+
+
+GROW_VARIANTS = {
+    "plain": {},
+    "int_hist": {"int_hist": True},
+    "partition": {"int_hist": True, "partition": True,
+                  "part_rpb": 1024},
+    "hist_compact": {"int_hist": True, "hist_compact": True},
+}
+
+
+@pytest.mark.parametrize("variant", sorted(GROW_VARIANTS))
+def test_grow_tree_compiles(one_chip, as_tpu, variant):
+    from lightgbm_tpu.learner.serial import grow_tree
+    from lightgbm_tpu.ops.compact import compaction_out_cols
+    s = functools.partial(_sds, one_chip)
+    cfg = _grow_cfg(**GROW_VARIANTS[variant])
+    F = 28
+    vals = s((N, 3), jnp.float32)
+    kw = {"bins_t": s((F, N), jnp.int8)}
+    if cfg.int_hist:
+        kw["chan_scale"] = s((3,), jnp.float32)
+    if cfg.hist_compact:
+        # GOSS at top_rate + other_rate = 0.3 (boosting/gbdt.py)
+        n_c = compaction_out_cols(int(np.ceil(N * 0.3)) + 8192, 1024,
+                                  cfg.rows_per_block)
+        vals = s((n_c, 3), jnp.float32)
+        kw["compact"] = (s((n_c, F), jnp.uint8), s((F, n_c), jnp.int8),
+                         vals)
+    text = _compiled_text(grow_tree.lower(
+        s((N, F), jnp.uint8), vals, s((F,), jnp.int32),
+        s((F,), jnp.bool_), s((F,), jnp.bool_), cfg, **kw))
+    assert "tpu_custom_call" in text
+
+
+def test_grow_tree_compiles_under_shard_map_on_four_chips(topo, as_tpu):
+    """tree_learner=data: the Pallas kernel inside a manual-axes region,
+    rows sharded over a 4-device mesh of described devices, the
+    histogram all-reduce put in by the grower."""
+    from lightgbm_tpu.learner.serial import grow_tree
+    from lightgbm_tpu.parallel.mesh import DATA_AXIS, shard_map
+    mesh = Mesh(np.array(topo.devices).reshape(4), (DATA_AXIS,))
+    cfg = _grow_cfg(int_hist=True, axis_name=DATA_AXIS, num_shards=4)
+    rows, rep = P(DATA_AXIS), P()
+
+    def grow(bins, vals, nb, nan, allowed, bins_t, chan_scale):
+        return grow_tree(bins, vals, nb, nan, allowed, cfg,
+                         bins_t=bins_t, chan_scale=chan_scale)
+
+    def s(spec, shape, dtype):
+        return _sds(NamedSharding(mesh, spec), shape, dtype)
+
+    F = 28
+    fn = jax.jit(shard_map(
+        grow, mesh=mesh,
+        in_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None), rep, rep, rep,
+                  P(None, DATA_AXIS), rep),
+        out_specs=(rep, rows), check_vma=False))
+    text = _compiled_text(fn.lower(
+        s(P(DATA_AXIS, None), (N, F), jnp.uint8),
+        s(P(DATA_AXIS, None), (N, 3), jnp.float32),
+        s(rep, (F,), jnp.int32), s(rep, (F,), jnp.bool_),
+        s(rep, (F,), jnp.bool_), s(P(None, DATA_AXIS), (F, N), jnp.int8),
+        s(rep, (3,), jnp.float32)))
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text
+
+
+# ---------------------------------------------------------------------
+# ingest and serving
+# ---------------------------------------------------------------------
+def test_ingest_chunk_program_compiles(one_chip):
+    """One 262,144 x 28 chunk of device bin assignment, both layouts."""
+    from lightgbm_tpu.ops.ingest import _assign_chunk_impl
+    s = functools.partial(_sds, one_chip)
+    R, F, B = 262_144, 28, 255
+    fn = jax.jit(_assign_chunk_impl,
+                 static_argnames=("out_dtype", "emit_transposed",
+                                  "any_cat"))
+    text = _compiled_text(fn.lower(
+        s((R, F), jnp.float32), s((F, B), jnp.float32),
+        s((F,), jnp.int32), s((F,), jnp.int32), s((F,), jnp.int32),
+        s((F,), jnp.int32), s((F,), jnp.bool_), s((F, 1), jnp.int32),
+        s((F, 1), jnp.int32), out_dtype=jnp.uint8, emit_transposed=True,
+        any_cat=False))
+    assert "s8[28,262144]" in text.replace(" ", "")   # the bins_t tile
+
+
+def test_onehot_forest_traversal_compiles(one_chip):
+    """100 trees x 127 leaves x 16,384 rows, the TPU formulation
+    (``default_formulation`` picks "gather" on this CPU)."""
+    from lightgbm_tpu.ops.predict import _forest_predict_impl
+    s = functools.partial(_sds, one_chip)
+    T, L, n, F = 100, 127, 16_384, 28
+    Ln = L - 1
+    stacked = {
+        "split_feature": s((T, Ln), jnp.int32),
+        "threshold_bin": s((T, Ln), jnp.int32),
+        "default_left": s((T, Ln), jnp.bool_),
+        "left_child": s((T, Ln), jnp.int32),
+        "right_child": s((T, Ln), jnp.int32),
+        "leaf_value": s((T, L), jnp.float32),
+        "num_leaves": s((T,), jnp.int32),
+    }
+    text = _compiled_text(_forest_predict_impl.lower(
+        stacked, s((n, F), jnp.uint8), s((F,), jnp.int32),
+        s((F,), jnp.bool_), s((T,), jnp.int32), num_class=1,
+        mode="level", formulation="onehot"))
+    assert "while" in text

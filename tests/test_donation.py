@@ -19,24 +19,12 @@ what it is — so the whole pass is pinned by bit-identity:
   LightGBMError naming the donating site (the runtime twin of the
   donation-discipline linter, docs/static-analysis.md).
 
-PROCESS SPLIT (the shape of this file): every donate-TRUE arm runs in
-ONE fresh subprocess (tests/_donation_worker.py — no persistent
-compilation cache, 8 fake CPU devices like conftest) whose artifacts
-come back through a pickle; this process trains only the cache-safe
-donate-FALSE arms and compares. Rationale in the worker's docstring:
-donation + persistent compile cache corrupts this jaxlib's CPU client
-natively, and even toggling the cache config around in-process
-donating dispatches proved crashy — so no donating dispatch ever runs
-in the pytest process. ``donation_enabled`` enforces the same rule for
-production (the stand-down test below).
+Both arms of every A/B run in this process, against the suite's
+persistent compilation cache (conftest.py): donating executables
+reloaded from that cache are part of what is tested.
 """
-import os
-import pathlib
-import pickle
-import subprocess
-import sys
-import tempfile
-
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -44,119 +32,141 @@ import lightgbm_tpu as lgb
 from lightgbm_tpu.boosting.gbdt import GBDT
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.utils.debug import CompileWatch, donation_enabled
+from lightgbm_tpu.utils.log import LightGBMError
 
-from _donation_worker import (MODES, N_ROUNDS, VALID_ROUNDS, VARIANTS,
-                              make_data, params_for)
+N_ROUNDS = 8
+VALID_ROUNDS = 12
 
-_WORKER = str(pathlib.Path(__file__).resolve().parent
-              / "_donation_worker.py")
+# learning_rate 0.5 -> GOSS activates at iteration 2 of 8, so the GOSS
+# variants exercise BOTH the plain and the sampled step under donation
+VARIANTS = {
+    "plain": {},
+    "goss": {"data_sample_strategy": "goss", "learning_rate": 0.5,
+             "top_rate": 0.3, "other_rate": 0.3},
+    "quantized": {"use_quantized_grad": True},
+}
 
-
-@pytest.fixture(scope="module")
-def donated(tmp_path_factory):
-    """Artifacts of every donate-true arm, from ONE clean worker run."""
-    out = tmp_path_factory.mktemp("donation") / "worker.pkl"
-    env = dict(os.environ)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)  # the unsafe combination
-    flags = env.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-    proc = subprocess.run(
-        [sys.executable, _WORKER, str(out)],
-        capture_output=True, text=True, env=env, timeout=560)
-    assert proc.returncode == 0, (
-        f"donation worker failed (rc={proc.returncode}) — a crash here "
-        f"is the donated-dispatch instability this split exists to "
-        f"contain:\n{proc.stderr[-3000:]}")
-    with open(out, "rb") as f:
-        return pickle.load(f)
+MODES = {
+    "per_iter": {"tpu_fuse_iters": 1},
+    "fused_chunk": {"tpu_fuse_iters": 4},
+    "sharded": {"tree_learner": "data"},
+    "streamed": {"tpu_streaming": "true",
+                 "tpu_stream_block_rows": 1024},
+}
 
 
-def test_worker_ran_with_donation_live(donated):
-    """The A/B is only real if the worker actually donated: the config
-    resolved to enabled and the client deleted a donated input."""
-    assert donated["donation_enabled_true"]
-    assert donated["probe_input_deleted"]
+def make_data(n=2048, f=8, seed=0):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, f))
+    w = rng.normal(size=f)
+    y = ((X @ w + 0.6 * X[:, 0] * X[:, 1]
+          + rng.normal(scale=0.5, size=n)) > 0).astype(np.float64)
+    return X, y
 
 
-def test_true_stands_down_under_persistent_cache_off_tpu():
-    """The known-bad combo is refused, not crashed on: forcing
-    donation on a non-TPU backend while a persistent compilation cache
-    is configured (as it is for this very test suite, via conftest)
-    warns and stays off — which is why the donate-true arms live in
-    the cache-less worker subprocess."""
-    import jax
+def params_for(extra, donate):
+    return {"objective": "binary", "num_leaves": 15, "verbosity": -1,
+            "min_data_in_leaf": 5, "tpu_donate": donate, **extra}
+
+
+def test_donation_is_live_off_tpu():
+    """The A/B is only real if the donate-true arm actually donates:
+    the config resolves to enabled and the CPU client deletes a
+    donated input at dispatch."""
+    assert donation_enabled(Config(params_for({}, "true")))
+    probe = jax.jit(lambda x: x + 1, donate_argnums=(0,))
+    a = jnp.ones((8, 8))
+    probe(a)
+    assert a.is_deleted()
+
+
+def test_tristate_resolution_with_persistent_cache():
+    """"true" donates on any backend with the persistent compilation
+    cache configured (as it is for this suite, via conftest); "auto"
+    stays off away from the TPU; "false" is off everywhere."""
     assert jax.default_backend() != "tpu"
     assert jax.config.jax_compilation_cache_dir  # conftest set it
-    cfg = Config({"objective": "binary", "tpu_donate": "true",
-                  "verbosity": -1})
-    assert not donation_enabled(cfg)
-    cfg_off = Config({"objective": "binary", "tpu_donate": "false",
-                      "verbosity": -1})
-    assert not donation_enabled(cfg_off)
+    assert donation_enabled(Config(params_for({}, "true")))
+    assert not donation_enabled(Config(params_for({}, "auto")))
+    assert not donation_enabled(Config(params_for({}, "false")))
 
 
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
 @pytest.mark.parametrize("mode", sorted(MODES))
-def test_bit_identical_donation_on_off(donated, mode, variant):
+def test_bit_identical_donation_on_off(mode, variant):
     X, y = make_data()
-    p = params_for({**MODES[mode], **VARIANTS[variant]}, "false")
-    m_off = lgb.train(p, lgb.Dataset(X, label=y),
+    out = {}
+    for donate in ("true", "false"):
+        p = params_for({**MODES[mode], **VARIANTS[variant]}, donate)
+        m = lgb.train(p, lgb.Dataset(X, label=y),
                       num_boost_round=N_ROUNDS)
-    ref = donated["combos"][f"{mode}-{variant}"]
-    assert np.array_equal(ref["pred"],
-                          m_off.predict(X, raw_score=True))
-    assert ref["model"] == m_off.model_to_string()
+        out[donate] = (m.model_to_string(),
+                       m.predict(X, raw_score=True))
+    assert np.array_equal(out["true"][1], out["false"][1])
+    assert out["true"][0] == out["false"][0]
 
 
-def test_valid_scores_donation_matches_eval_trajectory(donated):
+def test_valid_scores_donation_matches_eval_trajectory():
     # valid carries ride _valid_update_j's donated list (the per-iter
     # path: valid sets disable fusion); the recorded eval trajectory
     # and the early-stop decision must be unchanged
     Xt, yt = make_data(seed=3)
     Xv, yv = make_data(n=1024, seed=4)
-    rec = {}
-    ds = lgb.Dataset(Xt, label=yt)
-    bst = lgb.train(
-        params_for({"metric": "binary_logloss"}, "false"), ds,
-        num_boost_round=VALID_ROUNDS,
-        valid_sets=[lgb.Dataset(Xv, label=yv, reference=ds)],
-        valid_names=["v"],
-        callbacks=[lgb.record_evaluation(rec),
-                   lgb.early_stopping(5, verbose=False)])
-    assert donated["valid"]["record"] == rec
-    assert donated["valid"]["best_iteration"] == bst.best_iteration
+    out = {}
+    for donate in ("true", "false"):
+        rec = {}
+        ds = lgb.Dataset(Xt, label=yt)
+        bst = lgb.train(
+            params_for({"metric": "binary_logloss"}, donate), ds,
+            num_boost_round=VALID_ROUNDS,
+            valid_sets=[lgb.Dataset(Xv, label=yv, reference=ds)],
+            valid_names=["v"],
+            callbacks=[lgb.record_evaluation(rec),
+                       lgb.early_stopping(5, verbose=False)])
+        out[donate] = (rec, bst.best_iteration)
+    assert out["true"] == out["false"]
 
 
-def test_donation_adds_zero_programs(donated):
-    """The compile pin, both halves: warm donated iterations compiled
-    NOTHING in the worker, and the donated cold train requested no
-    more compiles than this process's undonated twin (donation aliases
-    buffers inside the same programs — it must never introduce one;
-    compile REQUESTS count persistent-cache hits too, so the two
-    processes' counts compare like-for-like)."""
-    assert donated["compile_true_warm"] == 0
+def test_donation_adds_zero_programs():
+    """The compile pin, both halves: warm donated iterations compile
+    NOTHING, and the donated cold train requests no more compiles than
+    its undonated twin (donation aliases buffers inside the same
+    programs — it must never introduce one; compile REQUESTS count
+    persistent-cache hits too, so the two counts compare
+    like-for-like)."""
     X, y = make_data(seed=5)
-    eng = GBDT(Config(params_for({"tpu_fuse_iters": 4}, "false")),
+    cold = {}
+    for donate in ("true", "false"):
+        eng = GBDT(Config(params_for({"tpu_fuse_iters": 4}, donate)),
+                   lgb.Dataset(X, label=y))
+        with CompileWatch(f"cold donate={donate}") as w:
+            eng.train_chunk(8)
+        cold[donate] = w.compiles
+        if donate == "true":
+            with CompileWatch("warm donated") as w_warm:
+                eng.train_chunk(8)
+            w_warm.assert_compiles(0)
+    assert cold["true"] <= cold["false"], (
+        f"enabling donation added programs: {cold['true']} compile "
+        f"request(s) donated vs {cold['false']} undonated")
+
+
+def test_use_after_donate_guard_fires_on_stale_score():
+    """tpu_debug_checks turns the stale-reference crash into an error
+    naming the donating site: re-feeding a score buffer the previous
+    iteration already donated fails with the guard's message, not
+    XLA's generic deleted-array error."""
+    X, y = make_data(seed=6)
+    eng = GBDT(Config(params_for({"tpu_debug_checks": True}, "true")),
                lgb.Dataset(X, label=y))
-    with CompileWatch("cold undonated") as w:
-        eng.train_chunk(8)
-    assert donated["compile_true_cold"] <= w.compiles, (
-        f"enabling donation added programs: "
-        f"{donated['compile_true_cold']} compile request(s) donated "
-        f"vs {w.compiles} undonated")
-
-
-def test_use_after_donate_guard_fires_on_stale_score(donated):
-    """tpu_debug_checks turned the stale-reference crash into an error
-    naming the donating site (observed in the worker): re-feeding a
-    score buffer the previous iteration already donated failed with
-    the guard's message, not XLA's generic deleted-array error."""
-    assert donated["stale_deleted"]
-    assert donated["guard_fired"]
-    assert "use-after-donate" in donated["guard_message"]
-    assert "the step's donated score" in donated["guard_message"]
+    stale = eng.score
+    eng.train_one_iter()
+    assert stale.is_deleted()
+    eng.score = stale
+    with pytest.raises(LightGBMError) as e:
+        eng.train_one_iter()
+    assert "use-after-donate" in str(e.value)
+    assert "the step's donated score" in str(e.value)
 
 
 def test_guard_silent_without_donate():

@@ -287,9 +287,8 @@ def test_cli_distributed_train_uneven_shards(tmp_path,
     rank's shard cross a pad-block boundary, exercising the
     globally-agreed pad layout (shapes would diverge across processes
     without the counts allgather). Needs REAL multi-process
-    collectives, which this container's jaxlib CPU backend lacks — the
-    conftest capability probe skips it there (known-red since the PR-1
-    seed) instead of leaving tier-1 with an expected failure."""
+    collectives: the conftest capability probe skips it where the CPU
+    backend cannot run them (the installed jaxlib can)."""
     from lightgbm_tpu.app import run
     X, y = _data(n=4097)
     train_path = str(tmp_path / "train.csv")

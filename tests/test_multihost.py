@@ -169,7 +169,10 @@ def _run_sync_uneven(shards, params, monkeypatch):
     state.update(rank=0, mode="combine")
     monkeypatch.setattr(jax, "process_index", lambda: 0)
     mappers = sync_bin_mappers(shards[0], params)
-    sizes = {r: int(np.sum(~np.isnan(recorded[r][:, 0])))
+    # the sample rides the gather as the raw 32-bit words of its
+    # float64 values (parallel.multihost.allgather_float64)
+    sizes = {r: int(np.sum(~np.isnan(
+                 recorded[r].view(np.float64)[:, 0])))
              for r in recorded}
     return mappers, sizes
 

@@ -190,6 +190,30 @@ def test_sharded_matches_unsharded(devices):
     np.testing.assert_allclose(sliced, want_sliced, rtol=0, atol=1e-12)
 
 
+def test_sharded_scan_goes_through_mesh_wrapper(monkeypatch):
+    """The sharded SHAP scan is built by ``parallel.mesh.shard_map``
+    like every other sharded program of the package (it once imported
+    JAX's own and so ran with a replication check the others turn
+    off). A 4-device mesh keeps its program out of the kernel cache
+    the [2]/[8] cases above fill."""
+    from lightgbm_tpu.parallel import mesh as mesh_lib
+    seen = []
+    real = mesh_lib.shard_map
+
+    def spy(f, **kwargs):
+        seen.append(kwargs)
+        return real(f, **kwargs)
+
+    monkeypatch.setattr(mesh_lib, "shard_map", spy)
+    bst, X = _train(objective="binary", rounds=8)
+    want = bst.predict(X[:64], pred_contrib=True)
+    mesh = enable_tree_sharding(bst, tree_mesh(4))
+    got = bst.predict(X[:64], pred_contrib=True)
+    assert [k["mesh"] for k in seen] == [mesh]
+    assert seen[0]["check_vma"] is False
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-12)
+
+
 # ---------------------------------------------------------------------------
 # capability gate
 # ---------------------------------------------------------------------------
