@@ -129,6 +129,13 @@ def _snap_gauge(snap, name):
     return None
 
 
+def _snap_total(snap, name):
+    """Sum of one counter over its label sets in an obs snapshot."""
+    vals = [m.get("value") or 0.0 for m in snap["metrics"]
+            if m["name"] == name]
+    return sum(vals) if vals else None
+
+
 def run_config(X, y, X_ho, y_ho, params, iters, warmup, windows=3,
                cat_features="auto", measure_predict=True):
     """Train warmup+iters rounds, AUC there, then median of N timed
@@ -513,10 +520,10 @@ def main():
         # pipeline (and the donation pass before it) squeezes — carried
         # whenever attribution ran, not only when copy_share did
         extras += f"; wall_busy_gap_ms={v:.2f}"
-    v = _snap_gauge(snap, "hist.rows_scanned")
+    v = _snap_total(snap, "hist.cols_scanned")
     if v:
-        # the structural win the partition exists for: total rows the
-        # histogram scans touched (masked = n_pad x rounds)
+        # the structural win the partition exists for: total columns
+        # the histogram calls were handed (masked = n_pad x rounds)
         extras += f"; hist_rows_scanned={v:.3g}"
     v = _snap_gauge(snap, "bench.stream_rows_per_sec")
     if v is not None:

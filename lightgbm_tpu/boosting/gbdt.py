@@ -304,6 +304,26 @@ class _DeviceData:
 AUTO_QUANT_MIN_ROWS = capabilities.AUTO_QUANT_MIN_ROWS
 
 
+def _cols_needed(host: Dict[str, np.ndarray]) -> float:
+    """Columns the histograms of these trees (any leading dims) had to
+    read, from the trees' own counts: a tree's root rows once and the
+    smaller child's rows at each split (benchmark/lib/work.py's rule);
+    a tree that never split counts 0."""
+    lc, rc = host["left_child"], host["right_child"]
+    icount, lcount = host["internal_count"], host["leaf_count"]
+
+    def count(child):
+        inner = np.take_along_axis(icount, np.maximum(child, 0), axis=-1)
+        leaf = np.take_along_axis(lcount, np.maximum(~child, 0), axis=-1)
+        return np.where(child >= 0, inner, leaf).astype(np.int64)
+
+    n_nodes = host["num_leaves"][..., None] - 1
+    live = np.arange(lc.shape[-1]) < n_nodes
+    smaller = np.where(live, np.minimum(count(lc), count(rc)), 0)
+    roots = np.where(n_nodes[..., 0] > 0, icount[..., 0], 0)
+    return float(np.sum(smaller) + np.sum(roots.astype(np.int64)))
+
+
 def goss_shard_valid_counts(n_local: int, n_pad_local: int,
                             n_global_devices: int, n_processes: int,
                             allgather=None):
@@ -737,7 +757,6 @@ class GBDT:
         if self.hist_partition:
             log.info("leaf-ordered row partition enabled: histograms "
                      "scan only the elected children's row spans")
-        obs.set_gauge("hist.partition", float(self.hist_partition))
 
         self.grow_cfg = self._make_grow_cfg()
 
@@ -789,8 +808,6 @@ class GBDT:
         self._bag_mask = None  # device [n_pad] or None when no bagging
         self._train_metric_names: List[str] = [m.name for m in self.metrics]
         self._build_step()
-        if self.hist_partition and self.mesh is None and obs.enabled():
-            self._probe_partition_move()
 
     # ------------------------------------------------------------------
     def _init_score_tile(self, dd: "_DeviceData") -> jnp.ndarray:
@@ -1062,7 +1079,15 @@ class GBDT:
 
         needs_rng = getattr(obj, "needs_rng", False)
         self._step_state = self._step_goss_state = None
+        # hist.onehot_elems a column scanned (_count_work): the kernel
+        # compares every (padded feature, bin) of its feature blocks
+        from ..ops.pallas_histogram import feature_blocks
+        F_h = self.data.bins.shape[1]
+        if self.use_pallas:
+            F_h = int(np.prod(feature_blocks(F_h, gcfg.num_bins)))
+        self._hist_onehot_per_col = F_h * gcfg.num_bins
 
+        @obs.scope("engine/gradients")
         def gradients(score, label, weight, key):
             s = score[:, 0] if K == 1 else score
             if needs_rng:
@@ -1080,6 +1105,7 @@ class GBDT:
         glevels = max(qbins // 2, 1)
         hlevels = max(qbins - 1, 1)
 
+        @obs.scope("engine/gradients")
         def quantize(gk_m, hk_m, mask_count, qkey):
             gmax = jnp.max(jnp.abs(gk_m))
             hmax = jnp.max(hk_m)
@@ -1108,6 +1134,7 @@ class GBDT:
                                jnp.asarray(1.0, jnp.float32)])
             return gq, hq, scale
 
+        @obs.scope("engine/score_update")
         def leaf_contrib(tree, leaf_id):
             """Per-row leaf_value[leaf_id] * lr. As a one-hot matmul: a
             per-row gather into a [L] table runs on the TPU scalar unit
@@ -1124,6 +1151,33 @@ class GBDT:
                     dimension_numbers=(((1,), (0,)), ((), ())),
                     precision=jax.lax.Precision.HIGHEST)[:, 0] * lr
             return tree["leaf_value"][leaf_id] * lr
+
+        @obs.scope("grower/leaf_values")
+        def renew_leaves(tree, leaf_id, gk_m, hk_m):
+            """Re-derive leaf outputs from FULL-precision sums
+            (quant_train_renew_leaf)."""
+            from ..ops.split import calc_leaf_output
+            Lq = tree["leaf_value"].shape[0]
+            oh = (leaf_id[:, None]
+                  == jnp.arange(Lq, dtype=jnp.int32)[None, :])
+            sums = jax.lax.dot_general(
+                oh.astype(jnp.float32),
+                jnp.stack([gk_m, hk_m], axis=1),
+                dimension_numbers=(((0,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST)   # [L, 2]
+            if gcfg.axis_name:
+                sums = jax.lax.psum(sums, gcfg.axis_name)
+            renewed = calc_leaf_output(
+                sums[:, 0], sums[:, 1], gcfg.lambda_l1,
+                gcfg.lambda_l2, gcfg.max_delta_step)
+            tree = dict(tree)
+            tree["leaf_value"] = jnp.where(
+                tree["leaf_count"] > 0, renewed, tree["leaf_value"])
+            return tree
+
+        @obs.scope("engine/score_update")
+        def add_contrib(score, k, tree, leaf_id):
+            return score.at[:, k].add(leaf_contrib(tree, leaf_id))
 
         def grow_all(bins, bins_t, score, g, h, mask_gh, mask_count,
                      allowed, qkey=None, cegb_pen=None, cegb_U=None):
@@ -1172,28 +1226,8 @@ class GBDT:
                     tree = {kk: v for kk, v in tree.items()
                             if kk != "leaf_used"}
                 if use_quant and renew_quant:
-                    # re-derive leaf outputs from FULL-precision sums
-                    # (quant_train_renew_leaf)
-                    from ..ops.split import calc_leaf_output
-                    Lq = tree["leaf_value"].shape[0]
-                    oh = (leaf_id[:, None]
-                          == jnp.arange(Lq, dtype=jnp.int32)[None, :])
-                    sums = jax.lax.dot_general(
-                        oh.astype(jnp.float32),
-                        jnp.stack([gk_m, hk_m], axis=1),
-                        dimension_numbers=(((0,), (0,)), ((), ())),
-                        precision=jax.lax.Precision.HIGHEST)   # [L, 2]
-                    if gcfg.axis_name:
-                        sums = jax.lax.psum(sums, gcfg.axis_name)
-                    renewed = calc_leaf_output(
-                        sums[:, 0], sums[:, 1], gcfg.lambda_l1,
-                        gcfg.lambda_l2, gcfg.max_delta_step)
-                    tree = dict(tree)
-                    tree["leaf_value"] = jnp.where(
-                        tree["leaf_count"] > 0, renewed,
-                        tree["leaf_value"])
-                new_score = new_score.at[:, k].add(
-                    leaf_contrib(tree, leaf_id))
+                    tree = renew_leaves(tree, leaf_id, gk_m, hk_m)
+                new_score = add_contrib(new_score, k, tree, leaf_id)
                 trees.append(tree)
                 leaf_ids.append(leaf_id)
             stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
@@ -1317,7 +1351,14 @@ class GBDT:
         # rates keep the sort (top_k at k ~ n IS a sort).
         _k_top_max = max(_k_top_list)
         _k_rand_max = max(_k_rand_list)
+        # goss.rows_in / goss.rows_kept an iteration (_count_work): the
+        # rows the thresholds rank and the exact count they keep
+        self._goss_rows = (
+            sum(_local_valid),
+            sum(min(kt, v) + min(kr, max(v - kt, 0)) for v, kt, kr
+                in zip(_local_valid, _k_top_list, _k_rand_list)))
 
+        @obs.scope("engine/goss_sample")
         def goss_masks(g, h, valid_mask, key):
             """GOSS (goss.hpp): keep top-a by |g*h|, sample b of the rest,
             amplify the sampled rest by (1-a)/b. Per-shard under the mesh,
@@ -1461,10 +1502,6 @@ class GBDT:
                            and (self.use_pallas
                                 or jax.default_backend() != "tpu"))
         self._use_goss_compact = use_goss_compact
-        # the partition-move probe (hist.partition_ms) must time the
-        # shape the grow loop actually repartitions: the compacted
-        # buffer under GOSS hist-compact, the full padded rows otherwise
-        self._goss_n_sub = n_sub if use_goss_compact else None
 
         # ---- buffer donation (tpu_donate; docs/perf.md "Iteration
         # floor"): the r5 trace pins ~9% of device busy on loop-state
@@ -1508,29 +1545,30 @@ class GBDT:
                 # into a fixed-size front buffer with per-block one-hot
                 # permutation matmuls instead; grad/hess/masks ride as
                 # value channels of the same kernel call.
-                g2 = g if K > 1 else g[:, None]
-                h2 = h if K > 1 else h[:, None]
-                vals_all = jnp.concatenate(
-                    [g2.T, h2.T, mask_gh[None], mask_count[None]],
-                    axis=0).astype(jnp.float32)       # [2K+2, n]
-                dest, algn, rem = plan_compaction(sel, R_c, n_sub)
-                if bins_t is not None:
-                    bins_t_c, vc = compact_rows(
-                        bins_t, vals_all, dest, algn, rem,
-                        out_cols=n_sub, rows_per_block=R_c)
-                    # int8 -> uint8 reinterpret restores bin values for
-                    # the row-major partition path
-                    bins_c = bins_t_c.T.astype(bins.dtype)
-                else:
-                    bt_any, vc = compact_rows_xla(
-                        bins.T, vals_all, dest, algn, rem,
-                        out_cols=n_sub, rows_per_block=R_c)
-                    bins_c = bt_any.T
-                    bins_t_c = None
-                g_c = vc[:K].T
-                h_c = vc[K:2 * K].T
-                mgh_c = vc[2 * K]
-                mc_c = vc[2 * K + 1]
+                with obs.scope("engine/goss_compact"):
+                    g2 = g if K > 1 else g[:, None]
+                    h2 = h if K > 1 else h[:, None]
+                    vals_all = jnp.concatenate(
+                        [g2.T, h2.T, mask_gh[None], mask_count[None]],
+                        axis=0).astype(jnp.float32)       # [2K+2, n]
+                    dest, algn, rem = plan_compaction(sel, R_c, n_sub)
+                    if bins_t is not None:
+                        bins_t_c, vc = compact_rows(
+                            bins_t, vals_all, dest, algn, rem,
+                            out_cols=n_sub, rows_per_block=R_c)
+                        # int8 -> uint8 reinterpret restores bin values
+                        # for the row-major partition path
+                        bins_c = bins_t_c.T.astype(bins.dtype)
+                    else:
+                        bt_any, vc = compact_rows_xla(
+                            bins.T, vals_all, dest, algn, rem,
+                            out_cols=n_sub, rows_per_block=R_c)
+                        bins_c = bt_any.T
+                        bins_t_c = None
+                    g_c = vc[:K].T
+                    h_c = vc[K:2 * K].T
+                    mgh_c = vc[2 * K]
+                    mc_c = vc[2 * K + 1]
                 qkey = jax.random.fold_in(key, 0x9e37)
                 import dataclasses as _dc
                 gcfg_c = _dc.replace(gcfg, hist_compact=True)
@@ -1541,8 +1579,9 @@ class GBDT:
                     in_sample = sel
                     U_eff = cegb_U | ~in_sample[:, None]
                 for k in range(K):
-                    gk = g_c[:, k] * mgh_c
-                    hk = h_c[:, k] * mgh_c
+                    with obs.scope("engine/goss_compact"):
+                        gk = g_c[:, k] * mgh_c
+                        hk = h_c[:, k] * mgh_c
                     chan_scale = None
                     if use_quant:
                         kq = jax.random.fold_in(qkey, k)
@@ -1570,8 +1609,7 @@ class GBDT:
                     # FULL leaf ids came from the in-loop partition; the
                     # score update is the same one-hot matmul as the
                     # masked path (no per-row traversal)
-                    new_score = new_score.at[:, k].add(
-                        leaf_contrib(tree, leaf_id))
+                    new_score = add_contrib(new_score, k, tree, leaf_id)
                     trees.append(tree)
                     leaf_ids.append(leaf_id)
                 stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
@@ -1597,6 +1635,7 @@ class GBDT:
         else:
             self._step_goss_compact = None
 
+        @obs.scope("engine/valid_update")
         def valid_update_impl(valid_bins_scores, stacked_trees):
             # apply this iteration's K trees to each valid set's raw scores
             out = []
@@ -1760,9 +1799,11 @@ class GBDT:
                          "default_left", "left_child", "right_child",
                          "split_gain", "internal_value", "internal_count",
                          "leaf_value", "leaf_count", "leaf_weight",
-                         # rows-scanned telemetry: psum'd inside
-                         # grow_tree, so replicated like the tree
-                         "hist_rows"]
+                         # the grower's work counts: rows scanned are
+                         # psum'd inside grow_tree, calls and slots are
+                         # the same on every shard
+                         "hist_rows", "hist_calls", "hist_slots",
+                         "hist_slots_filled"]
             if self.has_categorical:
                 tree_keys += ["is_cat", "cat_bitset"]
             tree_specs = {k: rep for k in tree_keys}
@@ -1842,6 +1883,7 @@ class GBDT:
                     "the sharded valid-update's donated scores")
 
         @jax.jit
+        @obs.scope("engine/score_update")
         def apply_renewed(score, leaf_ids, renewed_leaf_values):
             # re-apply renewed leaf outputs: score = score + lr * renewed
             for k in range(K):
@@ -1921,47 +1963,6 @@ class GBDT:
         self._step_custom = step_custom
         self._valid_update = valid_update
         self._apply_renewed = apply_renewed
-
-    # ------------------------------------------------------------------
-    def _probe_partition_move(self) -> None:
-        """One timed repartition move at the real data shape, recorded
-        as the ``hist.partition_ms`` gauge. The in-training move is
-        fused into the jitted growth while_loop where host timers
-        cannot see it; this standalone probe (worst case: half the rows
-        move) is the number the enable/disable decision trades against
-        per-round scan savings (docs/perf.md "Partitioned
-        histograms")."""
-        import time as _time
-
-        from ..ops import partition as part_ops
-        d = self.data
-        # under GOSS hist-compact the in-loop move operates on the
-        # compacted buffer, not the full rows — time THAT shape, or the
-        # gauge overstates the cost by ~1/(top_rate+other_rate)
-        n = self._goss_n_sub or d.n_pad
-        full = self._goss_n_sub is None
-        moved = jnp.asarray((np.arange(n) & 1).astype(bool))
-        F_h = d.bins.shape[1]
-        if self.use_pallas:
-            def mv(bins_t, vals_t, mvd):
-                _, n_front, _ = part_ops.plan_split_move(mvd)
-                return part_ops.move_cols_tpu(bins_t, vals_t, mvd,
-                                              n_front, self.part_rpb)
-            args = (d.bins_t if full else jnp.zeros((F_h, n), jnp.int8),
-                    jnp.zeros((4, n), jnp.float32), moved)
-        else:
-            def mv(bins, vals, mvd):
-                dest, _, _ = part_ops.plan_split_move(mvd)
-                return part_ops.move_rows_xla([bins, vals], dest)
-            args = (d.bins if full
-                    else jnp.zeros((n, F_h), d.bins.dtype),
-                    jnp.zeros((n, 4), jnp.float32), moved)
-        fn = jax.jit(mv)
-        jax.block_until_ready(fn(*args))          # compile
-        t0 = _time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        obs.set_gauge("hist.partition_ms",
-                      (_time.perf_counter() - t0) * 1e3)
 
     # ------------------------------------------------------------------
     def _cegb_U_arg(self) -> Optional[jnp.ndarray]:
@@ -2063,12 +2064,21 @@ class GBDT:
                 except _checkify.JaxRuntimeError as e:
                     log.fatal(f"tpu_debug at iteration {self.iter_}: "
                               f"{e}")
-        cegb_U_new = None
         # the fused XLA step dispatch (gradients + grow + split + score
         # apply run as ONE device program, so the host can only time
-        # the dispatch boundary; completion lands in train/fetch_trees
-        # where the tree arrays materialize)
+        # the dispatch boundary, train/dispatch; completion lands in
+        # train/fetch_trees where the tree arrays materialize)
         with obs.span("train/step", iteration=self.iter_):
+            self._train_step(grad, hess, goss_active, allowed, key,
+                             score_pre)
+        self.iter_ += 1
+
+    def _train_step(self, grad, hess, goss_active: bool, allowed, key,
+                    score_pre) -> None:
+        """The body of the ``train/step`` span: dispatch, valid update,
+        tree fetch and the host's bookkeeping of one iteration."""
+        cegb_U_new = None
+        with obs.span("train/dispatch"):
             if grad is not None:
                 mask_gh, mask_count = self._bagging_masks()
                 g = self._pad_custom(grad)
@@ -2136,7 +2146,7 @@ class GBDT:
                                                        stacked)
         with obs.span("train/fetch_trees"):
             host_trees = self._fetch_tree_arrays(stacked)
-        self._append_host_trees(host_trees)
+        self._append_host_trees(self._count_work(host_trees, goss_active))
         obs.inc("train.iterations")
         obs.heartbeat("train")
         if cegb_U_new is not None:
@@ -2158,7 +2168,6 @@ class GBDT:
             if not np.isfinite(np.asarray(self.score)).all():
                 log.fatal(f"Non-finite training scores at iteration "
                           f"{self.iter_}")
-        self.iter_ += 1
 
     def _apply_linear_fit(self, leaf_ids, score_pre) -> None:
         """Refine the just-grown trees' leaves with per-leaf weighted
@@ -2233,17 +2242,41 @@ class GBDT:
             leaf.copy_to_host_async()
         return jax.tree.map(np.asarray, stacked)
 
+    def _count_work(self, host: Dict[str, np.ndarray],
+                    sampled: bool) -> Dict[str, np.ndarray]:
+        """Feed the grower's own work counts of a step's or a chunk's
+        trees (any leading dims) to the ``hist.*`` / ``goss.*``
+        counters, labelled by the program that grew them, and return
+        the tree arrays without them. Always kept (``force``): once a
+        step or a chunk, a few dict lookups and adds."""
+        host = dict(host)
+        total = {k: float(np.sum(host.pop(k), dtype=np.float64))
+                 for k in ("hist_rows", "hist_calls", "hist_slots",
+                           "hist_slots_filled")}
+        cols = total["hist_rows"]
+        for name, value in (
+                # columns the calls were handed: the static buffer
+                # length, or the elected spans under hist_partition
+                ("hist.cols_scanned", cols),
+                ("hist.cols_needed", _cols_needed(host)),
+                ("hist.calls", total["hist_calls"]),
+                ("hist.leaf_slots", total["hist_slots"]),
+                ("hist.leaf_slots_filled", total["hist_slots_filled"]),
+                # the kernel's VPU work by its own account: one compare
+                # a (column, padded feature, bin)
+                ("hist.onehot_elems", cols * self._hist_onehot_per_col)):
+            obs.inc(name, value, force=True, sampled=int(bool(sampled)))
+        if sampled:
+            n_iters = host["num_leaves"].size // self.num_class
+            rows_in, rows_kept = self._goss_rows
+            obs.inc("goss.rows_in", float(rows_in * n_iters), force=True)
+            obs.inc("goss.rows_kept", float(rows_kept * n_iters),
+                    force=True)
+        return host
+
     def _append_host_trees(self, host: Dict[str, np.ndarray]) -> None:
         """Append one iteration's K per-class trees (host arrays with a
         leading class dim) to the model list."""
-        if "hist_rows" in host:
-            # rows the histogram scans touched (all K class trees):
-            # masked path = n x rounds, partitioned = sum of elected
-            # children's padded spans (the structural win this metric
-            # exists to watch — docs/perf.md "Partitioned histograms")
-            host = dict(host)
-            obs.inc("hist.rows_scanned",
-                    float(np.sum(host.pop("hist_rows"))))
         for k in range(self.num_class):
             arrays = {key: v[k] for key, v in host.items()}
             t = Tree.from_device(
@@ -2333,14 +2366,18 @@ class GBDT:
                 axis=1))
             with obs.span("train/fused_chunk", iterations=n,
                           start=it0):
-                new_score, stacked = self._chunk_cache[goss_now](
-                    self.score, keys)
+                # the call of the chunk program returns at enqueue
+                with obs.span("train/dispatch"):
+                    new_score, stacked = self._chunk_cache[goss_now](
+                        self.score, keys)
                 self.score = new_score
                 with obs.span("train/fetch_trees"):
                     host = self._fetch_tree_arrays(stacked)
-                for i in range(n):
-                    self._append_host_trees(
-                        {kk: v[i] for kk, v in host.items()})
+                with obs.span("train/append_trees"):
+                    host = self._count_work(host, goss_now)
+                    for i in range(n):
+                        self._append_host_trees(
+                            {kk: v[i] for kk, v in host.items()})
             obs.inc("train.iterations", n)
             obs.heartbeat("train")
             self.iter_ += n

@@ -376,9 +376,11 @@ class Dataset:
                                   in enumerate(self.bin_mappers)
                                   if not m.is_trivial]
         else:
+            from .. import obs
             from .binning import mappers_from_params
-            self.bin_mappers = mappers_from_params(
-                X, self.params, categorical_idx=cat_idx)
+            with obs.span("ingest/find_bins"):
+                self.bin_mappers = mappers_from_params(
+                    X, self.params, categorical_idx=cat_idx)
             self.used_features = [i for i, m in enumerate(self.bin_mappers)
                                   if not m.is_trivial]
             if len(self.used_features) < self.num_total_features:

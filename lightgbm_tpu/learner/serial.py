@@ -36,6 +36,7 @@ from typing import Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..ops.pallas_histogram import (multi_leaf_histogram,
                                     multi_leaf_histogram_xla)
 from ..ops.split import (NEG_INF, SplitConfig, calc_leaf_output,
@@ -288,8 +289,13 @@ class GrowState(NamedTuple):
     part_cnt: jnp.ndarray           # [L+1]
     # rows the histogram scans touched so far this tree (always
     # maintained — the masked path counts n per round) — the
-    # hist.rows_scanned observability metric
+    # hist.cols_scanned work counter
     rows_scanned: jnp.ndarray
+    # invocations of the histogram kernel so far this tree (the root's
+    # included) and, summed over them, the leaf slots that held a leaf
+    # (id not -1): the hist.calls / hist.leaf_slots_filled counters
+    hist_calls: jnp.ndarray
+    hist_slots_filled: jnp.ndarray
 
 
 def _masked_gains(gain, leaf_depth, num_leaves, max_depth):
@@ -376,6 +382,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                   and bool(cfg.axis_name)
                   and not (mode_voting or mode_feature))
 
+    @obs.scope("grower/histogram")
     def hist_reduce(h):
         """Mode-specific cross-device histogram reduction — ONE
         collective through the shared packed-int32 wire
@@ -423,6 +430,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         pr = math.gcd(cfg.rows_per_block, r_cap)
         base_rpb = pr
 
+        @obs.scope("grower/histogram")
         def hist_kernel(b_src, v_src, l_src, ids, rpb):
             """Raw local multi-leaf histogram over an arbitrary source
             (the whole data, the GOSS buffer, or partition spans) —
@@ -439,6 +447,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         import math
         base_rpb = cfg.rows_per_block
 
+        @obs.scope("grower/histogram")
         def hist_kernel(b_src, v_src, l_src, ids, rpb):
             return multi_leaf_histogram_xla(
                 b_src, v_src, l_src, ids, num_bins=B,
@@ -468,6 +477,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
             tuple(M_span * s for s in part_budgets) + (n_h,),
             jnp.float32)
 
+        @obs.scope("grower/histogram")
         def span_hist(pb, pv, pl, ids, offs, cnts):
             """[M, F_h, B, 3] local histograms of the elected children
             + the rows this round's scan touched."""
@@ -607,6 +617,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
     else:
         lazy_pen2 = None
 
+    @obs.scope("grower/split_search")
     def search_best(hists, sums, lowers=None, uppers=None, allows=None,
                     parent_outs=None, round_tag=0, depths=None,
                     pen2=None):
@@ -704,6 +715,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
             return elect_best(best, cfg.feature_axis)
         return best
 
+    @obs.scope("grower/leaf_values")
     def leaf_out(sums):
         return calc_leaf_output(sums[..., 0], sums[..., 1], cfg.lambda_l1,
                                 cfg.lambda_l2, cfg.max_delta_step)
@@ -781,76 +793,79 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
     def set0(arr, value):
         return arr.at[0].set(value)
 
-    state = GrowState(
-        split_idx=jnp.array(0, i32),
-        num_leaves=jnp.array(1, i32),
-        # pending forced entries must enter the loop even when the free
-        # root search found nothing (forced splits bypass gain checks)
-        has_split=(jnp.array(True) if forced is not None
-                   else jnp.isfinite(root_best["gain"])),
-        leaf_id=leaf_id0,
-        # rebuild mode carries no pool — a 1-element placeholder keeps
-        # the NamedTuple structure static
-        leaf_hist=(jnp.zeros((1, 1, 1, 1), jnp.float32)
-                   if cfg.hist_rebuild else
-                   set0(jnp.zeros((L + 1,) + root_hist.shape,
-                                  jnp.float32), root_hist)),
-        leaf_sums=set0(jnp.zeros((L + 1, 3), jnp.float32), root_sums),
-        leaf_depth=jnp.zeros(L + 1, i32),
-        best_gain=set0(jnp.full(L + 1, NEG_INF), root_best["gain"]),
-        best_feature=set0(jnp.zeros(L + 1, i32), root_best["feature"]),
-        best_threshold=set0(jnp.zeros(L + 1, i32),
-                            root_best["threshold_bin"]),
-        best_default_left=set0(jnp.zeros(L + 1, jnp.bool_),
-                               root_best["default_left"]),
-        best_lr_sums=set0(jnp.zeros((L + 1, 2, 3), jnp.float32),
-                          jnp.stack([root_best["left_sums"],
-                                     root_best["right_sums"]])),
-        best_is_cat=set0(jnp.zeros(L + 1, jnp.bool_),
-                         root_best["is_cat"]),
-        best_cat_bitset=set0(jnp.zeros((L + 1, W), jnp.uint32),
-                             root_best["cat_bitset"]),
-        split_feature=jnp.zeros(L, i32),
-        threshold_bin=jnp.zeros(L, i32),
-        default_left=jnp.zeros(L, jnp.bool_),
-        node_is_cat=jnp.zeros(L, jnp.bool_),
-        node_cat_bitset=jnp.zeros((L, W), jnp.uint32),
-        left_child=jnp.zeros(L, i32),
-        right_child=jnp.zeros(L, i32),
-        node_vcg=jnp.zeros((L, 3), jnp.float32),
-        leaf_vcw=set0(jnp.zeros((L + 1, 3), jnp.float32),
-                      jnp.stack([leaf_out(root_sums), root_sums[2],
-                                 root_sums[1]])),
-        leaf_parent=jnp.full(L + 1, -1, i32),
-        leaf_is_left=jnp.zeros(L + 1, jnp.bool_),
-        leaf_bounds=jnp.stack(
-            [jnp.full(L + 1, -jnp.inf, jnp.float32),
-             jnp.full(L + 1, jnp.inf, jnp.float32)], axis=1),
-        leaf_used=jnp.zeros(
-            (L + 1, F_meta if (cfg.has_interaction or cfg.has_cegb_lazy)
-             else 1), jnp.bool_),
-        mono_left=jnp.zeros(
-            (L, L + 1) if use_mono_inter else (1, 1), jnp.bool_),
-        mono_right=jnp.zeros(
-            (L, L + 1) if use_mono_inter else (1, 1), jnp.bool_),
-        leaf_flo=(jnp.zeros((L + 1, F_meta), i32) if use_mono_adv
-                  else jnp.zeros((1, 1), i32)),
-        leaf_fhi=(jnp.broadcast_to(feat_num_bin[None, :],
-                                   (L + 1, F_meta)).astype(i32)
-                  if use_mono_adv else jnp.zeros((1, 1), i32)),
-        leaf_id_c=(leaf_id0_c if compact is not None
-                   else jnp.zeros(1, i32)),
-        forced_target=(jnp.where(f_parent < 0, 0, -1).astype(i32)
-                       if forced is not None else jnp.zeros(1, i32)),
-        part_bins=part_bins0,
-        part_vals=part_vals0,
-        part_leaf=part_leaf0,
-        part_off=part_off0,
-        part_cnt=part_cnt0,
-        # the root histogram above scanned the whole source once
-        # (float32: n x rounds x shards overflows int32 at prod scale)
-        rows_scanned=jnp.asarray(n_h, jnp.float32),
-    )
+    with obs.scope("grower/leaf_values"):
+        state = GrowState(
+            split_idx=jnp.array(0, i32),
+            num_leaves=jnp.array(1, i32),
+            # pending forced entries must enter the loop even when the free
+            # root search found nothing (forced splits bypass gain checks)
+            has_split=(jnp.array(True) if forced is not None
+                       else jnp.isfinite(root_best["gain"])),
+            leaf_id=leaf_id0,
+            # rebuild mode carries no pool — a 1-element placeholder keeps
+            # the NamedTuple structure static
+            leaf_hist=(jnp.zeros((1, 1, 1, 1), jnp.float32)
+                       if cfg.hist_rebuild else
+                       set0(jnp.zeros((L + 1,) + root_hist.shape,
+                                      jnp.float32), root_hist)),
+            leaf_sums=set0(jnp.zeros((L + 1, 3), jnp.float32), root_sums),
+            leaf_depth=jnp.zeros(L + 1, i32),
+            best_gain=set0(jnp.full(L + 1, NEG_INF), root_best["gain"]),
+            best_feature=set0(jnp.zeros(L + 1, i32), root_best["feature"]),
+            best_threshold=set0(jnp.zeros(L + 1, i32),
+                                root_best["threshold_bin"]),
+            best_default_left=set0(jnp.zeros(L + 1, jnp.bool_),
+                                   root_best["default_left"]),
+            best_lr_sums=set0(jnp.zeros((L + 1, 2, 3), jnp.float32),
+                              jnp.stack([root_best["left_sums"],
+                                         root_best["right_sums"]])),
+            best_is_cat=set0(jnp.zeros(L + 1, jnp.bool_),
+                             root_best["is_cat"]),
+            best_cat_bitset=set0(jnp.zeros((L + 1, W), jnp.uint32),
+                                 root_best["cat_bitset"]),
+            split_feature=jnp.zeros(L, i32),
+            threshold_bin=jnp.zeros(L, i32),
+            default_left=jnp.zeros(L, jnp.bool_),
+            node_is_cat=jnp.zeros(L, jnp.bool_),
+            node_cat_bitset=jnp.zeros((L, W), jnp.uint32),
+            left_child=jnp.zeros(L, i32),
+            right_child=jnp.zeros(L, i32),
+            node_vcg=jnp.zeros((L, 3), jnp.float32),
+            leaf_vcw=set0(jnp.zeros((L + 1, 3), jnp.float32),
+                          jnp.stack([leaf_out(root_sums), root_sums[2],
+                                     root_sums[1]])),
+            leaf_parent=jnp.full(L + 1, -1, i32),
+            leaf_is_left=jnp.zeros(L + 1, jnp.bool_),
+            leaf_bounds=jnp.stack(
+                [jnp.full(L + 1, -jnp.inf, jnp.float32),
+                 jnp.full(L + 1, jnp.inf, jnp.float32)], axis=1),
+            leaf_used=jnp.zeros(
+                (L + 1, F_meta if (cfg.has_interaction or cfg.has_cegb_lazy)
+                 else 1), jnp.bool_),
+            mono_left=jnp.zeros(
+                (L, L + 1) if use_mono_inter else (1, 1), jnp.bool_),
+            mono_right=jnp.zeros(
+                (L, L + 1) if use_mono_inter else (1, 1), jnp.bool_),
+            leaf_flo=(jnp.zeros((L + 1, F_meta), i32) if use_mono_adv
+                      else jnp.zeros((1, 1), i32)),
+            leaf_fhi=(jnp.broadcast_to(feat_num_bin[None, :],
+                                       (L + 1, F_meta)).astype(i32)
+                      if use_mono_adv else jnp.zeros((1, 1), i32)),
+            leaf_id_c=(leaf_id0_c if compact is not None
+                       else jnp.zeros(1, i32)),
+            forced_target=(jnp.where(f_parent < 0, 0, -1).astype(i32)
+                           if forced is not None else jnp.zeros(1, i32)),
+            part_bins=part_bins0,
+            part_vals=part_vals0,
+            part_leaf=part_leaf0,
+            part_off=part_off0,
+            part_cnt=part_cnt0,
+            # the root histogram above scanned the whole source once
+            # (float32: n x rounds x shards overflows int32 at prod scale)
+            rows_scanned=jnp.asarray(n_h, jnp.float32),
+            hist_calls=jnp.array(1, i32),
+            hist_slots_filled=jnp.array(1, i32),
+        )
 
     node_trash = L - 1  # real nodes occupy 0..L-2
     leaf_trash = L
@@ -1006,6 +1021,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                 bdef[feat_sel].astype(jnp.float32)])
         packed = jnp.stack(attr_cols, axis=1)
 
+        @obs.scope("grower/partition")
         def apply_splits(lf_vec, bins_mat, fm=False):
             """Route one row set through this round's selected splits
             (shared by the full partition, the compacted buffer's
@@ -1097,32 +1113,33 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         # else packs to the front — per-leaf contiguity and within-leaf
         # source order both survive, and the (offset, count) tables
         # update from the same prefix sums (ops/partition.py).
-        if use_part:
-            part_leaf_mv = apply_splits(s.part_leaf, s.part_bins,
-                                        fm=part_fm)
-            moved = part_leaf_mv != s.part_leaf
-            dest, n_front, cum = part_ops.plan_split_move(moved)
-            p_off, p_cnt = part_ops.update_tables(
-                s.part_off, s.part_cnt, cum, n_front, tl_safe, new_ids,
-                valid)
-            if part_fm:
-                # TPU: two compact_rows passes (front keys, back keys);
-                # the int32 leaf ids ride as one extra float32 value
-                # channel (exact via the kernel's bf16x3 split)
-                pv_aug = jnp.concatenate(
-                    [s.part_vals,
-                     part_leaf_mv[None].astype(jnp.float32)])
-                p_bins, pv2 = part_ops.move_cols_tpu(
-                    s.part_bins, pv_aug, moved, n_front, cfg.part_rpb)
-                p_vals = pv2[:-1]
-                p_leaf = pv2[-1].astype(i32)
+        with obs.scope("grower/partition"):
+            if use_part:
+                part_leaf_mv = apply_splits(s.part_leaf, s.part_bins,
+                                            fm=part_fm)
+                moved = part_leaf_mv != s.part_leaf
+                dest, n_front, cum = part_ops.plan_split_move(moved)
+                p_off, p_cnt = part_ops.update_tables(
+                    s.part_off, s.part_cnt, cum, n_front, tl_safe, new_ids,
+                    valid)
+                if part_fm:
+                    # TPU: two compact_rows passes (front keys, back keys);
+                    # the int32 leaf ids ride as one extra float32 value
+                    # channel (exact via the kernel's bf16x3 split)
+                    pv_aug = jnp.concatenate(
+                        [s.part_vals,
+                         part_leaf_mv[None].astype(jnp.float32)])
+                    p_bins, pv2 = part_ops.move_cols_tpu(
+                        s.part_bins, pv_aug, moved, n_front, cfg.part_rpb)
+                    p_vals = pv2[:-1]
+                    p_leaf = pv2[-1].astype(i32)
+                else:
+                    p_bins, p_vals, p_leaf = part_ops.move_rows_xla(
+                        [s.part_bins, s.part_vals, part_leaf_mv], dest)
             else:
-                p_bins, p_vals, p_leaf = part_ops.move_rows_xla(
-                    [s.part_bins, s.part_vals, part_leaf_mv], dest)
-        else:
-            p_bins, p_vals, p_leaf = (s.part_bins, s.part_vals,
-                                      s.part_leaf)
-            p_off, p_cnt = s.part_off, s.part_cnt
+                p_bins, p_vals, p_leaf = (s.part_bins, s.part_vals,
+                                          s.part_leaf)
+                p_off, p_cnt = s.part_off, s.part_cnt
 
         def span_tables(ids):
             """Per-elected-child (offset, count) rows for slice_spans
@@ -1152,6 +1169,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                 span_rows = jnp.asarray(n_h, jnp.float32)
             left_hist, right_hist = hist2[:Kb], hist2[Kb:]
             leaf_hist = s.leaf_hist
+            hist_ids = both_ids
         else:
             # ---- smaller-child histogram + sibling subtraction ---------
             left_smaller = lsums[:, 2] <= rsums[:, 2]
@@ -1167,38 +1185,40 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
             else:
                 hist_small = hist_multi(hist_lid, small_ids)
                 span_rows = jnp.asarray(n_h, jnp.float32)
-            # TPU note: the [L+1, F, B, 3] pool gather/scatter by leaf id
-            # lowers to serialized dynamic slices (~13 ms/round at
-            # nl=127); both become one-hot matmuls on the MXU instead.
-            # 0/1 weights with disjoint rows keep values exact; the
-            # trash lane L may accumulate a SUM of invalid lanes rather
-            # than the last write, but slot L is never an active leaf.
-            F_h = s.leaf_hist.shape[1]
-            pool_flat = s.leaf_hist.reshape(L + 1, -1)
-            leaf_ids_ax = jnp.arange(L + 1, dtype=i32)
-            oh_parent = (tl_safe[:, None]
-                         == leaf_ids_ax[None, :]).astype(jnp.float32)
-            parent_hist = jax.lax.dot_general(
-                oh_parent, pool_flat,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST).reshape(
-                    Kb, F_h, B, 3)
-            hist_large = parent_hist - hist_small
-            ls4 = left_smaller[:, None, None, None]
-            left_hist = jnp.where(ls4, hist_small, hist_large)
-            right_hist = jnp.where(ls4, hist_large, hist_small)
-            oh_new = (new_ids[:, None]
-                      == leaf_ids_ax[None, :]).astype(jnp.float32)
-            upd = jax.lax.dot_general(
-                jnp.concatenate([oh_parent, oh_new]).T,
-                jnp.concatenate([left_hist, right_hist]).reshape(
-                    2 * Kb, -1),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST)
-            written = (jnp.sum(oh_parent, axis=0)
-                       + jnp.sum(oh_new, axis=0)) > 0       # [L+1]
-            leaf_hist = jnp.where(written[:, None], upd,
-                                  pool_flat).reshape(s.leaf_hist.shape)
+            hist_ids = small_ids
+            with obs.scope("grower/histogram"):
+                # TPU note: the [L+1, F, B, 3] pool gather/scatter by leaf id
+                # lowers to serialized dynamic slices (~13 ms/round at
+                # nl=127); both become one-hot matmuls on the MXU instead.
+                # 0/1 weights with disjoint rows keep values exact; the
+                # trash lane L may accumulate a SUM of invalid lanes rather
+                # than the last write, but slot L is never an active leaf.
+                F_h = s.leaf_hist.shape[1]
+                pool_flat = s.leaf_hist.reshape(L + 1, -1)
+                leaf_ids_ax = jnp.arange(L + 1, dtype=i32)
+                oh_parent = (tl_safe[:, None]
+                             == leaf_ids_ax[None, :]).astype(jnp.float32)
+                parent_hist = jax.lax.dot_general(
+                    oh_parent, pool_flat,
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST).reshape(
+                        Kb, F_h, B, 3)
+                hist_large = parent_hist - hist_small
+                ls4 = left_smaller[:, None, None, None]
+                left_hist = jnp.where(ls4, hist_small, hist_large)
+                right_hist = jnp.where(ls4, hist_large, hist_small)
+                oh_new = (new_ids[:, None]
+                          == leaf_ids_ax[None, :]).astype(jnp.float32)
+                upd = jax.lax.dot_general(
+                    jnp.concatenate([oh_parent, oh_new]).T,
+                    jnp.concatenate([left_hist, right_hist]).reshape(
+                        2 * Kb, -1),
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST)
+                written = (jnp.sum(oh_parent, axis=0)
+                           + jnp.sum(oh_new, axis=0)) > 0       # [L+1]
+                leaf_hist = jnp.where(written[:, None], upd,
+                                      pool_flat).reshape(s.leaf_hist.shape)
 
         depth2 = s.leaf_depth[tl_safe] + 1
         lvals = leaf_out(lsums)
@@ -1208,6 +1228,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
             # lambda_l2 + cat_l2, matching the gain computed in
             # ops/split.py (reference: feature_histogram.hpp categorical
             # CalculateSplittedLeafOutput uses the cat-augmented l2)
+            @obs.scope("grower/leaf_values")
             def leaf_out_cat(sums):
                 return calc_leaf_output(
                     sums[..., 0], sums[..., 1], cfg.lambda_l1,
@@ -1402,17 +1423,18 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                                   if lazy is not None else None))
 
         # ---- tree wiring -----------------------------------------------
-        lc = s.left_child.at[node_ids].set(-top_leaf - 1)
-        rc = s.right_child.at[node_ids].set(-new_ids - 1)
-        p = s.leaf_parent[tl_safe]
-        was_left = s.leaf_is_left[tl_safe]
-        fix_l = jnp.where(valid & (p >= 0) & was_left, p, node_trash)
-        fix_r = jnp.where(valid & (p >= 0) & ~was_left, p, node_trash)
-        # trash-lane writes land in the unused node slot L-1
-        lc = lc.at[fix_l].set(jnp.where(fix_l == node_trash, lc[fix_l],
-                                        node_ids))
-        rc = rc.at[fix_r].set(jnp.where(fix_r == node_trash, rc[fix_r],
-                                        node_ids))
+        with obs.scope("grower/leaf_values"):
+            lc = s.left_child.at[node_ids].set(-top_leaf - 1)
+            rc = s.right_child.at[node_ids].set(-new_ids - 1)
+            p = s.leaf_parent[tl_safe]
+            was_left = s.leaf_is_left[tl_safe]
+            fix_l = jnp.where(valid & (p >= 0) & was_left, p, node_trash)
+            fix_r = jnp.where(valid & (p >= 0) & ~was_left, p, node_trash)
+            # trash-lane writes land in the unused node slot L-1
+            lc = lc.at[fix_l].set(jnp.where(fix_l == node_trash, lc[fix_l],
+                                            node_ids))
+            rc = rc.at[fix_r].set(jnp.where(fix_r == node_trash, rc[fix_r],
+                                            node_ids))
 
         # ---- forced-entry state resolution -----------------------------
         if forced is not None:
@@ -1441,70 +1463,74 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                                                         -2, -1)),
                                     tgt))).astype(i32)
 
-        new = GrowState(
-            split_idx=s.split_idx + nv,
-            num_leaves=s.num_leaves + nv,
-            has_split=jnp.array(True),
-            leaf_id=leaf_id,
-            leaf_hist=leaf_hist,
-            leaf_sums=s.leaf_sums.at[ids2].set(child_sums),
-            leaf_depth=s.leaf_depth.at[ids2].set(
-                jnp.concatenate([depth2, depth2])),
-            best_gain=s.best_gain.at[ids2].set(bests["gain"]),
-            best_feature=s.best_feature.at[ids2].set(bests["feature"]),
-            best_threshold=s.best_threshold.at[ids2].set(
-                bests["threshold_bin"]),
-            best_default_left=s.best_default_left.at[ids2].set(
-                bests["default_left"]),
-            best_lr_sums=s.best_lr_sums.at[ids2].set(
-                jnp.stack([bests["left_sums"], bests["right_sums"]],
-                          axis=1)),
-            best_is_cat=s.best_is_cat.at[ids2].set(bests["is_cat"]),
-            best_cat_bitset=s.best_cat_bitset.at[ids2].set(
-                bests["cat_bitset"]),
-            split_feature=s.split_feature.at[node_ids].set(feat_sel),
-            threshold_bin=s.threshold_bin.at[node_ids].set(thr_sel),
-            default_left=s.default_left.at[node_ids].set(dl_sel),
-            node_is_cat=s.node_is_cat.at[node_ids].set(
-                cat_sel if cfg.has_categorical
-                else s.best_is_cat[tl_safe]),
-            node_cat_bitset=s.node_cat_bitset.at[node_ids].set(
-                bs_sel if cfg.has_categorical
-                else s.best_cat_bitset[tl_safe]),
-            left_child=lc,
-            right_child=rc,
-            node_vcg=s.node_vcg.at[node_ids].set(jnp.stack(
-                [s.leaf_vcw[tl_safe, 0] if cfg.path_smooth > 0.0
-                 else leaf_out(psums),
-                 psums[:, 2], gain_rec], axis=1)),
-            leaf_vcw=s.leaf_vcw.at[ids2].set(jnp.stack(
-                [jnp.concatenate([lvals, rvals]),
-                 child_sums[:, 2], child_sums[:, 1]], axis=1)),
-            leaf_parent=s.leaf_parent.at[ids2].set(
-                jnp.concatenate([node_ids, node_ids])),
-            leaf_is_left=s.leaf_is_left.at[ids2].set(
-                jnp.concatenate([jnp.ones(Kb, jnp.bool_),
-                                 jnp.zeros(Kb, jnp.bool_)])),
-            leaf_bounds=(s.leaf_bounds.at[ids2].set(
-                jnp.stack([child_lower, child_upper], axis=1))
-                if cfg.has_monotone else s.leaf_bounds),
-            leaf_used=(s.leaf_used.at[ids2].set(child_used)
-                       if (cfg.has_interaction or cfg.has_cegb_lazy)
-                       else s.leaf_used),
-            mono_left=ml,
-            mono_right=mr,
-            leaf_flo=leaf_flo2,
-            leaf_fhi=leaf_fhi2,
-            leaf_id_c=leaf_id_c,
-            forced_target=(forced_tgt_next if forced is not None
-                           else s.forced_target),
-            part_bins=p_bins,
-            part_vals=p_vals,
-            part_leaf=p_leaf,
-            part_off=p_off,
-            part_cnt=p_cnt,
-            rows_scanned=s.rows_scanned + span_rows,
-        )
+        with obs.scope("grower/leaf_values"):
+            new = GrowState(
+                split_idx=s.split_idx + nv,
+                num_leaves=s.num_leaves + nv,
+                has_split=jnp.array(True),
+                leaf_id=leaf_id,
+                leaf_hist=leaf_hist,
+                leaf_sums=s.leaf_sums.at[ids2].set(child_sums),
+                leaf_depth=s.leaf_depth.at[ids2].set(
+                    jnp.concatenate([depth2, depth2])),
+                best_gain=s.best_gain.at[ids2].set(bests["gain"]),
+                best_feature=s.best_feature.at[ids2].set(bests["feature"]),
+                best_threshold=s.best_threshold.at[ids2].set(
+                    bests["threshold_bin"]),
+                best_default_left=s.best_default_left.at[ids2].set(
+                    bests["default_left"]),
+                best_lr_sums=s.best_lr_sums.at[ids2].set(
+                    jnp.stack([bests["left_sums"], bests["right_sums"]],
+                              axis=1)),
+                best_is_cat=s.best_is_cat.at[ids2].set(bests["is_cat"]),
+                best_cat_bitset=s.best_cat_bitset.at[ids2].set(
+                    bests["cat_bitset"]),
+                split_feature=s.split_feature.at[node_ids].set(feat_sel),
+                threshold_bin=s.threshold_bin.at[node_ids].set(thr_sel),
+                default_left=s.default_left.at[node_ids].set(dl_sel),
+                node_is_cat=s.node_is_cat.at[node_ids].set(
+                    cat_sel if cfg.has_categorical
+                    else s.best_is_cat[tl_safe]),
+                node_cat_bitset=s.node_cat_bitset.at[node_ids].set(
+                    bs_sel if cfg.has_categorical
+                    else s.best_cat_bitset[tl_safe]),
+                left_child=lc,
+                right_child=rc,
+                node_vcg=s.node_vcg.at[node_ids].set(jnp.stack(
+                    [s.leaf_vcw[tl_safe, 0] if cfg.path_smooth > 0.0
+                     else leaf_out(psums),
+                     psums[:, 2], gain_rec], axis=1)),
+                leaf_vcw=s.leaf_vcw.at[ids2].set(jnp.stack(
+                    [jnp.concatenate([lvals, rvals]),
+                     child_sums[:, 2], child_sums[:, 1]], axis=1)),
+                leaf_parent=s.leaf_parent.at[ids2].set(
+                    jnp.concatenate([node_ids, node_ids])),
+                leaf_is_left=s.leaf_is_left.at[ids2].set(
+                    jnp.concatenate([jnp.ones(Kb, jnp.bool_),
+                                     jnp.zeros(Kb, jnp.bool_)])),
+                leaf_bounds=(s.leaf_bounds.at[ids2].set(
+                    jnp.stack([child_lower, child_upper], axis=1))
+                    if cfg.has_monotone else s.leaf_bounds),
+                leaf_used=(s.leaf_used.at[ids2].set(child_used)
+                           if (cfg.has_interaction or cfg.has_cegb_lazy)
+                           else s.leaf_used),
+                mono_left=ml,
+                mono_right=mr,
+                leaf_flo=leaf_flo2,
+                leaf_fhi=leaf_fhi2,
+                leaf_id_c=leaf_id_c,
+                forced_target=(forced_tgt_next if forced is not None
+                               else s.forced_target),
+                part_bins=p_bins,
+                part_vals=p_vals,
+                part_leaf=p_leaf,
+                part_off=p_off,
+                part_cnt=p_cnt,
+                rows_scanned=s.rows_scanned + span_rows,
+                hist_calls=s.hist_calls + 1,
+                hist_slots_filled=s.hist_slots_filled
+                + jnp.sum(hist_ids >= 0).astype(i32),
+            )
         next_gains = _masked_gains(new.best_gain, new.leaf_depth,
                                    new.num_leaves, cfg.max_depth)
         keep_going = jnp.isfinite(jnp.max(next_gains)) & (nv > 0)
@@ -1544,6 +1570,13 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         "leaf_count": final.leaf_vcw[:L, 1],
         "leaf_weight": final.leaf_vcw[:L, 2],
         "hist_rows": rows_scanned,
+        # the grower's own work counts (boosting/gbdt.py feeds them to
+        # the hist.* counters); every call after the root's has
+        # hist_slots_per_call slots
+        "hist_calls": final.hist_calls,
+        "hist_slots": Kb + (final.hist_calls - 1)
+        * (2 * Kb if cfg.hist_rebuild else Kb),
+        "hist_slots_filled": final.hist_slots_filled,
     }
     if cfg.has_categorical:
         # only emitted when categorical features exist, so downstream

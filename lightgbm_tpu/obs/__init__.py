@@ -9,11 +9,17 @@ Pillars, one import:
   (``dump_jsonl``), Prometheus-style text (``prometheus_text``), or
   the ``Booster.metrics()`` / ``GBDT.metrics_snapshot()`` APIs.
 - **Tracing** (obs/tracing.py): ``with obs.span("train/round",
-  round=i):`` — nested spans that record wall time (plus optional
-  device-synced time) into a Chrome-trace JSON viewable in Perfetto;
-  the serving dispatch loop adds per-batch span trees with rider
-  flow events, and rank-tagged exports merge into one gang-wide
-  timeline via ``scripts/trace_merge.py`` (obs/aggregate.py).
+  round=i):`` — nested spans that record wall time into a
+  Chrome-trace JSON viewable in Perfetto; the serving dispatch loop
+  adds per-batch span trees with rider flow events, and rank-tagged
+  exports merge into one gang-wide timeline via
+  ``scripts/trace_merge.py`` (obs/aggregate.py). Every span is ALSO a
+  ``jax.profiler.TraceAnnotation("lgbm/<name>")``, and device code is
+  wrapped in ``obs.scope(<name of LAYERS>)``: any profiler dump
+  (``tpu_profile_dir``) then holds the program's host spans and its
+  layers on the device's clock, and obs/trace_attr.py reduces it by
+  layer. The profiler session is the switch for everything timed on
+  the device.
 - **Device telemetry** (obs/telemetry.py): compile-request counting,
   program-cache-size and HBM gauges refreshed into the registry.
 - **Active plane** (obs/slo.py + obs/server.py + obs/aggregate.py):
@@ -24,18 +30,20 @@ Pillars, one import:
 
 OFF BY DEFAULT and engineered for ~zero cost when off: every
 instrumented hot path funnels through :func:`span` / :func:`inc` /
-:func:`observe`, whose disabled path is one bool check and a shared
-no-op context manager — no locks, no clocks, no allocation. Enabled
+:func:`observe`, whose disabled path is one bool check (and, for a
+span, a profiler annotation that is a no-op of under 1 us while no
+profiler session is open) — no locks, no clocks. There is no span
+site per row, per leaf or per tree. Enabled
 via ``Config`` knobs (``tpu_metrics=true``, ``tpu_trace_dir=DIR``,
 ``tpu_metrics_dump=PATH``, ``tpu_metrics_port=N``, ``tpu_slo_*``) or
 programmatically with :func:`enable`.
 
 Cold paths that must record regardless (restart/retry accounting, the
-benches, the utils/timer back-compat shim) pass ``force=True``.
+benches, the utils/timer back-compat shim, and the work counters of
+the grower and of ingest, once a chunk) pass ``force=True``.
 """
 from __future__ import annotations
 
-import contextlib
 import os
 import time
 from typing import Any, Callable, Dict, Optional
@@ -49,7 +57,7 @@ from .tracing import (export_chrome_trace, set_trace_rank, span_stack,
 
 __all__ = [
     "enable", "disable", "enabled", "any_enabled", "tracing_enabled",
-    "slo_enabled", "span", "inc", "set_gauge", "observe", "counter",
+    "slo_enabled", "span", "scope", "LAYERS", "inc", "set_gauge", "observe", "counter",
     "gauge", "histogram", "heartbeat", "retire_heartbeat",
     "set_heartbeat_file",
     "predict_instrumented", "registry", "snapshot", "dump_jsonl",
@@ -61,11 +69,10 @@ __all__ = [
 
 
 class _State:
-    __slots__ = ("metrics", "device_time", "slo")
+    __slots__ = ("metrics", "slo")
 
     def __init__(self) -> None:
         self.metrics = False
-        self.device_time = False
         self.slo = False
 
 
@@ -80,14 +87,56 @@ _state = _State()
 # the dead process's frozen ratios forever)
 _EPHEMERAL_PREFIXES = ("heartbeat.", "slo.", "predict.cache_hit_ratio")
 
-# shared no-op context manager for disabled spans: nullcontext is
-# reentrant and reusable, so ONE instance serves every disabled site
-_NULL_CM = contextlib.nullcontext()
+# Device scopes: the ONE table of names that may appear under "lgbm/"
+# inside a device program, each with its layer of PERF.md section 3.
+# obs/trace_attr.py reduces a profiler dump by these names.
+LAYERS: Dict[str, str] = {
+    "engine/gradients": "engine",
+    "engine/goss_sample": "engine",
+    "engine/goss_compact": "engine",
+    "engine/score_update": "engine",
+    "engine/valid_update": "engine",
+    "grower/histogram": "grower",
+    "grower/split_search": "grower",
+    "grower/partition": "grower",
+    "grower/leaf_values": "grower",
+    "ingest/assign": "ingest",
+}
+
+SCOPE_PREFIX = "lgbm/"
+
+
+def scope(name: str):
+    """``jax.named_scope("lgbm/" + name)`` for a name of :data:`LAYERS`
+    (anything else raises): metadata on the ops traced inside it, no
+    arithmetic and no schedule change."""
+    if name not in LAYERS:
+        raise KeyError(f"obs.scope: {name!r} is not in obs.LAYERS")
+    import jax
+    return jax.named_scope(SCOPE_PREFIX + name)
+
+
+_ANNOTATION = None
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation`` with the ``set`` of a span, made
+    at the first span so that importing obs never imports jax."""
+    global _ANNOTATION
+    from jax.profiler import TraceAnnotation
+
+    class _Annotation(TraceAnnotation):
+        __slots__ = ()
+
+        def set(self, **attrs) -> None:
+            self.set_metadata(**attrs)
+
+    _ANNOTATION = _Annotation
+    return _Annotation
 
 
 def enable(metrics: bool = True, trace_dir: Optional[str] = None,
            trace: Optional[bool] = None,
-           device_time: Optional[bool] = None,
            slo: Optional[bool] = None,
            slo_window_s: Optional[float] = None,
            slo_thresholds: Optional[Dict[str, float]] = None) -> None:
@@ -105,8 +154,6 @@ def enable(metrics: bool = True, trace_dir: Optional[str] = None,
         ensure_compile_listener()
     if trace or trace_dir:
         _tracing.enable_tracing(trace_dir)
-    if device_time is not None:
-        _state.device_time = bool(device_time)
     if slo or slo_window_s or slo_thresholds:
         _slo.enable(window_s=slo_window_s, thresholds=slo_thresholds)
         _state.slo = True
@@ -143,29 +190,31 @@ def any_enabled() -> bool:
 class _Span:
     """Reentrant-per-instance span context manager (one per call)."""
 
-    __slots__ = ("_t", "_force")
+    __slots__ = ("_t", "_force", "_ann")
 
-    def __init__(self, name: str, args: Dict[str, Any], sync,
-                 force: bool) -> None:
-        self._t = _tracing._SpanTimer(name, args, sync)
+    def __init__(self, name: str, args: Dict[str, Any], force: bool,
+                 ann) -> None:
+        self._t = _tracing._SpanTimer(name, args)
         self._force = force
+        self._ann = ann
 
     def __enter__(self) -> "_Span":
+        self._ann.__enter__()
         self._t.start()
         return self
 
     def set(self, **attrs) -> None:
         """Attach attributes discovered mid-span (e.g. whether a
         registry checkout was a cache hit) — they land in the trace
-        event recorded at exit. Callers must null-check the ``as``
-        value first: a disabled span is the shared nullcontext, whose
-        ``__enter__`` yields None."""
+        event recorded at exit (a disabled span takes them as the
+        profiler annotation's metadata)."""
         self._t.args.update(attrs)
 
     def __exit__(self, *exc) -> None:
         self._t.stop(_tracing.tracing_enabled(),
                      _observe_span if (_state.metrics or self._force)
                      else None)
+        self._ann.__exit__(*exc)
 
 
 def _observe_span(name: str, dur: float) -> None:
@@ -174,26 +223,22 @@ def _observe_span(name: str, dur: float) -> None:
         _slo.feed_hist(name, dur)
 
 
-def span(name: str, sync: Optional[Callable[[], Any]] = None,
-         force: bool = False, **attrs):
+def span(name: str, force: bool = False, **attrs):
     """Scoped phase timer: records a duration histogram under ``name``
     (when metrics are on) and a Chrome-trace event (when tracing is on).
 
-    ``sync``: optional callable (e.g. ``lambda:
-    jax.block_until_ready(x)``) invoked before the span closes when
-    ``device_time`` is enabled, splitting dispatch wall time from
-    device completion time in the trace args.
+    On EVERY call, on or off, the span is also a
+    ``jax.profiler.TraceAnnotation("lgbm/" + name, **attrs)``: a no-op
+    while no profiler session is open, and inside one the span lands on
+    the host plane of the dump, on the clock the device ops are on.
 
     ``force=True`` records even when observability is globally off
     (explicit-measurement callers: utils/timer shim, benches).
-
-    No-op (a shared null context manager) when everything is off.
     """
+    ann = (_ANNOTATION or _annotation_cls())(SCOPE_PREFIX + name, **attrs)
     if not (force or _state.metrics or _tracing.tracing_enabled()):
-        return _NULL_CM
-    return _Span(name, attrs,
-                 sync if (sync is not None and _state.device_time)
-                 else None, force)
+        return ann
+    return _Span(name, attrs, force, ann)
 
 
 # ---------------------------------------------------------------------------

@@ -135,7 +135,6 @@ def _pop() -> None:
 
 def record_event(name: str, start_monotonic: float, dur_s: float,
                  args: Optional[Dict[str, Any]] = None,
-                 device_s: Optional[float] = None,
                  tid: Optional[int] = None) -> None:
     """Append one complete event (called by ``obs.span`` on exit).
     ``tid`` overrides the recording thread's ident — retroactive
@@ -148,15 +147,15 @@ def record_event(name: str, start_monotonic: float, dur_s: float,
     and a tuple append under the GIL costs a fraction of a dict build
     + lock round-trip — dict materialization happens once, on the
     cold export/read path (:func:`events`). Shapes:
-    ``("X", name, ts_s, dur_s, tid, args|None, parent|None, depth,
-    device_s|None)`` and ``("s"|"f", name, flow_id, ts_s, tid,
+    ``("X", name, ts_s, dur_s, tid, args|None, parent|None, depth)``
+    and ``("s"|"f", name, flow_id, ts_s, tid,
     args|None)``."""
     stack = getattr(_tls, "stack", ())
     depth = len(stack) - 1
     _append(("X", name, start_monotonic, dur_s,
              (int(tid) if tid is not None
               else threading.get_ident() & 0x7FFFFFFF),
-             args, stack[-2] if depth > 0 else None, depth, device_s))
+             args, stack[-2] if depth > 0 else None, depth))
 
 
 def _append(rec: tuple) -> None:
@@ -187,7 +186,7 @@ def _materialize(rec: tuple, pid: int) -> Dict[str, Any]:
     """One raw buffer tuple -> Chrome-trace event dict (cold path)."""
     kind = rec[0]
     if kind == "X":
-        _k, name, ts, dur, tid, args, parent, depth, device_s = rec
+        _k, name, ts, dur, tid, args, parent, depth = rec
         ev: Dict[str, Any] = {
             "name": str(name), "ph": "X", "ts": ts * 1e6,
             "dur": max(dur, 0.0) * 1e6, "pid": pid, "tid": tid,
@@ -196,8 +195,6 @@ def _materialize(rec: tuple, pid: int) -> Dict[str, Any]:
         if parent is not None:
             a["parent"] = parent
             a["depth"] = depth
-        if device_s is not None:
-            a["device_s"] = device_s
         if a:
             ev["args"] = a
         return ev
@@ -306,15 +303,14 @@ def export_chrome_trace(path: Optional[str] = None) -> Optional[str]:
 
 
 class _SpanTimer:
-    """Internal helper used by ``obs.span``: measures wall (and
-    optionally device-synced) duration and feeds trace + metrics."""
+    """Internal helper used by ``obs.span``: measures wall duration
+    and feeds trace + metrics."""
 
-    __slots__ = ("name", "args", "sync", "t0", "depth")
+    __slots__ = ("name", "args", "t0", "depth")
 
-    def __init__(self, name: str, args: Dict[str, Any], sync) -> None:
+    def __init__(self, name: str, args: Dict[str, Any]) -> None:
         self.name = name
         self.args = args
-        self.sync = sync
         self.t0 = 0.0
         self.depth = 0
 
@@ -323,17 +319,9 @@ class _SpanTimer:
         self.t0 = time.monotonic()
 
     def stop(self, record_trace: bool, observe) -> None:
-        device_s = None
-        if self.sync is not None:
-            t_dispatch = time.monotonic() - self.t0
-            try:
-                self.sync()
-            except Exception:
-                pass
-            device_s = time.monotonic() - self.t0 - t_dispatch
         dur = time.monotonic() - self.t0
         if record_trace:
-            record_event(self.name, self.t0, dur, self.args, device_s)
+            record_event(self.name, self.t0, dur, self.args)
         _pop()
         if observe is not None:
             observe(self.name, dur)

@@ -241,6 +241,8 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
             jax.ShapeDtypeStruct((F_pad, out_cols), jnp.int8),
             jax.ShapeDtypeStruct((C_pad, out_cols), jnp.float32),
         ],
+        # the device op's name, pinned: profile readers match it
+        name="compact_rows",
     )(aligned, rem, dest.reshape(1, n), bins_t, vals_t)
     # Pallas outputs are uninitialized; zero everything past the last
     # block's write window so downstream scans see zero contributions

@@ -228,9 +228,12 @@ def _assign_chunk(*args, **kwargs):
         import functools
 
         import jax
+
+        from .. import obs
         _ASSIGN_JIT = functools.partial(
             jax.jit, static_argnames=("out_dtype", "emit_transposed",
-                                      "any_cat"))(_assign_chunk_impl)
+                                      "any_cat"))(
+            obs.scope("ingest/assign")(_assign_chunk_impl))
     return _ASSIGN_JIT(*args, **kwargs)
 
 
@@ -310,28 +313,34 @@ def device_ingest(X: np.ndarray, bin_mappers, used_features,
     row_parts = []
     t_parts = []
     pending = None
-    track = obs.any_enabled()
+    # the four spans a chunk say where ingest's time goes: host slicing
+    # and conversion, the link, the device program's dispatch, or the
+    # wait on the two-deep queue
     with obs.span("ingest/device", rows=n, features=Fu):
         for s in range(0, max(n, 1), R):
             e = min(s + R, n)
-            blk = host_prep(s, e)
-            chunk_dev = jax.device_put(blk)
-            if track:
-                # H2D traffic accounting: every streamed raw chunk
-                # (padded f32) crosses the host->device link once
-                obs.inc("ingest.h2d_bytes", int(blk.nbytes))
-                obs.inc("ingest.chunks")
-            res = _assign_chunk(chunk_dev, *dev_tables,
-                                out_dtype=out_jdtype,
-                                emit_transposed=emit_transposed,
-                                any_cat=any_cat)
+            with obs.span("ingest/host_prep"):
+                blk = host_prep(s, e)
+            with obs.span("ingest/h2d"):
+                chunk_dev = jax.device_put(blk)
+            # work counters, always kept (once a chunk): every streamed
+            # raw chunk (padded f32) crosses the host->device link once
+            obs.inc("ingest.cells", (e - s) * Fu, force=True)
+            obs.inc("ingest.h2d_bytes", int(blk.nbytes), force=True)
+            obs.inc("ingest.chunks", force=True)
+            with obs.span("ingest/assign_dispatch"):
+                res = _assign_chunk(chunk_dev, *dev_tables,
+                                    out_dtype=out_jdtype,
+                                    emit_transposed=emit_transposed,
+                                    any_cat=any_cat)
             row_parts.append(res[0])
             if emit_transposed:
                 t_parts.append(res[1])
             # double buffer: keep at most two chunks in flight so host
             # prep overlaps device compute without unbounded queueing
             if pending is not None:
-                pending.block_until_ready()
+                with obs.span("ingest/wait"):
+                    pending.block_until_ready()
             pending = res[0]
     bins = (row_parts[0] if len(row_parts) == 1
             else jnp.concatenate(row_parts, axis=0))[:n]

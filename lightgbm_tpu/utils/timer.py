@@ -1,4 +1,4 @@
-"""Back-compat shim over the observability subsystem + profiler hooks.
+"""Back-compat shim over the observability subsystem.
 
 The phase-timer implementation that used to live here (its own
 ``_ACCUM``/``_COUNT`` dicts on ``perf_counter``) is gone: the obs
@@ -13,14 +13,12 @@ Reference lineage unchanged: the reference's global timer macros
 (include/LightGBM/utils/log.h, UNVERIFIED — empty mount, see SURVEY.md
 banner) printing per-phase timings in debug builds.
 
-The ``jax.profiler`` hooks (deep device-side kernel traces for
-TensorBoard/xprof via ``tpu_profile_dir``) still live here; obs spans
-cover the HOST orchestration the device profiler does not attribute.
+The profiler session itself is ``tpu_profile_dir`` (engine.train); every
+obs span is an annotation in its dump (obs/__init__.py).
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Dict, Iterator, Optional
+from typing import Dict
 
 from . import log
 
@@ -60,27 +58,3 @@ def log_timers() -> None:
     hists = [m for m in registry().metrics() if isinstance(m, Histogram)]
     for m in sorted(hists, key=lambda m: -m.sum):
         log.debug(f"{m.name}: {m.sum:.3f}s ({m.count} calls)")
-
-
-def start_trace(log_dir: str) -> None:
-    """Begin a jax.profiler trace (view with TensorBoard/xprof)."""
-    import jax
-    jax.profiler.start_trace(log_dir)
-
-
-def stop_trace() -> None:
-    import jax
-    jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def trace(log_dir: Optional[str]) -> Iterator[None]:
-    """Trace a block when ``log_dir`` is set; no-op otherwise."""
-    if not log_dir:
-        yield
-        return
-    start_trace(log_dir)
-    try:
-        yield
-    finally:
-        stop_trace()

@@ -182,6 +182,12 @@ def gauge(name):
             return m.get("value")
     return None
 
+def total(name):
+    """Sum of one counter over its label sets."""
+    vals = [m.get("value") or 0.0 for m in snap.get("metrics", [])
+            if m.get("name") == name]
+    return sum(vals) if vals else None
+
 def serve_stat(key):
     """Read one field off the serving smoke's final JSON record (the
     queue-wait p99 decomposition signal); a failed/absent smoke run
@@ -200,11 +206,11 @@ print("obs " + json.dumps({
     "peak_hbm_gib": gauge("bench.peak_hbm_gib"),
     "bench_iters_per_sec": gauge("bench.iters_per_sec"),
     "predict_programs": gauge("compile.predict_programs"),
-    # rows the training histogram scans touched (hist.rows_scanned is a
-    # counter, but the snapshot reader is name-based either way):
-    # masked = n_pad x rounds; a partition regression shows up here as
-    # this number jumping back to the masked product
-    "hist_rows_scanned": gauge("hist.rows_scanned"),
+    # columns the training histogram calls were handed (the
+    # hist.cols_scanned counter, summed over sampled=0|1): masked =
+    # n_pad x rounds; a partition regression shows up here as this
+    # number jumping back to the masked product
+    "hist_rows_scanned": total("hist.cols_scanned"),
     "hist_partition": gauge("bench.hist_partition"),
     # loop-state %copy share of device busy (trace attribution,
     # scripts/trace_attr.py) — present when the bench ran with

@@ -113,6 +113,35 @@ def test_compact_rows_compiles(one_chip, F, C):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("kernel", ["multi_leaf_histogram",
+                                    "compact_rows"])
+def test_pallas_call_lowers_with_its_pinned_name(one_chip, kernel):
+    """The benchmark's kernel metrics match the device op by name
+    (``^multi_leaf_histogram(\\.\\d+)?$``, ``^compact_rows(...)``). The
+    name is the ``pallas_call``'s own ``name=``: called from a function
+    of another name, the kernel still lowers and compiles under it."""
+    from lightgbm_tpu.ops import compact, pallas_histogram
+    s = functools.partial(_sds, one_chip)
+    if kernel == "multi_leaf_histogram":
+        def renamed(*a):
+            return pallas_histogram.multi_leaf_histogram.__wrapped__(
+                *a, num_bins=256, rows_per_block=4096, int_mode=True)
+        args = (s((13, SLAB), jnp.int8), s((3, SLAB), jnp.float32),
+                s((SLAB,), jnp.int32), s((8,), jnp.int32))
+    else:
+        out_cols = compact.compaction_out_cols(int(SLAB * 0.3), 1024, 4096)
+
+        def renamed(*a):
+            return compact.compact_rows.__wrapped__(
+                *a, out_cols=out_cols, rows_per_block=1024)
+        args = (s((13, SLAB), jnp.int8), s((4, SLAB), jnp.float32),
+                s((SLAB,), jnp.int32), s((SLAB // 1024,), jnp.int32),
+                s((SLAB // 1024,), jnp.int32))
+    lowered = jax.jit(renamed).lower(*args)
+    assert f'kernel_name = "{kernel}"' in lowered.as_text()
+    assert f"%{kernel}." in _compiled_text(lowered)
+
+
 # ---------------------------------------------------------------------
 # the tree grower at Higgs-1M: n=2^20, F=28, B=256, L=127, Kb=32
 # ---------------------------------------------------------------------
@@ -153,6 +182,15 @@ def test_grow_tree_compiles(one_chip, as_tpu, variant):
         s((N, F), jnp.uint8), vals, s((F,), jnp.int32),
         s((F,), jnp.bool_), s((F,), jnp.bool_), cfg, **kw))
     assert "tpu_custom_call" in text
+    # the chip's compiler keeps the grower's scopes as op metadata,
+    # the kernel's included
+    for scope in ("histogram", "split_search", "partition",
+                  "leaf_values"):
+        assert f"lgbm/grower/{scope}" in text, scope
+    kernel_line = next(ln for ln in text.splitlines()
+                       if "%multi_leaf_histogram." in ln
+                       and "custom-call(" in ln)
+    assert "lgbm/grower/histogram" in kernel_line
 
 
 def test_grow_tree_compiles_under_shard_map_on_four_chips(topo, as_tpu):

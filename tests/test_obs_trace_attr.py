@@ -3,7 +3,10 @@
 The attribution pipeline is pure parsing — so it is pinned against a
 SYNTHETIC XSpace dump encoded with the same protobuf wire format the
 reader decodes: known per-op durations in, exact ``copy_share`` /
-``wall_busy_gap_ms`` out. Also covers the degradation contract (a
+``wall_busy_gap_ms`` out; busy is a union of leaf ops (a ``while``
+and its body are not counted twice) and never passes the window.
+tests/test_obs_layers.py holds the join to scopes against real dumps.
+Also covers the degradation contract (a
 host-only trace — the CPU backend's shape — must report "nothing to
 attribute", never crash the run that produced it), the gauge feed into
 the obs registry, and the CLI.
@@ -45,13 +48,9 @@ def _vfield(num: int, value: int) -> bytes:
     return _varint(num << 3) + _varint(value)
 
 
-def _event(mid: int, offset_ps: int, duration_ps: int,
-           occurrences: int = 0) -> bytes:
-    buf = (_vfield(1, mid) + _vfield(2, offset_ps)
-           + _vfield(3, duration_ps))
-    if occurrences:
-        buf += _vfield(5, occurrences)
-    return buf
+def _event(mid: int, offset_ps: int, duration_ps: int) -> bytes:
+    return (_vfield(1, mid) + _vfield(2, offset_ps)
+            + _vfield(3, duration_ps))
 
 
 def _line(name: str, timestamp_ns: int, events) -> bytes:
@@ -77,9 +76,9 @@ def _plane(name: str, lines, metadata) -> bytes:
 
 def _synthetic_xspace() -> bytes:
     """One host plane (must be ignored) + one device plane whose
-    "XLA Ops" line carries: fusion.1 60 ms, copy.3 25 ms twice via
-    num_occurrences=2 at 12.5 ms, copy-start.4 10 ms, dynamic-slice.9
-    5 ms -> busy 100 ms, copy 35 ms, copy_share 0.35."""
+    "XLA Ops" line carries: fusion.1 60 ms, copy.3 twice at 12.5 ms,
+    copy-start.4 10 ms, dynamic-slice.9 5 ms -> busy 100 ms, copy
+    35 ms, copy_share 0.35."""
     MS = 1_000_000_000  # ps per ms
     host = _plane("/host:CPU", [
         _line("python threads", 0, [_event(1, 0, 5 * MS)]),
@@ -87,7 +86,8 @@ def _synthetic_xspace() -> bytes:
     dev = _plane("/device:TPU:0 (fake)", [
         _line("XLA Ops", 1_000, [
             _event(1, 0, 60 * MS),
-            _event(2, 60 * MS, 12_500_000_000, occurrences=2),
+            _event(2, 60 * MS, 12_500_000_000),
+            _event(2, 72_500_000_000, 12_500_000_000),
             _event(3, 85 * MS, 10 * MS),
             _event(4, 95 * MS, 5 * MS),
         ]),
@@ -117,9 +117,12 @@ def test_parse_and_aggregate_synthetic_dump():
     agg = aggregate_ops(planes)
     assert agg is not None
     assert agg["device_plane"] == "/device:TPU:0 (fake)"
-    # name resolution through the metadata map, occurrences multiplied
-    assert agg["ops"]["%copy.3"] == [25_000_000_000.0, 2]
+    # name resolution through the metadata map; an op is its short
+    # name and its scope (none in this dump)
+    assert agg["ops"][("copy.3", "unscoped")] == [25_000_000_000.0, 2]
     assert agg["busy_ps"] == 100_000_000_000
+    # no lgbm/ annotation in the dump: the window is first op to last
+    assert agg["window_ps"] == 100_000_000_000
     # copy.3 + copy-start.4 count as copies; dynamic-slice does not
     assert agg["copy_ps"] == 35_000_000_000
 
@@ -198,11 +201,70 @@ def test_host_only_trace_degrades_not_crashes(tmp_path):
     assert not attribute(str(g))["found"]
 
 
-def test_profile_gauges_feed_obs_registry(dump_dir):
+def test_busy_is_a_union_of_leaves_and_never_passes_the_window(tmp_path):
+    """A scan's ``while`` encloses its body: the old reader summed
+    durations, counted both, and could report more busy than wall.
+    Here the while keeps only its self time, busy is the leaves' union
+    cut to the window (a named host annotation), and the leading and
+    trailing host waits show as idle gaps."""
+    US = 1_000_000
+    dev = _plane("/device:TPU:0", [_line("XLA Ops", 0, [
+        _event(1, 20 * US, 100 * US),      # while.1  20..120, encloses
+        _event(2, 30 * US, 30 * US),       #   fusion.2  30..60
+        _event(3, 70 * US, 40 * US),       #   copy.3    70..110
+        _event(2, 150 * US, 100 * US),     # fusion.2 150..250, past the end
+    ])], [_metadata_entry(1, "%while.1 = (s32[]) while(...)"),
+          _metadata_entry(2, "fusion.2"), _metadata_entry(3, "copy.3")])
+    host = _plane("/host:CPU", [_line("python", 0, [
+        _event(1, 0, 200 * US),            # lgbm/train/fused_chunk 0..200
+        _event(2, 120 * US, 80 * US),      #   lgbm/train/fetch_trees
+    ])], [_metadata_entry(1, "lgbm/train/fused_chunk"),
+          _metadata_entry(2, "lgbm/train/fetch_trees")])
+    f = tmp_path / "nested.xplane.pb"
+    f.write_bytes(_field(1, dev) + _field(1, host))
+    res = attribute(str(f))
+    assert res["window"] == "lgbm/train/fused_chunk"
+    assert res["wall_ms"] == pytest.approx(0.2)
+    # leaves inside the window: 30..60, 70..110, 150..200
+    assert res["busy_ms"] == pytest.approx(0.12)
+    assert res["busy_ms"] <= res["wall_ms"]
+    ops = {o["name"]: (o["ms"], o["calls"]) for o in res["ops"]}
+    assert ops["while.1"] == (pytest.approx(0.03), 1)    # 100 - 30 - 40
+    assert ops["fusion.2"] == (pytest.approx(0.13), 2)   # self, not cut
+    assert [(lay["scope"], lay["ms"]) for lay in res["layers"]] \
+        == [("unscoped", pytest.approx(0.12))]
+    gaps = [(g["name"], g["ms"]) for g in res["idle_gaps"]]
+    assert gaps == [
+        ("lgbm/train/fetch_trees", pytest.approx(0.04)),    # 110..150
+        ("lgbm/train/fused_chunk", pytest.approx(0.03)),    # 0..30
+        ("lgbm/train/fused_chunk", pytest.approx(0.01))]    # 60..70
+    assert res["busy_ms"] + sum(ms for _n, ms in gaps) \
+        == pytest.approx(res["wall_ms"])
+    spans = {sp["name"]: (sp["ms"], sp["count"]) for sp in res["spans"]}
+    assert spans["lgbm/train/fetch_trees"] == (pytest.approx(0.08), 1)
+    # a window that is not in the dump is nothing to attribute
+    assert not attribute(str(f), window="lgbm/train/step")["found"]
+
+
+def test_profile_gauges_feed_obs_registry(dump_dir, monkeypatch):
     from lightgbm_tpu import obs
+    from lightgbm_tpu.obs import metrics as obs_metrics
+
+    # a registry this test owns: whatever ran before it in the process
+    # (forced gauges of another profile, an enabled pillar) is not here
+    monkeypatch.setattr(obs_metrics, "_REGISTRY",
+                        obs_metrics.MetricsRegistry())
     res = profile_gauges(dump_dir, iters=10, wall_ms=150.0)
     assert res["found"]
-    snap = obs.snapshot()
+    snap = obs.registry().snapshot()
+    assert {m["name"] for m in snap["metrics"]} == {
+        "train.copy_share", "train.comm_share", "train.layer_ms",
+        "train.wall_busy_gap_ms"}
+    # the synthetic dump names no scope: all 100 ms, 10 ms an
+    # iteration, are unscoped
+    (layer,) = [m for m in snap["metrics"] if m["name"] == "train.layer_ms"]
+    assert layer["labels"] == {"scope": "unscoped"}
+    assert layer["value"] == pytest.approx(10.0)
     vals = {m["name"]: m["value"] for m in snap["metrics"]
             if not m.get("labels")}
     assert vals["train.copy_share"] == pytest.approx(0.35)
@@ -234,6 +296,7 @@ def test_cli_text_and_json(dump_dir):
     assert out2.returncode == 0, out2.stderr
     assert "%copy (loop-state copies)" in out2.stdout
     assert "5.00 ms/iter" in out2.stdout
+    assert "unscoped" in out2.stdout and "device busy" in out2.stdout
     # nothing to attribute -> exit 3 (the CPU-trace contract)
     out3 = subprocess.run(
         [sys.executable, os.path.join(REPO_ROOT, "scripts",

@@ -296,7 +296,7 @@ def test_partition_parallel_learners(learner):
 # ---------------------------------------------------------------------------
 
 def test_rows_scanned_metric():
-    """hist.rows_scanned: masked = n_pad x realized rounds; the
+    """hist.cols_scanned: masked = n_pad x realized rounds; the
     partitioned path must record strictly fewer once spans engage.
     (leaf_batch is kept small so the pow2 ladder has budgets under
     n/Kb at this test size — with the 32-lane default the spans only
@@ -307,11 +307,11 @@ def test_rows_scanned_metric():
     extra = {"tpu_leaf_batch": 2, "tpu_metrics": True}
     b0, _ = _model_text(X, y, {**extra, "tpu_hist_partition": "false"},
                         rounds=3)
-    masked = obs.registry().counter("hist.rows_scanned").value
+    masked = obs.registry().counter("hist.cols_scanned", sampled=0).value
     obs.reset()
     b1, _ = _model_text(X, y, {**extra, "tpu_hist_partition": "true"},
                         rounds=3)
-    part = obs.registry().counter("hist.rows_scanned").value
+    part = obs.registry().counter("hist.cols_scanned", sampled=0).value
     assert masked > 0 and part > 0
     assert part < masked
     n_pad = b0.engine.data.n_pad
