@@ -31,6 +31,7 @@ from ..metric import Metric, metrics_for_config
 from ..objective import Objective, create_objective
 from ..ops.histogram import pad_rows
 from ..ops.predict import forest_predict_binned, tree_predict_binned
+from ..ops.select import PASSES as SELECT_PASSES, kth_largest, kth_smallest
 from ..tree import Tree
 from ..utils import log
 from ..utils.prefetch import InflightWindow
@@ -1343,14 +1344,6 @@ class GBDT:
         _k_rand_list = [int(v * other_rate) for v in _local_valid]
         goss_k_top_tbl = jnp.asarray(_k_top_list, jnp.int32)
         goss_k_rand_tbl = jnp.asarray(_k_rand_list, jnp.int32)
-        # static top-k bounds (max over shards): the threshold
-        # extraction below selects ORDER STATISTICS, so the full n-row
-        # %sort the round-5 trace flagged (~4% of device busy) is
-        # replaced by lax.top_k over the bounding k — same selected
-        # values bit-for-bit, no total order materialized. Near-1.0
-        # rates keep the sort (top_k at k ~ n IS a sort).
-        _k_top_max = max(_k_top_list)
-        _k_rand_max = max(_k_rand_list)
         # goss.rows_in / goss.rows_kept an iteration (_count_work): the
         # rows the thresholds rank and the exact count they keep
         self._goss_rows = (
@@ -1374,15 +1367,9 @@ class GBDT:
             k_top = goss_k_top_tbl[sid]
             k_rand = goss_k_rand_tbl[sid].astype(jnp.float32)
             k_rest = jnp.maximum(n_valid - k_top, 1.0)
-            if _k_top_max < n_local:
-                # the k_top-th largest metric: index k_top-1 of the
-                # descending top-k pool == sorted_m[n_local - k_top]
-                top_pool = jax.lax.top_k(metric, _k_top_max)[0]
-                thresh = top_pool[jnp.clip(k_top, 1, _k_top_max) - 1]
-            else:
-                sorted_m = jnp.sort(metric)
-                thresh_idx = jnp.clip(n_local - k_top, 0, n_local - 1)
-                thresh = sorted_m[thresh_idx]
+            # the k_top-th largest metric, == sort(metric)[n_local -
+            # k_top], by a counting select: no row is ordered
+            thresh = kth_largest(metric, k_top)
             # EXACT top-k (goss.hpp partitions exactly k rows): ties at
             # the threshold break by row index via a cumulative count,
             # so the selected count is deterministic — required both for
@@ -1404,21 +1391,9 @@ class GBDT:
             k_cap = jnp.minimum(k_rand, k_rest).astype(jnp.int32)
             u = jnp.where(rest, jax.random.uniform(key, (n_local,)),
                           jnp.inf)
-            if 0 < _k_rand_max < n_local:
-                # the k_cap-th SMALLEST draw: ascending top-k of -u
-                # bounded by the static max over shards; k_cap = 0
-                # indexes the minimum, matching the clip below (picked
-                # is force-emptied by the k_cap > 0 mask either way)
-                u_small = -jax.lax.top_k(-u, _k_rand_max)[0]
-                u_thresh = u_small[jnp.clip(k_cap - 1, 0,
-                                            _k_rand_max - 1)]
-            elif _k_rand_max == 0:
-                # other_rate rounds to zero rows everywhere: nothing is
-                # ever picked; any threshold value works
-                u_thresh = jnp.float32(0.0)
-            else:
-                u_sorted = jnp.sort(u)
-                u_thresh = u_sorted[jnp.clip(k_cap - 1, 0, n_local - 1)]
+            # the k_cap-th SMALLEST draw; k_cap = 0 reads the minimum
+            # (picked is force-emptied by the k_cap > 0 mask below)
+            u_thresh = kth_smallest(u, k_cap)
             strictly = rest & (u < u_thresh)
             at_t = rest & (u == u_thresh)
             need = k_cap - jnp.sum(strictly).astype(jnp.int32)
@@ -1429,6 +1404,8 @@ class GBDT:
                        + picked.astype(jnp.float32) * amp)
             mask_count = (is_top | picked).astype(jnp.float32)
             return mask_gh, mask_count
+
+        self._goss_masks = goss_masks
 
         def step_goss_impl(bins, bins_t, label, weight, score, valid_mask,
                            allowed, cegb_pen, key, cegb_U=None):
@@ -2272,6 +2249,9 @@ class GBDT:
             obs.inc("goss.rows_in", float(rows_in * n_iters), force=True)
             obs.inc("goss.rows_kept", float(rows_kept * n_iters),
                     force=True)
+            # full reads of the rows by goss_masks' two counting selects
+            obs.inc("goss.select_passes",
+                    float(2 * SELECT_PASSES * n_iters), force=True)
         return host
 
     def _append_host_trees(self, host: Dict[str, np.ndarray]) -> None:

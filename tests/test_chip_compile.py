@@ -227,6 +227,43 @@ def test_grow_tree_compiles_under_shard_map_on_four_chips(topo, as_tpu):
 
 
 # ---------------------------------------------------------------------
+# the engine's GOSS sample
+# ---------------------------------------------------------------------
+def test_goss_sample_program_orders_no_row(one_chip):
+    """``goss_masks`` at the benchmark cell's length (57,503,744 rows,
+    shapes only): its two thresholds come from the counting select, so
+    the optimised program holds no sort and no top-k, and every pass of
+    a select is ONE fusion that reads the rows once and leaves only
+    counts (``goss.select_passes`` counts those reads)."""
+    import re
+
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.boosting.gbdt import GBDT
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.ops.select import PASSES
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(4096, 4))
+    # the closure's k's come from this small table; they are data of
+    # the program (a table lookup), its shapes are the arguments'
+    eng = GBDT(Config({"objective": "binary", "verbosity": -1,
+                       "data_sample_strategy": "goss"}),
+               lgb.Dataset(X, label=(X[:, 0] > 0).astype(float)))
+    s = functools.partial(_sds, one_chip)
+    n = 57_503_744
+    text = _compiled_text(jax.jit(eng._goss_masks).lower(
+        s((n,), jnp.float32), s((n,), jnp.float32), s((n,), jnp.float32),
+        s((2,), jnp.uint32)))
+    assert not re.search(r"\bsort\(", text)
+    assert not re.search(r"top-?k", text, re.I)
+    # fusions whose every output is an int32 scalar: the select's
+    # passes. XLA folds each select's first pass into the fusion that
+    # writes its input, hence PASSES - 1 a select.
+    counts_only = re.findall(
+        r"= \((?:s32\[\]\S*,? ?(?:/\*index=\d+\*/)?)+\) fusion\(", text)
+    assert len(counts_only) == 2 * (PASSES - 1), len(counts_only)
+
+
+# ---------------------------------------------------------------------
 # ingest and serving
 # ---------------------------------------------------------------------
 def test_ingest_chunk_program_compiles(one_chip):

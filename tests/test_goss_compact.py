@@ -7,6 +7,7 @@ fixed-size buffer — the SAME sample (same RNG stream), so models must
 match the masked path up to float accumulation order.
 """
 import numpy as np
+import pytest
 
 import lightgbm_tpu as lgb
 
@@ -90,6 +91,96 @@ def test_compact_engine_flag_and_fallbacks():
     assert not eng3._use_goss_compact
 
 
+def _tied_table(n):
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(n, 4))
+    # many duplicated rows -> tied gradients/hessians
+    X[n // 2 - 48:] = X[:n - (n // 2 - 48)]
+    y = (X[:, 0] > 0).astype(float)
+    return X, y
+
+
+def _goss_oracle(metric, valid, u, k_top, k_rand):
+    """goss_masks as it was before the counting select, in numpy: sort,
+    read the threshold, break its ties by row index, then the k
+    smallest draws among the rest the same way."""
+    n = len(metric)
+    thresh = np.sort(metric)[np.clip(n - k_top, 0, n - 1)]
+    above = (metric > thresh) & valid
+    tie = (metric == thresh) & valid
+    is_top = above | (tie & (np.cumsum(tie) <= k_top - above.sum()))
+    rest = valid & ~is_top
+    k_cap = int(min(k_rand, max(valid.sum() - k_top, 1)))
+    u = np.where(rest, u, np.float32(np.inf))
+    u_thresh = np.sort(u)[np.clip(k_cap - 1, 0, n - 1)]
+    strictly = rest & (u < u_thresh)
+    at_t = rest & (u == u_thresh)
+    picked = (strictly | (at_t & (np.cumsum(at_t) <= k_cap - strictly.sum()))
+              ) & (k_cap > 0)
+    return is_top, picked
+
+
+@pytest.mark.parametrize("step,n,top_rate,other_rate", [
+    ("masked", 4096, 0.25, 0.15),
+    ("compact", 32768, 0.25, 0.15),
+    ("masked", 4096, 0.7, 0.3),        # rates summing to 1: every row kept
+    ("masked", 4096, 0.999, 0.3),      # k_rand beyond the rest: capped
+    ("masked", 4096, 1.0, 0.0),        # k_top = n (the jnp.sort branch)
+    ("masked", 4096, 0.0, 1.0),        # k_top floored at 1, k_rand = n
+    ("compact", 65536, 0.45, 0.3),
+])
+def test_goss_masks_pick_the_sorted_formulations_rows(step, n, top_rate,
+                                                      other_rate):
+    """The counting select changes HOW the two thresholds are found, not
+    which rows they keep: ``goss_masks`` marks the rows the sort-based
+    formulation marked, and the step that runs it grows its tree on
+    exactly those rows (every leaf's count, not just the total)."""
+    import jax
+    from lightgbm_tpu.boosting.gbdt import GBDT
+    from lightgbm_tpu.config import Config
+    X, y = _tied_table(n)
+    cfg = Config({"objective": "binary", "num_leaves": 7,
+                  "data_sample_strategy": "goss", "learning_rate": 0.5,
+                  "top_rate": top_rate, "other_rate": other_rate,
+                  "tpu_goss_compact": step == "compact", "verbosity": -1})
+    eng = GBDT(cfg, lgb.Dataset(X, label=y))
+    assert (eng._step_goss_compact is not None) == (step == "compact")
+    for _ in range(3):                 # GOSS starts at round 1 / 0.5
+        eng.train_one_iter()
+    d = eng.data
+    valid = np.asarray(d.valid_mask) > 0
+    n_valid = int(valid.sum())
+    assert n_valid == n
+    k_top = max(1, int(n_valid * top_rate))
+    k_rand = int(n_valid * other_rate)
+    # what the step about to run will see: its key, its gradients
+    key = jax.random.PRNGKey(cfg.objective_seed + eng.iter_)
+    _, km = jax.random.split(key)
+    g, h = eng.objective.get_gradients(eng.score[:, 0], d.label, d.weight)
+    mask_gh, mask_count = jax.jit(eng._goss_masks)(g, h, d.valid_mask, km)
+    metric = np.abs(np.asarray(g) * np.asarray(h)) * valid
+    # heavy ties, or the table does not test the tie-break
+    assert len(np.unique(metric[valid])) < 0.6 * n_valid
+    u = np.asarray(jax.random.uniform(km, (len(metric),)))
+    is_top, picked = _goss_oracle(metric, valid, u, k_top, k_rand)
+    assert is_top.sum() == k_top
+    assert picked.sum() == min(k_rand, n_valid - k_top)
+    amp = np.float32((1.0 - top_rate) / max(other_rate, 1e-12))
+    np.testing.assert_array_equal(np.asarray(mask_count) > 0,
+                                  is_top | picked)
+    np.testing.assert_array_equal(
+        np.asarray(mask_gh),
+        is_top.astype(np.float32) + picked.astype(np.float32) * amp)
+    # and the step itself: each leaf holds the oracle's rows
+    eng.train_one_iter()
+    t = eng.models[-1]
+    leaf = t.predict_leaf_raw(X)
+    want = np.bincount(leaf[(is_top | picked)[:n]],
+                       minlength=t.num_leaves)
+    np.testing.assert_array_equal(
+        np.asarray(t.leaf_count[:t.num_leaves], np.int64), want)
+
+
 def test_goss_selects_exact_counts():
     """GOSS parity property (goss.hpp): exactly floor(a*n_valid) top
     rows and exactly floor(b*n_valid) random rows are selected every
@@ -97,12 +188,8 @@ def test_goss_selects_exact_counts():
     heavily tied |g*h| metrics."""
     from lightgbm_tpu.boosting.gbdt import GBDT
     from lightgbm_tpu.config import Config
-    rng = np.random.default_rng(5)
     n = 4096
-    X = rng.normal(size=(n, 4))
-    # many duplicated rows -> tied gradients/hessians
-    X[2000:] = X[:2096]
-    y = (X[:, 0] > 0).astype(float)
+    X, y = _tied_table(n)
     ds = lgb.Dataset(X, label=y)
     cfg = Config({"objective": "binary", "num_leaves": 7,
                   "data_sample_strategy": "goss", "learning_rate": 0.5,
