@@ -531,6 +531,9 @@ class GBDT:
                     self.bundle_plan = plan_bundles(nb_logical,
                                                     default_bins, multi)
                     self.has_bundles = True
+                    obs.inc("bundle.groups", len(multi), force=True)
+                    obs.inc("bundle.features_bundled",
+                            sum(len(b) for b in multi), force=True)
                     log.info(
                         f"EFB: bundled {sum(len(b) for b in multi)} "
                         f"features into {len(multi)} bundles "
@@ -2231,6 +2234,7 @@ class GBDT:
                  for k in ("hist_rows", "hist_calls", "hist_slots",
                            "hist_slots_filled")}
         cols = total["hist_rows"]
+        label = int(bool(sampled))
         for name, value in (
                 # columns the calls were handed: the static buffer
                 # length, or the elected spans under hist_partition
@@ -2242,7 +2246,16 @@ class GBDT:
                 # the kernel's VPU work by its own account: one compare
                 # a (column, padded feature, bin)
                 ("hist.onehot_elems", cols * self._hist_onehot_per_col)):
-            obs.inc(name, value, force=True, sampled=int(bool(sampled)))
+            obs.inc(name, value, force=True, sampled=label)
+        # splits these trees made, and how many of them are set-splits
+        n_nodes = host["num_leaves"][..., None] - 1
+        obs.inc("split.chosen", float(np.sum(n_nodes)), force=True,
+                sampled=label)
+        if "is_cat" in host:
+            live = np.arange(host["is_cat"].shape[-1]) < n_nodes
+            obs.inc("split.chosen_cat",
+                    float(np.sum(host["is_cat"].astype(bool) & live)),
+                    force=True, sampled=label)
         if sampled:
             n_iters = host["num_leaves"].size // self.num_class
             rows_in, rows_kept = self._goss_rows
@@ -2268,6 +2281,10 @@ class GBDT:
                 if len(newly):
                     self._cegb_used[newly] = True
                     self._cegb_pen_cache = None   # refresh on next step
+            if t.cat_threshold is not None:
+                # uint32 words of the model's category-VALUE bitsets
+                obs.inc("tree.cat_bitset_words", len(t.cat_threshold),
+                        force=True)
             self.models.append(t)
         self._invalidate_forest_cache()
 

@@ -276,8 +276,30 @@ class Dataset:
         return [f"Column_{i}" for i in range(n_features)]
 
     def _resolve_categorical(self, names: List[str]) -> List[int]:
+        """Column indices of the categorical features: the constructor's
+        ``categorical_feature`` where it is given, else the one in
+        ``params`` (any of its aliases; a list, or LightGBM's
+        ``"0,1,2"`` / ``"name:c1,c2"`` string), else pandas category
+        dtypes. Given both ways the argument wins with a warning, as
+        upstream (basic.py Dataset._lazy_init)."""
+        from ..config import _ALIASES
+        from_params = next(
+            (v for k, v in self.params.items()
+             if _ALIASES.get(k, k) == "categorical_feature"), None)
+        if isinstance(from_params, str):
+            by_name = from_params.startswith("name:")
+            from_params = [
+                c if by_name else int(c) for c in
+                (from_params[5:] if by_name else from_params).split(",")
+                if c.strip()]
         cf = self.categorical_feature
         if cf == "auto" or cf is None:
+            cf = from_params or None
+        elif from_params:
+            log.warning("categorical_feature in the Dataset's params is "
+                        "ignored: the categorical_feature argument "
+                        "is given and wins")
+        if cf is None:
             # pandas category dtype auto-detection
             if hasattr(self.data, "dtypes"):
                 return [i for i, dt in enumerate(self.data.dtypes)

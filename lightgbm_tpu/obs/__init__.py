@@ -98,9 +98,14 @@ LAYERS: Dict[str, str] = {
     "engine/valid_update": "engine",
     "grower/histogram": "grower",
     "grower/split_search": "grower",
+    # the categorical candidates (sorted many-vs-many, one-hot) inside
+    # split_search: an op takes the innermost scope
+    "grower/cat_search": "grower",
     "grower/partition": "grower",
     "grower/leaf_values": "grower",
     "ingest/assign": "ingest",
+    # the category-table lookup inside assign
+    "ingest/cat_lookup": "ingest",
 }
 
 SCOPE_PREFIX = "lgbm/"

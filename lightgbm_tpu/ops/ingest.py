@@ -164,57 +164,78 @@ def build_tables(bin_mappers, used_features: Sequence[int],
 
 def _assign_chunk_impl(raw, ub, n_ub, mt, default_bin, num_bin, is_cat,
                        cat_sorted, cat_perm, out_dtype, emit_transposed,
-                       any_cat):
+                       cat_cols=()):
     """One chunk of rows through the full mapping, on device.
 
-    raw: ``[R, Fu]`` float32 (NaN = missing). Returns the row-major
-    ``[R, Fu]`` bin block and (optionally) the feature-major ``[Fu, R]``
-    int8 tile (uint8 bits bitcast — the wraparound layout the Pallas
-    histogram kernel reads).
+    raw: ``[R, Fu]`` float32 (NaN = missing). ``cat_cols`` (static): the
+    positions of the categorical columns; each kind of column goes
+    through its own search only. Returns the row-major ``[R, Fu]`` bin
+    block, (optionally) the feature-major ``[Fu, R]`` int8 tile (uint8
+    bits bitcast — the wraparound layout the Pallas histogram kernel
+    reads) and, with categorical columns, the count of their cells whose
+    id is in no bin.
     """
     import jax
     import jax.numpy as jnp
     nanm = jnp.isnan(raw)
-    v = jnp.where(nanm, jnp.float32(0.0), raw)
-    # vectorized searchsorted(side="right") against the exclusive-f32
-    # bounds: one batched binary search per feature column (padding
-    # bounds are +inf, so they only count for v=+inf — removed by the
-    # same clip the host applies)
-    cnt = jax.vmap(
-        lambda bnd, col: jnp.searchsorted(bnd, col, side="right"),
-        in_axes=(0, 1), out_axes=1)(ub, v).astype(jnp.int32)
-    vb = jnp.minimum(cnt, n_ub[None, :] - 1)
-    miss = jnp.where(mt[None, :] == 2, num_bin[None, :] - 1,
-                     default_bin[None, :])
-    out = jnp.where(nanm, jnp.broadcast_to(miss, vb.shape), vb)
-    if any_cat:
+
+    def numeric(cols):
+        """Bins of the numeric columns ``cols`` (None = all)."""
+        def take(a, axis=0):
+            return a if cols is None else jnp.take(a, cols, axis=axis)
+        nan_c = take(nanm, 1)
+        v = jnp.where(nan_c, jnp.float32(0.0), take(raw, 1))
+        # vectorized searchsorted(side="right") against the exclusive-
+        # f32 bounds: one batched binary search per feature column
+        # (padding bounds are +inf, so they only count for v=+inf —
+        # removed by the same clip the host applies)
+        cnt = jax.vmap(
+            lambda bnd, col: jnp.searchsorted(bnd, col, side="right"),
+            in_axes=(0, 1), out_axes=1)(take(ub), v).astype(jnp.int32)
+        vb = jnp.minimum(cnt, take(n_ub)[None, :] - 1)
+        miss = jnp.where(take(mt)[None, :] == 2, take(num_bin)[None, :] - 1,
+                         take(default_bin)[None, :])
+        return jnp.where(nan_c, jnp.broadcast_to(miss, vb.shape), vb)
+
+    other = None
+    if not cat_cols:
+        out = numeric(None)
+    else:
         # categorical: truncate-toward-zero int cast (the host's
         # .astype(int64)); NaN -> -1 (the host's missing sentinel),
         # inf / out-of-int32-range -> INT32_MIN (matches no table entry
         # — build_tables guarantees real ids are int32-safe via
-        # cat_device_safe). Lookup is a per-feature binary search over
-        # the SORTED category table (O(R*Fu*log C), no [R, Fu, C]
-        # broadcast); a hit maps through cat_perm to its bin, a miss to
-        # the unseen bin 0.
-        inr = (raw >= jnp.float32(-2**31)) & (raw < jnp.float32(2**31))
-        iv = jnp.where(jnp.isnan(raw), jnp.float32(-1.0),
-                       jnp.where(inr, raw,
-                                 jnp.float32(-2**31))).astype(jnp.int32)
-        C = cat_sorted.shape[1]
-        pos = jnp.minimum(
-            jax.vmap(lambda tbl, col: jnp.searchsorted(tbl, col,
-                                                       side="left"),
-                     in_axes=(0, 1), out_axes=1)(cat_sorted, iv)
-            .astype(jnp.int32), C - 1)
-        found = jnp.take_along_axis(cat_sorted, pos.T, axis=1).T
-        cb = jnp.where(found == iv,
-                       jnp.take_along_axis(cat_perm, pos.T, axis=1).T, 0)
-        out = jnp.where(is_cat[None, :], cb, out)
+        # cat_device_safe). The lookup compares a cell with EVERY id of
+        # its column's table inside one fused reduction (no [R, Fc, C]
+        # array is written, and no gather is made: a binary search's
+        # gathers cost this chip 10 ns a cell and step, the compares
+        # next to nothing); ids are distinct in a table, so the sum of
+        # the hits' bins is the one hit's bin, and a miss is bin 0.
+        from .. import obs
+        cc = np.asarray(cat_cols, dtype=np.int32)
+        nc = np.setdiff1d(np.arange(raw.shape[1], dtype=np.int32), cc)
+        with obs.scope("ingest/cat_lookup"):
+            raw_c, nan_c = jnp.take(raw, cc, axis=1), jnp.take(nanm, cc,
+                                                              axis=1)
+            inr = ((raw_c >= jnp.float32(-2**31))
+                   & (raw_c < jnp.float32(2**31)))
+            iv = jnp.where(nan_c, jnp.float32(-1.0),
+                           jnp.where(inr, raw_c, jnp.float32(-2**31))
+                           ).astype(jnp.int32)
+            eq = iv[:, :, None] == jnp.take(cat_sorted, cc, axis=0)[None]
+            cb = jnp.sum(jnp.where(eq, jnp.take(cat_perm, cc, axis=0)[None],
+                                   0), axis=2, dtype=jnp.int32)
+            # cells that hold an id (not NaN) which no bin of the
+            # column's table holds: the `ingest.cat_other` counter
+            other = jnp.sum(~nan_c & (cb == 0), dtype=jnp.int32)
+        parts = [cb] if not len(nc) else [numeric(nc), cb]
+        order = np.argsort(np.concatenate([nc, cc]), kind="stable")
+        out = jnp.take(jnp.concatenate(parts, axis=1), order, axis=1)
     row = out.astype(out_dtype)
     if not emit_transposed:
-        return row, None
+        return row, None, other
     bt = jax.lax.bitcast_convert_type(out.T.astype(jnp.uint8), jnp.int8)
-    return row, bt
+    return row, bt, other
 
 
 _ASSIGN_JIT = None
@@ -232,7 +253,7 @@ def _assign_chunk(*args, **kwargs):
         from .. import obs
         _ASSIGN_JIT = functools.partial(
             jax.jit, static_argnames=("out_dtype", "emit_transposed",
-                                      "any_cat"))(
+                                      "cat_cols"))(
             obs.scope("ingest/assign")(_assign_chunk_impl))
     return _ASSIGN_JIT(*args, **kwargs)
 
@@ -295,7 +316,7 @@ def device_ingest(X: np.ndarray, bin_mappers, used_features,
                   jnp.asarray(tables.num_bin), jnp.asarray(tables.is_cat),
                   jnp.asarray(tables.cat_sorted),
                   jnp.asarray(tables.cat_perm))
-    any_cat = bool(tables.is_cat.any())
+    cat_cols = tuple(int(j) for j in np.flatnonzero(tables.is_cat))
     R = max(min(int(chunk_rows), max(n, 1)), 1)
     # single-chunk jobs skip the chunk-shape padding entirely
     col_sel = np.asarray(used, dtype=np.intp)
@@ -306,12 +327,14 @@ def device_ingest(X: np.ndarray, bin_mappers, used_features,
         blk = X[s:e] if take_all else X[s:e][:, col_sel]
         blk = np.ascontiguousarray(blk, dtype=np.float32)
         if e - s < R:
+            # NaN, not 0: a padded row must not count as an id
             blk = np.concatenate(
-                [blk, np.zeros((R - (e - s), Fu), np.float32)])
+                [blk, np.full((R - (e - s), Fu), np.nan, np.float32)])
         return blk
 
     row_parts = []
     t_parts = []
+    other_parts = []
     pending = None
     # the four spans a chunk say where ingest's time goes: host slicing
     # and conversion, the link, the device program's dispatch, or the
@@ -332,16 +355,25 @@ def device_ingest(X: np.ndarray, bin_mappers, used_features,
                 res = _assign_chunk(chunk_dev, *dev_tables,
                                     out_dtype=out_jdtype,
                                     emit_transposed=emit_transposed,
-                                    any_cat=any_cat)
+                                    cat_cols=cat_cols)
             row_parts.append(res[0])
             if emit_transposed:
                 t_parts.append(res[1])
+            if cat_cols:
+                other_parts.append(res[2])
+                obs.inc("ingest.cat_cells", (e - s) * len(cat_cols),
+                        force=True)
             # double buffer: keep at most two chunks in flight so host
             # prep overlaps device compute without unbounded queueing
             if pending is not None:
                 with obs.span("ingest/wait"):
                     pending.block_until_ready()
             pending = res[0]
+        if cat_cols:
+            # one small fetch at the end (it waits for the last chunk)
+            obs.inc("ingest.cat_other",
+                    float(np.sum(jax.device_get(other_parts),
+                                 dtype=np.int64)), force=True)
     bins = (row_parts[0] if len(row_parts) == 1
             else jnp.concatenate(row_parts, axis=0))[:n]
     bins_t = None

@@ -24,6 +24,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from .. import obs
+
 # a plain Python float (weak-typed -> f32 under jnp ops), NOT a device
 # array: materializing an array at import time would initialize the XLA
 # backend and break jax.distributed.initialize for multi-host users
@@ -180,6 +182,7 @@ def _pack_bitset(inset: jax.Array, n_words: int) -> jax.Array:
                    axis=1, dtype=jnp.uint32)
 
 
+@obs.scope("grower/cat_search")
 def _categorical_candidates(hist, parent_sums, num_bin, allowed_feature,
                             is_cat, cfg: SplitConfig,
                             out_lower=None, out_upper=None,
@@ -279,6 +282,7 @@ def _categorical_candidates(hist, parent_sums, num_bin, allowed_feature,
     return all_gain, orders, cum, valid_bin
 
 
+@obs.scope("grower/cat_search")
 def _categorical_best(hist, parent_sums, num_bin, allowed_feature, is_cat,
                       cfg: SplitConfig, out_lower=None, out_upper=None,
                       cegb_pen=None, parent_out=None, contri=None):

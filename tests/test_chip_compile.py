@@ -68,12 +68,12 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compiled_text(lowered):
+def _compiled_text(lowered, temp_limit=8 * 2**30):
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     # one v5e chip has 16 GiB; a program's own temporaries past half of
     # it would leave no room for the data it works on
-    assert mem.temp_size_in_bytes < 8 * 2**30, mem
+    assert mem.temp_size_in_bytes < temp_limit, mem
     return compiled.as_text()
 
 
@@ -157,6 +157,11 @@ GROW_VARIANTS = {
     "partition": {"int_hist": True, "partition": True,
                   "part_rpb": 1024},
     "hist_compact": {"int_hist": True, "hist_compact": True},
+    # the click-log cell's program: 13 count + 26 categorical columns
+    # (3 feature blocks), set-split search and bitset routing
+    "hist_compact_cat": {"int_hist": True, "hist_compact": True,
+                         "has_categorical": True,
+                         "cat_positions": tuple(range(13, 39))},
 }
 
 
@@ -166,9 +171,11 @@ def test_grow_tree_compiles(one_chip, as_tpu, variant):
     from lightgbm_tpu.ops.compact import compaction_out_cols
     s = functools.partial(_sds, one_chip)
     cfg = _grow_cfg(**GROW_VARIANTS[variant])
-    F = 28
+    F = 39 if cfg.has_categorical else 28
     vals = s((N, 3), jnp.float32)
     kw = {"bins_t": s((F, N), jnp.int8)}
+    if cfg.has_categorical:
+        kw["is_cat"] = s((F,), jnp.bool_)
     if cfg.int_hist:
         kw["chan_scale"] = s((3,), jnp.float32)
     if cfg.hist_compact:
@@ -185,7 +192,8 @@ def test_grow_tree_compiles(one_chip, as_tpu, variant):
     # the chip's compiler keeps the grower's scopes as op metadata,
     # the kernel's included
     for scope in ("histogram", "split_search", "partition",
-                  "leaf_values"):
+                  "leaf_values") + (("cat_search",)
+                                    if cfg.has_categorical else ()):
         assert f"lgbm/grower/{scope}" in text, scope
     kernel_line = next(ln for ln in text.splitlines()
                        if "%multi_leaf_histogram." in ln
@@ -266,21 +274,31 @@ def test_goss_sample_program_orders_no_row(one_chip):
 # ---------------------------------------------------------------------
 # ingest and serving
 # ---------------------------------------------------------------------
-def test_ingest_chunk_program_compiles(one_chip):
-    """One 262,144 x 28 chunk of device bin assignment, both layouts."""
+@pytest.mark.parametrize("F,C", [(28, 0), (39, 254)],
+                         ids=["numeric", "categorical"])
+def test_ingest_chunk_program_compiles(one_chip, F, C):
+    """One 262,144-row chunk of device bin assignment, both layouts: 28
+    numeric columns, and the click-log cell's 39 with category tables of
+    254 ids (the lookup keeps its scope, the "other" count comes out)."""
+    from lightgbm_tpu import obs
     from lightgbm_tpu.ops.ingest import _assign_chunk_impl
     s = functools.partial(_sds, one_chip)
-    R, F, B = 262_144, 28, 255
-    fn = jax.jit(_assign_chunk_impl,
+    R, B = 262_144, 255
+    fn = jax.jit(obs.scope("ingest/assign")(_assign_chunk_impl),
                  static_argnames=("out_dtype", "emit_transposed",
-                                  "any_cat"))
+                                  "cat_cols"))
     text = _compiled_text(fn.lower(
         s((R, F), jnp.float32), s((F, B), jnp.float32),
         s((F,), jnp.int32), s((F,), jnp.int32), s((F,), jnp.int32),
-        s((F,), jnp.int32), s((F,), jnp.bool_), s((F, 1), jnp.int32),
-        s((F, 1), jnp.int32), out_dtype=jnp.uint8, emit_transposed=True,
-        any_cat=False))
-    assert "s8[28,262144]" in text.replace(" ", "")   # the bins_t tile
+        s((F,), jnp.int32), s((F,), jnp.bool_),
+        s((F, max(C, 1)), jnp.int32), s((F, max(C, 1)), jnp.int32),
+        out_dtype=jnp.uint8, emit_transposed=True,
+        cat_cols=tuple(range(13, F)) if C else ()),
+        # the lookup's [R, 26, 254] compare lives inside one fused
+        # reduction: written out it would be 6.9 GB a chunk
+        temp_limit=2**30)
+    assert f"s8[{F},262144]" in text.replace(" ", "")   # the bins_t tile
+    assert ("lgbm/ingest/cat_lookup" in text) == bool(C)
 
 
 def test_onehot_forest_traversal_compiles(one_chip):

@@ -322,7 +322,8 @@ def test_metrics_off_by_default_records_nothing():
     # no span histogram, no gated counter or gauge: only the work
     # counters the grower and ingest always keep (once a step or chunk)
     names = {m.name for m in obs.registry().metrics()}
-    assert names and all(n.startswith(("hist.", "goss.", "ingest."))
+    assert names and all(n.startswith(("hist.", "goss.", "ingest.",
+                                       "split.", "tree.", "bundle."))
                          for n in names), names
     assert not obs.enabled()
 

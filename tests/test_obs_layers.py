@@ -115,7 +115,7 @@ def test_ingest_program_carries_its_scope():
             jnp.zeros((8, 1), i32))
     text = ingest._ASSIGN_JIT.lower(
         *args, out_dtype=jnp.uint8, emit_transposed=True,
-        any_cat=False).as_text(debug_info=True)
+        cat_cols=()).as_text(debug_info=True)
     assert "lgbm/ingest/assign" in text
 
 
