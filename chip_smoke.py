@@ -195,6 +195,25 @@ def phase_kernels(run: Run, rows: int = 1 << 17) -> dict:
     h_pl, h_xla = both(lv, True)
     np.testing.assert_array_equal(h_pl, h_xla)
     check(float(np.abs(h_pl).sum()) > 0, "int8 histogram is all zero")
+    # ragged per-column bin counts (the benchmark cells' tables: 1,952
+    # and 7,520 one-hot rows where full columns take 3,328 and 9,984):
+    # bit-equal to the XLA sums, zeros where a column has no such bin
+    for col_bins in (
+            (22, 12, 31, 7, 256, 256, 30, 256, 255, 223, 229, 256, 3),
+            (104, 256, 256, 171, 256, 256, 256, 251, 256, 16, 68, 37, 249)
+            + (255,) * 18 + (24, 4, 27, 11, 5, 19, 16, 100)):
+        rb = np.stack([rng.integers(0, c, size=rows) for c in col_bins],
+                      axis=1).astype(np.uint8)
+        with pallas():
+            h_pl = np.asarray(multi_leaf_histogram(
+                jnp.asarray(np.ascontiguousarray(rb.T).astype(np.int8)),
+                jnp.asarray(lv.T), leaf, small, num_bins=B,
+                col_bins=col_bins, rows_per_block=R, int_mode=True))
+        h_xla = np.asarray(multi_leaf_histogram_xla(
+            jnp.asarray(rb), jnp.asarray(lv), leaf, small, num_bins=B,
+            rows_per_block=1024, precise=True))
+        np.testing.assert_array_equal(h_pl, h_xla)
+        check(float(np.abs(h_pl).sum()) > 0, "ragged histogram all zero")
 
     # row compaction: bit-equal at F=28, C=3, keep-fraction 0.3
     Rc = 1024
@@ -214,6 +233,7 @@ def phase_kernels(run: Run, rows: int = 1 << 17) -> dict:
     return {"rows": rows, "shape": {"F": F, "B": B, "K": K},
             "pallas": "compiled" if run.on_tpu else "interpret",
             "hist_f32_max_abs_err": f32_err, "hist_int8": "exact",
+            "hist_int8_ragged": "exact",
             "compact_rows": "bit-equal", "kept_rows": int(mask.sum())}
 
 

@@ -570,6 +570,15 @@ class GBDT:
             has_nan = np.concatenate([has_nan, np.zeros(fpad, bool)])
             is_cat = np.concatenate([is_cat, np.zeros(fpad, bool)])
         self.feat_num_bin = jnp.asarray(num_bin.astype(np.int32))
+        # bin count of each column of the kernel's source (bins_t): the
+        # bundled physical matrix under EFB; under feature-parallel one
+        # program serves every shard's column slice, so a position takes
+        # the largest count any shard has there
+        col_bins = (self.bundle_plan.phys_num_bin if self.has_bundles
+                    else num_bin)
+        if self._shard_features:
+            col_bins = col_bins.reshape(n_shards, -1).max(axis=0)
+        self._hist_col_bins = tuple(int(b) for b in col_bins)
         self.feat_has_nan = jnp.asarray(has_nan)
         self.has_categorical = bool(is_cat.any())
         self.feat_is_cat = jnp.asarray(is_cat)
@@ -1028,6 +1037,7 @@ class GBDT:
                       and int(config.num_grad_quant_bins) <= 127
                       and self.data.n_pad
                       * int(config.num_grad_quant_bins) < 2**31),
+            hist_col_bins=self._hist_col_bins,
             axis_name=(self.axis if self.mesh is not None
                        and not self._shard_features else ""),
             has_categorical=self.has_categorical,
@@ -1083,13 +1093,16 @@ class GBDT:
 
         needs_rng = getattr(obj, "needs_rng", False)
         self._step_state = self._step_goss_state = None
-        # hist.onehot_elems a column scanned (_count_work): the kernel
-        # compares every (padded feature, bin) of its feature blocks
-        from ..ops.pallas_histogram import feature_blocks
-        F_h = self.data.bins.shape[1]
+        # hist.onehot_elems a column scanned (_count_work): the rows of
+        # the kernel's one-hot, from the layout the kernel itself uses;
+        # the XLA fallback builds every bin of every column
         if self.use_pallas:
-            F_h = int(np.prod(feature_blocks(F_h, gcfg.num_bins)))
-        self._hist_onehot_per_col = F_h * gcfg.num_bins
+            from ..ops.pallas_histogram import onehot_layout
+            self._hist_onehot_per_col = onehot_layout(
+                gcfg.hist_col_bins, gcfg.num_bins).onehot_rows
+        else:
+            self._hist_onehot_per_col = \
+                self.data.bins.shape[1] * gcfg.num_bins
 
         @obs.scope("engine/gradients")
         def gradients(score, label, weight, key):
@@ -2243,8 +2256,8 @@ class GBDT:
                 ("hist.calls", total["hist_calls"]),
                 ("hist.leaf_slots", total["hist_slots"]),
                 ("hist.leaf_slots_filled", total["hist_slots_filled"]),
-                # the kernel's VPU work by its own account: one compare
-                # a (column, padded feature, bin)
+                # the kernel's VPU and MXU work by its own account: the
+                # one-hot rows it builds for a column scanned
                 ("hist.onehot_elems", cols * self._hist_onehot_per_col)):
             obs.inc(name, value, force=True, sampled=label)
         # splits these trees made, and how many of them are set-splits

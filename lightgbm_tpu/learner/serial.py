@@ -38,7 +38,8 @@ import jax.numpy as jnp
 
 from .. import obs
 from ..ops.pallas_histogram import (multi_leaf_histogram,
-                                    multi_leaf_histogram_xla)
+                                    multi_leaf_histogram_xla,
+                                    onehot_layout)
 from ..ops.split import (NEG_INF, SplitConfig, calc_leaf_output,
                          elect_best, find_best_split, per_feature_gains,
                          smooth_output)
@@ -67,6 +68,11 @@ class GrowConfig:
     # integer accumulation at 2x MXU rate); only valid when vals carry
     # small integer levels (use_quantized_grad, engine-enforced)
     int_hist: bool = False
+    # static bin count of each PHYSICAL column of the histogram source
+    # (bins_t's rows: bundle_plan.phys_num_bin under EFB, 1 for the
+    # shard-width padding columns): the Pallas kernel builds one-hot
+    # rows for those bins only. () = every column has num_bins
+    hist_col_bins: Tuple[int, ...] = ()
     # GOSS histogram-only compaction: histograms scan the compacted
     # sampled-row buffer (grow_tree's `compact` argument) while the
     # full-row partition/score path stays masked
@@ -412,21 +418,16 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                 f"storage round-trips 0..255); got num_bins={B}. Use the "
                 f"XLA path for wider histograms.")
         h_vals_t = h_vals.T
+        col_bins = cfg.hist_col_bins or (B,) * h_bins_t.shape[0]
         # block size must divide the padded row count; rows_per_block does
         # (padding guarantees it), so cap via gcd to keep the streamed
         # one-hot within scoped VMEM without breaking divisibility.
         # R=4096 measured fastest on v5e at Higgs width, but the
-        # feature-blocked grid (F*B > 8192, e.g. MSLR/Criteo widths)
-        # overflows the 16MB scoped-vmem budget at 4096 — those shapes
-        # cap at 2048.
+        # feature-blocked grid (more one-hot rows than one block holds,
+        # e.g. MSLR widths) overflows the 16MB scoped-vmem budget at
+        # 4096 — those shapes cap at 2048.
         import math
-        r_cap = 4096 if h_bins_t.shape[0] * B <= 8192 else 2048
-        if h_bins_t.shape[0] <= 5 and B > 128:
-            # measured on v5e (round 3): at F<=4, B=256 Mosaic's stack
-            # allocation for the streamed one-hot blows scoped VMEM
-            # (28.7M > 16M) at R=4096; F=6 is fine. Narrow-F shapes are
-            # cheap anyway — halve the row block for safety margin.
-            r_cap = min(r_cap, 2048)
+        r_cap = 4096 if onehot_layout(col_bins, B).n_fb == 1 else 2048
         pr = math.gcd(cfg.rows_per_block, r_cap)
         base_rpb = pr
 
@@ -437,7 +438,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
             cross-device reduction stays with the caller so the span
             lax.switch never encloses a collective."""
             return multi_leaf_histogram(
-                b_src, v_src, l_src, ids, num_bins=B,
+                b_src, v_src, l_src, ids, num_bins=B, col_bins=col_bins,
                 rows_per_block=rpb, int_mode=cfg.int_hist)
 
         def hist_multi(leaf_id, small_ids):

@@ -7,10 +7,12 @@ atomic adds. TPUs have no fast scatter-atomics; the MXU formulation is
 
     hist[k, f, b, c] = sum_r [bin(r,f) == b] * [leaf(r) == small_k] * vals[r, c]
 
-One grid step processes a row block: the bin one-hot ``[F*B, R]`` is
-generated in VMEM (never staged through HBM — the failure mode of the XLA
-einsum formulation) and contracted on the MXU in ONE large
-``[F*B, R] x [R, K*C]`` matmul.
+One grid step processes a row block, a lane chunk at a time: the bin
+one-hot ``[rows, chunk]`` is generated in VMEM (never staged through HBM —
+the failure mode of the XLA einsum formulation) and contracted on the MXU
+in ONE ``[rows, chunk] x [chunk, C*K]`` matmul. ``rows`` follows the static
+per-column bin counts (``onehot_layout``): a column of 22 bins owns 32
+one-hot rows, not ``num_bins``, and a bin no column has costs nothing.
 
 The K axis is the TPU-specific trick: packing K candidate leaves' masks
 into the matmul N dimension amortizes the MXU's 128-wide N padding, so one
@@ -18,14 +20,17 @@ data scan yields K leaf histograms (K*C ≈ 128 → negligible padding waste).
 The batched tree grower (learner/serial.py) exploits this by expanding the
 top-K leaves per round.
 
-Measured on v5e (1M rows, F=28, B=256): ~23ms/scan at K=8, ~34ms at K=42 —
-the floor is the VPU one-hot generation (int32 compares; int8/bf16 vector
-compares are unsupported by this target), not the matmul.
+A call has two limits, both proportional to the one-hot rows: the VPU's
+compare-and-convert of every one-hot element (int32 compares; int8/bf16
+vector compares are unsupported by this target) and the MXU's
+``rows x 128 x columns`` at 393 TOP/s int8 (197 TFLOP/s bf16). PERF.md §6
+PR 30 has the measured calls against both.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+import itertools
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,60 +39,146 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _hist_kernel(bins_ref, vals_ref, leaf_ref, small_ref, out_ref, *,
-                 num_bins: int, n_feat: int, n_leaves: int, n_chan: int,
+                 rows: Tuple[int, ...], n_chan: int, chunk: int,
                  int_mode: bool = False):
     i = pl.program_id(1)      # row-block index (feature block is dim 0)
-    # bins stored int8 to halve HBM traffic; wrapped values are restored
-    # with & 0xFF after widening (cheap at [F, R])
-    bins_blk = bins_ref[...].astype(jnp.int32) & 0xFF    # [F, R]
-    vals_blk = vals_ref[...]                             # [C, R]
-    lid = leaf_ref[...]                                  # [1, R]
     small = small_ref[...]                               # [K, 1]
-
-    mask = (lid == small).astype(jnp.float32)            # [K, R]
-    prod = (mask[:, None, :] * vals_blk[None, :, :]) \
-        .reshape(n_leaves * n_chan, -1)
     # int_mode (use_quantized_grad): grad/hess are small integer levels,
     # so the contraction rides the MXU's 2x-rate int8 path with EXACT
     # int32 accumulation (the reference's integer-histogram design,
     # cuda_gradient_discretizer.cu; measured 1.25x/scan on v5e)
-    rhs = prod.astype(jnp.int8 if int_mode else jnp.bfloat16)
-
-    # [B*F, R] one-hot in tiled layout (pltpu.repeat tiles the F rows B
-    # times: row q corresponds to (b = q // F, f = q % F))
-    big = pltpu.repeat(bins_blk, num_bins, axis=0)
-    iota_b = (jax.lax.broadcasted_iota(jnp.int32, (n_feat * num_bins, 1),
-                                       0) // n_feat)
-    onehot = (big == iota_b).astype(jnp.int8 if int_mode
-                                    else jnp.bfloat16)
-
-    contrib = jax.lax.dot_general(
-        onehot, rhs, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=(jnp.int32 if int_mode
-                                else jnp.float32))       # [B*F, K*C]
+    oh_t = jnp.int8 if int_mode else jnp.bfloat16
 
     @pl.when(i == 0)
     def _():
-        out_ref[...] = contrib
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(i > 0)
-    def _():
-        out_ref[...] += contrib
+    def scan(c, carry):
+        """One lane chunk of the row block. The loop keeps the unrolled
+        program (and its compile time) at one chunk's size whatever the
+        row block, and the live one-hot at ``[sum(rows), chunk]``."""
+        sl = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        # bins stored int8 to halve HBM traffic; wrapped values are
+        # restored with & 0xFF after widening (cheap at [F, chunk])
+        bins_blk = bins_ref[:, sl].astype(jnp.int32) & 0xFF   # [F, chunk]
+        vals_blk = vals_ref[:, sl]                            # [C, chunk]
+        if int_mode:
+            vals_blk = vals_blk.astype(jnp.int32)
+        in_leaf = leaf_ref[:, sl] == small                    # [K, chunk]
+        # leaf-masked values, CHANNEL-major ([C*K, chunk], lane c*K+k of
+        # the accumulator): each channel is one select of a sublane
+        # broadcast, and the K-row pieces stack on tile boundaries (a
+        # [K, C, chunk] product reshaped to [K*C, chunk] shuffles every
+        # sublane: it cost as much as 2,000 one-hot rows)
+        rhs = jnp.concatenate(
+            [jnp.where(in_leaf, vals_blk[ch:ch + 1, :], 0).astype(oh_t)
+             for ch in range(n_chan)], axis=0)
+
+        # [sum(rows), chunk] one-hot, column by column: position p of
+        # the block owns rows[p] rows (its bin count rounded up to
+        # ROW_TILE, so every piece starts on an int8 sublane tile), each
+        # a sublane broadcast of its row of bins against an iota. A bin
+        # no column of that position has gets no row: it costs neither
+        # the VPU nor the MXU anything.
+        pieces = []
+        for p, r in enumerate(rows):
+            iota_b = jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0)
+            pieces.append((bins_blk[p:p + 1, :] == iota_b).astype(oh_t))
+        onehot = jnp.concatenate(pieces, axis=0)
+
+        out_ref[...] += jax.lax.dot_general(
+            onehot, rhs, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=(jnp.int32 if int_mode
+                                    else jnp.float32))  # [sum(rows), C*K]
+        return carry
+
+    jax.lax.fori_loop(0, bins_ref.shape[1] // chunk, scan, 0)
 
 
-def feature_blocks(F: int, num_bins: int) -> Tuple[int, int]:
-    """(features a block, blocks) of the kernel's feature grid; their
-    product is the padded feature count the one-hot is generated for."""
-    F_blk = F if F * num_bins <= 8192 else max(1, 4096 // num_bins)
-    return F_blk, (F + F_blk - 1) // F_blk
+# one-hot rows of a column: its bin count rounded up to the int8
+# sublane tile, so the pieces concatenate on tile boundaries
+ROW_TILE = 32
+# one-hot rows one feature block may hold: up to ONE_BLOCK_ROWS the
+# table is a single block; a wider one is cut into windows of at most
+# WINDOW_ROWS (the [rows, K*C] accumulator, its contribution and the
+# streamed one-hot share the 16MB scoped-vmem budget)
+ONE_BLOCK_ROWS = 8192
+WINDOW_ROWS = 4096
+
+
+class OneHotLayout(NamedTuple):
+    """Static row layout of the kernel's one-hot and accumulator.
+
+    ``rows[p]`` one-hot rows belong to position p of every feature
+    block; column f sits at position ``f % f_blk`` of block
+    ``f // f_blk``, and its bin b at row ``offsets[p] + b`` of that
+    block's ``block_rows`` rows."""
+    f_blk: int
+    n_fb: int
+    rows: Tuple[int, ...]
+
+    @property
+    def block_rows(self) -> int:
+        return sum(self.rows)
+
+    @property
+    def onehot_rows(self) -> int:
+        """One-hot rows built for every column of rows scanned: what
+        ``hist.onehot_elems`` counts."""
+        return self.n_fb * self.block_rows
+
+    @property
+    def offsets(self) -> Tuple[int, ...]:
+        return tuple(itertools.accumulate((0,) + self.rows[:-1]))
+
+
+@functools.lru_cache(maxsize=None)
+def onehot_layout(col_bins: Tuple[int, ...], num_bins: int) -> OneHotLayout:
+    """The layout for a source whose PHYSICAL column f holds bins
+    ``0..col_bins[f]-1``. A table of at most ONE_BLOCK_ROWS rows is one
+    block, every column with its own count. A wider one runs the 2-D
+    grid over windows of ``f_blk`` columns and ONE kernel body serves
+    them all, so a position takes the largest count any window has
+    there (all-full columns give the dense ``f_blk x num_bins``)."""
+    need = tuple(-(-min(max(int(c), 1), num_bins) // ROW_TILE) * ROW_TILE
+                 for c in col_bins)
+    F = len(need)
+    if sum(need) <= ONE_BLOCK_ROWS:
+        return OneHotLayout(F, 1, need)
+    f_blk = max(1, WINDOW_ROWS // max(need))
+    n_fb = -(-F // f_blk)
+    rows = tuple(max(need[p::f_blk]) for p in range(f_blk))
+    return OneHotLayout(f_blk, n_fb, rows)
+
+
+def dense_histograms(out: jax.Array, layout: OneHotLayout, F: int,
+                     num_bins: int, K: int, C: int) -> jax.Array:
+    """The kernel's ``[n_fb * block_rows, C*K]`` accumulator -> the
+    dense ``[K, F, num_bins, C]`` the grower reads, zeros where a column
+    has no such bin: static slices and pads of the small accumulator,
+    one per position of a block."""
+    out = out.reshape(layout.n_fb, layout.block_rows, C * K)
+    cols = []
+    for off, r in zip(layout.offsets, layout.rows):
+        keep = min(r, num_bins)      # ROW_TILE can round past num_bins
+        cols.append(jnp.pad(out[:, off:off + keep],
+                            ((0, 0), (0, num_bins - keep), (0, 0))))
+    dense = jnp.stack(cols, axis=1)        # [n_fb, f_blk, num_bins, C*K]
+    dense = dense.reshape(layout.n_fb * layout.f_blk, num_bins, C, K)[:F]
+    return dense.transpose(3, 0, 1, 2)
+
+
+# lanes of a row block one pass of the kernel's inner loop takes
+LANE_CHUNK = 1024
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("num_bins", "rows_per_block",
-                                    "int_mode"))
+                   static_argnames=("num_bins", "col_bins",
+                                    "rows_per_block", "int_mode"))
 def multi_leaf_histogram(bins_t: jax.Array, vals_t: jax.Array,
                          leaf_id: jax.Array, small_ids: jax.Array, *,
                          num_bins: int,
+                         col_bins: Optional[Tuple[int, ...]] = None,
                          rows_per_block: int = 2048,
                          int_mode: bool = False) -> jax.Array:
     """Histograms of K leaves in one fused scan (TPU Pallas path).
@@ -102,6 +193,9 @@ def multi_leaf_histogram(bins_t: jax.Array, vals_t: jax.Array,
       small_ids: ``[K]`` int32 leaf ids to histogram (-1 entries match no
         row, giving zero histograms for inactive slots).
       num_bins: static histogram width B.
+      col_bins: static bin count of each of the F columns (a value of
+        column f is below ``col_bins[f]``); the one-hot holds rows for
+        those bins only. None: every column has ``num_bins``.
 
     Returns:
       ``[K, F, B, C]`` float32.
@@ -111,34 +205,38 @@ def multi_leaf_histogram(bins_t: jax.Array, vals_t: jax.Array,
     K = small_ids.shape[0]
     R = rows_per_block
     assert n % R == 0, f"n={n} must be a multiple of rows_per_block={R}"
+    if col_bins is None:
+        col_bins = (num_bins,) * F
+    if len(col_bins) != F:
+        raise ValueError(
+            f"col_bins names {len(col_bins)} columns, bins_t has {F}")
 
-    # feature blocking keeps the [B*F_blk, K*C] VMEM accumulator (and the
-    # transient one-hot) bounded for wide datasets (MSLR F=136+); at
-    # F*B <= 8192 this is a single block, identical to the unblocked
-    # form. Blocked (wide-F) layouts use a half-size block: [8192, R]
-    # streaming exceeds the 16MB scoped-vmem budget at K*C ~ 96+
-    # (measured: 16.25M at F_blk=32, B=256, R=2048 on v5e).
-    F_blk, n_fb = feature_blocks(F, num_bins)
-    F_pad = n_fb * F_blk
+    # feature blocking keeps the [rows, K*C] VMEM accumulator (and the
+    # transient one-hot) bounded for wide datasets (MSLR F=136+); up to
+    # ONE_BLOCK_ROWS one-hot rows this is a single block. Blocked
+    # (wide-F) layouts use half-size windows and, in learner/serial.py,
+    # a half-size row block.
+    lay = onehot_layout(tuple(col_bins), num_bins)
+    F_pad = lay.n_fb * lay.f_blk
     if F_pad > F:
         bins_t = jnp.concatenate(
             [bins_t, jnp.zeros((F_pad - F, n), bins_t.dtype)])
 
-    kernel = functools.partial(_hist_kernel, num_bins=num_bins,
-                               n_feat=F_blk, n_leaves=K, n_chan=C,
-                               int_mode=int_mode)
+    kernel = functools.partial(
+        _hist_kernel, rows=lay.rows, n_chan=C, int_mode=int_mode,
+        chunk=LANE_CHUNK if R % LANE_CHUNK == 0 else R)
     # NO input_output_aliases here (examined, round 7 — docs/perf.md
-    # "Iteration floor"): the [B*F_pad, K*C] accumulator is an
-    # output-only carry across the sequential row-block grid, already
-    # accumulated in place in VMEM by the @pl.when(i>0) add; no input
+    # "Iteration floor"): the [rows, C*K] accumulator is an
+    # output-only carry across the sequential row-block grid, zeroed at
+    # its first step and accumulated in place in VMEM; no input
     # operand shares its shape/dtype, and threading a caller-supplied
     # zeroed buffer just to alias it would ADD an HBM zero-fill per
     # call — strictly worse than the status quo.
     out = pl.pallas_call(
         kernel,
-        grid=(n_fb, n // R),
+        grid=(lay.n_fb, n // R),
         in_specs=[
-            pl.BlockSpec((F_blk, R), lambda j, i: (j, i),
+            pl.BlockSpec((lay.f_blk, R), lambda j, i: (j, i),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((C, R), lambda j, i: (0, i),
                          memory_space=pltpu.VMEM),
@@ -147,14 +245,14 @@ def multi_leaf_histogram(bins_t: jax.Array, vals_t: jax.Array,
             pl.BlockSpec((K, 1), lambda j, i: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((num_bins * F_blk, K * C),
+        out_specs=pl.BlockSpec((lay.block_rows, C * K),
                                lambda j, i: (j, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((num_bins * F_pad, K * C),
+        out_shape=jax.ShapeDtypeStruct((lay.onehot_rows, C * K),
                                        jnp.int32 if int_mode
                                        else jnp.float32),
         cost_estimate=pl.CostEstimate(
-            flops=2 * F_pad * num_bins * n * K * C,
+            flops=2 * lay.onehot_rows * n * K * C,
             bytes_accessed=bins_t.size + vals_t.size * 4 + leaf_id.size * 4,
             transcendentals=0),
         # the device op's name, pinned: profile readers match it
@@ -162,10 +260,7 @@ def multi_leaf_histogram(bins_t: jax.Array, vals_t: jax.Array,
     )(bins_t, vals_t, leaf_id.reshape(1, n), small_ids.reshape(K, 1))
     if int_mode:
         out = out.astype(jnp.float32)
-    # per block j, row q = b * F_blk + f_local
-    out = out.reshape(n_fb, num_bins, F_blk, K, C)
-    out = out.transpose(3, 0, 2, 1, 4).reshape(K, F_pad, num_bins, C)
-    return out[:, :F]
+    return dense_histograms(out, lay, F, num_bins, K, C)
 
 
 def multi_leaf_histogram_xla(bins: jax.Array, vals: jax.Array,

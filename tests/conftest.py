@@ -45,6 +45,22 @@ if not TPU_MODE:
         f"expected 8 fake CPU devices, got {jax.devices()}")
 
 
+@pytest.fixture
+def pallas_path(monkeypatch):
+    """The engine's Pallas path: compiled on the chip (``TPU_MODE``); on
+    the CPU the engine is told it stands on a TPU and its kernels run in
+    interpret mode, so what follows the kernel (layouts, counters, model
+    bytes) is held by tier-1 too. Steered here, in the test, never
+    through an option of the program."""
+    if TPU_MODE:
+        yield
+        return
+    from jax.experimental.pallas import tpu as pltpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
 @pytest.fixture(scope="session")
 def multiprocess_collectives():
     """Capability probe for cross-process collectives on the CPU

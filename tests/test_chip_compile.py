@@ -27,6 +27,9 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+# the benchmark cells' per-column bin counts
+from test_hist_layout import AIRLINE as AIRLINE_BINS, CRITEO as CRITEO_BINS
+
 N = 1 << 20          # Higgs-1M rows
 SLAB = 1 << 17       # one kernel slab
 
@@ -82,19 +85,27 @@ def _compiled_text(lowered, temp_limit=8 * 2**30):
 # ---------------------------------------------------------------------
 @pytest.mark.parametrize("int_mode", [False, True],
                          ids=["f32", "int8"])
-@pytest.mark.parametrize("F,B,K", [
-    (28, 256, 32),    # Higgs: single feature block
-    (136, 256, 32),   # MSLR width: feature-blocked grid (F*B > 8192)
-    (28, 64, 32),     # narrow bins
-])
-def test_multi_leaf_histogram_compiles(one_chip, F, B, K, int_mode):
-    from lightgbm_tpu.ops.pallas_histogram import multi_leaf_histogram
+@pytest.mark.parametrize("col_bins,B,K", [
+    ((256,) * 28, 256, 32),    # Higgs: one block of full columns
+    ((256,) * 136, 256, 32),   # MSLR width: feature-blocked grid
+    ((64,) * 28, 64, 32),      # narrow bins
+    (AIRLINE_BINS, 256, 32),   # ragged: 1,952 one-hot rows, one block
+    (CRITEO_BINS, 256, 32),    # ragged: 7,520 rows in ONE block
+    ((256, 1, 1, 1), 256, 32),  # narrow table with 1-bin padding columns
+    (((256,) * 12 + (40,) * 4) * 2 + (256,) * 8, 256, 8),  # ragged grid
+], ids=["higgs", "mslr", "narrow", "airline", "criteo", "padding",
+        "ragged_grid"])
+def test_multi_leaf_histogram_compiles(one_chip, col_bins, B, K, int_mode):
+    from lightgbm_tpu.ops.pallas_histogram import (multi_leaf_histogram,
+                                                   onehot_layout)
     s = functools.partial(_sds, one_chip)
-    R = 4096 if F * B <= 8192 else 2048      # learner/serial.py's caps
+    # learner/serial.py's caps
+    R = 4096 if onehot_layout(col_bins, B).n_fb == 1 else 2048
     text = _compiled_text(multi_leaf_histogram.lower(
-        s((F, SLAB), jnp.int8), s((3, SLAB), jnp.float32),
+        s((len(col_bins), SLAB), jnp.int8), s((3, SLAB), jnp.float32),
         s((SLAB,), jnp.int32), s((K,), jnp.int32),
-        num_bins=B, rows_per_block=R, int_mode=int_mode))
+        num_bins=B, col_bins=col_bins, rows_per_block=R,
+        int_mode=int_mode))
     assert "tpu_custom_call" in text
 
 
@@ -158,10 +169,12 @@ GROW_VARIANTS = {
                   "part_rpb": 1024},
     "hist_compact": {"int_hist": True, "hist_compact": True},
     # the click-log cell's program: 13 count + 26 categorical columns
-    # (3 feature blocks), set-split search and bitset routing
+    # at their own bin counts (one block of 7,520 one-hot rows),
+    # set-split search and bitset routing
     "hist_compact_cat": {"int_hist": True, "hist_compact": True,
                          "has_categorical": True,
-                         "cat_positions": tuple(range(13, 39))},
+                         "cat_positions": tuple(range(13, 39)),
+                         "hist_col_bins": CRITEO_BINS},
 }
 
 

@@ -18,6 +18,7 @@ from lightgbm_tpu.ops.histogram import build_histogram
 from lightgbm_tpu.ops.pallas_histogram import (multi_leaf_histogram,
                                                multi_leaf_histogram_xla)
 from lightgbm_tpu.ops.predict import tree_predict_binned
+from test_hist_layout import AIRLINE as AIRLINE_BINS, CRITEO as CRITEO_BINS
 
 
 def _data(n=2048, F=6, B=32, n_leaves=5, seed=0):
@@ -86,6 +87,112 @@ def test_pallas_matches_xla_boundary_shapes(F, B, rpb):
         jnp.asarray(small_ids), num_bins=B, rows_per_block=rpb))
     np.testing.assert_allclose(h_pl, h_xla, rtol=2e-2, atol=0.5)
     np.testing.assert_array_equal(h_pl[..., 2], h_xla[..., 2])
+
+
+# per-column bin counts: the two benchmark cells' tables, a 1-bin column,
+# all full
+RAGGED = {
+    "airline": (AIRLINE_BINS, 4096),
+    "criteo": (CRITEO_BINS, 4096),
+    "criteo_grid": (CRITEO_BINS + (256,) * 9, 2048),   # 48 columns: grid
+    "one_bin": ((256, 1, 90, 1, 256), 4096),
+    "all_full": ((256,) * 13, 4096),
+}
+
+
+def _ragged_data(col_bins, n, seed):
+    rng = np.random.default_rng(seed)
+    bins = np.stack([rng.integers(0, c, size=n) for c in col_bins],
+                    axis=1).astype(np.uint8)
+    lv = np.stack([rng.integers(-16, 17, size=n),
+                   rng.integers(0, 17, size=n),
+                   np.ones(n)], axis=1).astype(np.float32)
+    leaf_id = rng.integers(0, 40, size=n).astype(np.int32)
+    return bins, lv, leaf_id
+
+
+@requires_tpu
+@pytest.mark.parametrize("case", sorted(RAGGED))
+def test_pallas_ragged_counts_match_xla(case):
+    """One-hot rows only for the bins a column has: int mode bit-equal
+    to the XLA sums (zeros where a column has no such bin), f32 mode
+    within the bf16 tolerance, and equal to the same call without the
+    counts."""
+    col_bins, rpb = RAGGED[case]
+    bins, lv, leaf_id = _ragged_data(col_bins, 1 << 15, len(col_bins))
+    small = np.arange(32, dtype=np.int32)
+    small[[5, 17]] = -1
+    args = (jnp.asarray(np.ascontiguousarray(bins.T).astype(np.int8)),
+            jnp.asarray(lv.T), jnp.asarray(leaf_id), jnp.asarray(small))
+    h_xla = np.asarray(multi_leaf_histogram_xla(
+        jnp.asarray(bins), jnp.asarray(lv), jnp.asarray(leaf_id),
+        jnp.asarray(small), num_bins=256, rows_per_block=1024,
+        precise=True))
+    for int_mode in (True, False):
+        h = np.asarray(multi_leaf_histogram(
+            *args, num_bins=256, col_bins=col_bins, rows_per_block=rpb,
+            int_mode=int_mode))
+        h_dense = np.asarray(multi_leaf_histogram(
+            *args, num_bins=256, rows_per_block=2048, int_mode=int_mode))
+        if int_mode:
+            np.testing.assert_array_equal(h, h_xla)
+            np.testing.assert_array_equal(h, h_dense)
+        else:
+            np.testing.assert_allclose(h, h_xla, rtol=2e-2, atol=0.5)
+            np.testing.assert_allclose(h, h_dense, rtol=2e-2, atol=0.5)
+    for f, nb in enumerate(col_bins):
+        assert not h[:, f, nb:].any()
+    assert float(np.abs(h).sum()) > 0
+
+
+@pytest.mark.parametrize("learner,want_bins", [
+    ("serial", (255, 255, 255, 6, 255, 255, 41)),
+    # a 1-bin column pads the width to the 8 shards of the scatter
+    ("data", (255, 255, 255, 6, 255, 255, 41, 1)),
+    # one program serves every shard's column slice: a position takes
+    # the largest count any shard has there
+    ("feature", (255,)),
+])
+def test_model_text_is_equal_with_and_without_counts(pallas_path,
+                                                     monkeypatch,
+                                                     learner, want_bins):
+    """The grower reads the dense [K, F, B, C] either way, and integer
+    histograms are the same sums: a forest grown with the kernel's
+    ragged layout equals, byte for byte, the one grown with every
+    column at num_bins. On every learner that runs the kernel."""
+    import dataclasses
+
+    import jax
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.boosting.gbdt import GBDT
+    if learner != "serial" and jax.device_count() < 2:
+        pytest.skip("one device: the engine runs the serial learner")
+    rng = np.random.default_rng(5)
+    n, rounds = (6000, 5) if learner == "serial" else (2048, 2)
+    X = rng.normal(size=(n, 7)).astype(np.float32)
+    X[:, 3] = rng.integers(0, 5, size=n)
+    X[:, 6] = rng.integers(0, 40, size=n)
+    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] + 0.2 * X[:, 3]
+         + 0.3 * rng.normal(size=n) > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
+              "learning_rate": 0.5, "data_sample_strategy": "goss",
+              "tpu_fuse_iters": 2, "use_quantized_grad": True,
+              "tpu_leaf_batch": 4, "tree_learner": learner}
+    texts, col_bins = [], []
+    for dense in (False, True):
+        if dense:
+            ragged_cfg = GBDT._make_grow_cfg
+            monkeypatch.setattr(
+                GBDT, "_make_grow_cfg",
+                lambda self: dataclasses.replace(
+                    ragged_cfg(self), hist_col_bins=()))
+        bst = lgb.train(params, lgb.Dataset(X, label=y),
+                        num_boost_round=rounds)
+        assert bst.engine.use_pallas and bst.engine.grow_cfg.int_hist
+        col_bins.append(bst.engine.grow_cfg.hist_col_bins)
+        texts.append(bst.model_to_string())
+    assert col_bins == [want_bins, ()]
+    assert texts[0] == texts[1]
 
 
 @requires_tpu

@@ -162,6 +162,28 @@ def test_cols_scanned_is_at_least_cols_needed(extra):
             == scanned * 8 * 256
 
 
+def test_onehot_elems_counts_the_rows_the_kernel_builds(pallas_path):
+    """The Pallas side of the line above: one-hot rows only for the bins
+    a column has. The counter reads the layout function the kernel
+    builds its one-hot from, so the two cannot part."""
+    from lightgbm_tpu.ops.pallas_histogram import onehot_layout
+    X, y = _data()
+    X[:, 3] = np.floor(3 * X[:, 3])          # a short count column
+    X[:, 6] = X[:, 6] > 0                    # a flag
+    bst = lgb.train(dict(GOSS, tpu_leaf_batch=4), lgb.Dataset(X, label=y),
+                    num_boost_round=5)
+    cfg = bst.engine.grow_cfg
+    assert cfg.use_pallas
+    assert cfg.hist_col_bins == tuple(
+        int(b) for b in bst.engine.train_set.feature_num_bins())
+    rows = onehot_layout(cfg.hist_col_bins, cfg.num_bins).onehot_rows
+    dense = len(cfg.hist_col_bins) * cfg.num_bins
+    assert sum(cfg.hist_col_bins) <= rows < dense == 8 * 256
+    for s in (0, 1):
+        assert _counter("hist.onehot_elems", sampled=s) \
+            == _counter("hist.cols_scanned", sampled=s) * rows > 0
+
+
 def test_counters_are_kept_with_obs_off_and_carry_sampled():
     """learning_rate 0.5: GOSS starts at iteration 2, so of 6 rounds
     two trees come from the un-sampled program and four from the
