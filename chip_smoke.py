@@ -65,8 +65,8 @@ def check(cond, msg: str) -> None:
 
 
 def synth_higgs(n, f, seed):
-    """bench.py's Higgs-like generator (copied: bench.py is due a
-    redesign, the smoke's data must not move with it)."""
+    """A Higgs-like table: normal columns, a label from a linear score
+    with one product and one absolute-value term, plus noise."""
     rng = np.random.default_rng(seed)
     X = rng.normal(size=(n, f)).astype(np.float32)
     w = rng.normal(size=f)
@@ -324,10 +324,11 @@ def _model_record(run: Run, bst, phase: str) -> dict:
 
 
 def phase_train_goss_quant(run: Run, rounds: int = 25) -> dict:
-    """GOSS + quantized gradients (the bench.py default). Chunks of 5
-    fused iterations, so the fused ``lax.scan`` step runs before GOSS
-    starts (round 1/learning_rate) and after; the donation probe's
-    ``update()`` then runs the per-iteration GOSS step."""
+    """GOSS + quantized gradients (what both benchmark cells train
+    with). Chunks of 5 fused iterations, so the fused ``lax.scan`` step
+    runs before GOSS starts (round 1/learning_rate) and after; the
+    donation probe's ``update()`` then runs the per-iteration GOSS
+    step."""
     import lightgbm_tpu as lgb
     params = {**BASE_PARAMS, "data_sample_strategy": "goss",
               "use_quantized_grad": True, "tpu_fuse_iters": 5}

@@ -45,11 +45,11 @@ class Booster:
                         # settings govern the construct that this
                         # Booster triggers (ops/ingest.py) — including
                         # the gates _want_transposed_ingest /
-                        # _want_device_ingest read (pallas, precision,
+                        # _want_device_ingest read (precision,
                         # streaming), else construct emits device
                         # arrays the engine will never adopt
                         "tpu_ingest_device", "tpu_ingest_chunk_rows",
-                        "tpu_ingest_threads", "tpu_use_pallas",
+                        "tpu_ingest_threads",
                         "tpu_double_precision_hist", "tpu_streaming",
                         "tree_learner", "tpu_compile_cache_dir"):
                 train_set.params.setdefault(key, getattr(self.config, key))

@@ -98,6 +98,11 @@ def _predict_row_bucket(n: int, cap: int) -> int:
     b = max(_next_pow2(max(n, 1)), PREDICT_ROW_BUCKET_FLOOR)
     return b if b <= cap else cap
 
+# rows a histogram block holds (the padded row count is a multiple; a
+# small table takes its own row count rounded up to 256);
+# learner/serial.py::grow_tree says what the chip read for other sizes
+ROWS_PER_BLOCK = 4096
+
 # stacked-forest cache entries kept per engine (distinct (start, num,
 # pad) tree ranges in flight at once — full model + a few early-stop
 # slices; each entry is only T * Ln * ~10 ints of HBM)
@@ -478,7 +483,7 @@ class GBDT:
                 multihost_utils.process_allgather(
                     np.asarray([n_rows_layout], np.int64))).max())
         rows_per_block = min(
-            config.tpu_rows_per_block,
+            ROWS_PER_BLOCK,
             pad_rows(max(1, n_rows_layout // n_shards), 256))
         self.rows_per_block = rows_per_block
 
@@ -688,23 +693,15 @@ class GBDT:
         self._n_forced = 0
         fs_path = str(config.forcedsplits_filename or "").strip()
         if fs_path:
-            if (self.mesh is not None or config.tpu_hist_mode != "pool"
-                    or self.has_bundles):
+            if self.mesh is not None or self.has_bundles:
                 log.warning("forcedsplits_filename requires the serial "
-                            "learner, tpu_hist_mode=pool and no EFB "
-                            "bundles; ignoring forced splits")
+                            "learner and no EFB bundles; ignoring "
+                            "forced splits")
             else:
                 self._load_forced_splits(fs_path)
 
-        # The fused Pallas kernel needs a TPU backend and int8-roundtrip
-        # bin ids (B <= 256); anything else takes the XLA einsum path.
-        # tpu_double_precision_hist also routes to the XLA path — the
-        # Pallas kernel's operands are bf16 by design (quantized mode is
-        # the exact-at-speed alternative).
-        self.use_pallas = bool(config.tpu_use_pallas and F > 0
-                               and self.B <= 256
-                               and not config.tpu_double_precision_hist
-                               and jax.default_backend() == "tpu")
+        self.use_pallas = F > 0 and capabilities.pallas_histogram_runs(
+            self.B, config.tpu_double_precision_hist)
         self.data = _DeviceData(self.train_set, rows_per_block, self.mesh,
                                 transposed=self.use_pallas,
                                 shard_features=self._shard_features,
@@ -718,9 +715,9 @@ class GBDT:
         # ---- leaf-ordered device row partition (tpu_hist_partition;
         # ops/partition.py): rows ride the grow-loop carry grouped by
         # leaf so each round's histogram scans only the elected
-        # children's spans (siblings by pool subtraction / rebuild
-        # N-packing). Trees are structurally identical to the masked
-        # path (bit-exact under quantized gradients). The per-round
+        # children's spans (siblings by pool subtraction). Trees are
+        # structurally identical to the masked path (bit-exact under
+        # quantized gradients). The per-round
         # repartition move costs ~2 compaction passes (docs/perf.md
         # "Partitioned histograms"), so AUTO only engages where the
         # cost model wins: the Pallas pool path over a large
@@ -739,7 +736,7 @@ class GBDT:
             if not can_part:
                 log.warning(
                     "tpu_hist_partition=true needs the Pallas path on "
-                    "TPU (max_bin<=255, tpu_use_pallas=true, no "
+                    "TPU (max_bin<=255, no "
                     "tpu_double_precision_hist) or a non-TPU backend; "
                     "keeping the masked full-scan histograms")
             self.hist_partition = can_part
@@ -1066,7 +1063,6 @@ class GBDT:
             monotone_penalty=config.monotone_penalty,
             has_interaction=self.has_interaction,
             has_bundles=self.has_bundles,
-            hist_rebuild=(config.tpu_hist_mode == "rebuild"),
             partition=self.hist_partition,
             part_rpb=self.part_rpb,
             feature_fraction_bynode=config.feature_fraction_bynode,
@@ -1464,10 +1460,8 @@ class GBDT:
         from ..ops.compact import (compact_rows, compact_rows_xla,
                                    compaction_out_cols, plan_compaction)
         # compaction block size: <= 1024 (kernel VMEM budget) and a
-        # divisor of n_pad (which is a rows_per_block multiple); a
-        # degenerate divisor (odd tpu_rows_per_block values) would
-        # shred the kernel grid into sub-lane-width matmuls, so those
-        # shapes keep the masked path
+        # divisor of n_pad (a rows_per_block multiple, itself a
+        # multiple of 256: R_c is 256, 512 or 1024)
         R_c = _math.gcd(1024, gcfg.rows_per_block)
         frac = top_rate + other_rate
         n_sub = compaction_out_cols(
@@ -1480,7 +1474,6 @@ class GBDT:
                            and not (use_quant and renew_quant)
                            and not getattr(obj, "has_pos_state", False)
                            and top_rate + other_rate < 1.0
-                           and R_c >= 256
                            # the compacted buffer (sampled rows + write
                            # slack) must genuinely shrink the scan; tiny
                            # datasets / near-1.0 fractions keep the
@@ -1490,8 +1483,8 @@ class GBDT:
                            and n_sub < self.data.n_pad
                            # the XLA scatter fallback serializes ON TPU
                            # (docs/perf.md) — without the Pallas path
-                           # (max_bin>256 / tpu_double_precision_hist /
-                           # tpu_use_pallas=false) keep the masked scan
+                           # (max_bin>256 / tpu_double_precision_hist)
+                           # keep the masked scan
                            and (self.use_pallas
                                 or jax.default_backend() != "tpu"))
         self._use_goss_compact = use_goss_compact

@@ -298,6 +298,20 @@ def supports(engine: str, config,
 # ---------------------------------------------------------------------------
 # auto-mode policies
 # ---------------------------------------------------------------------------
+def pallas_histogram_runs(num_bins: int, double_precision_hist) -> bool:
+    """Does the Pallas histogram kernel run for a table of this bin
+    width? It needs a TPU backend, bin ids that round-trip through int8
+    (at most 256 bins a column) and bf16 operands, so
+    ``tpu_double_precision_hist`` takes the XLA einsum path, as does
+    everything else (quantized mode is the exact-at-speed alternative).
+    The engine asks with its histogram width, ingest with the bin count
+    its dtype can hold, so the feature-major tile is emitted exactly
+    where the kernel will read it."""
+    import jax
+    return (num_bins <= 256 and not double_precision_hist
+            and jax.default_backend() == "tpu")
+
+
 def hist_partition_auto(config, use_pallas: bool,
                         n_pad: int) -> Tuple[bool, Optional[str]]:
     """The ``tpu_hist_partition=auto`` cost model: engage the
@@ -305,9 +319,9 @@ def hist_partition_auto(config, use_pallas: bool,
     move pays for itself — the Pallas pool path over a large
     un-compacted source (docs/perf.md "Partitioned histograms").
     Returns ``(engage, stand_down_reason)``; the reason is None when
-    engaging or when the path was never plausible (non-Pallas /
-    rebuild mode, where no stand-down message is owed)."""
-    if not use_pallas or str(config.tpu_hist_mode) != "pool":
+    engaging or when the path was never plausible (no Pallas kernel,
+    where no stand-down message is owed)."""
+    if not use_pallas:
         return False, None
     if str(config.data_sample_strategy) == "goss":
         return False, "GOSS already compacts the scan"

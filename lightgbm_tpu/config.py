@@ -292,7 +292,6 @@ _PARAMS: Dict[str, Tuple[str, Any, Tuple[str, ...], Optional[Tuple[float, float]
     "tpu_model_watch": _P("str", ""),
     "tpu_model_watch_interval": _P("float", 2.0, [], (0.0, None)),
     # ---- TPU-specific (new; no reference analog) -------------------------
-    "tpu_rows_per_block": _P("int", 4096),
     # buffer donation for the boosting carries (docs/perf.md "Iteration
     # floor"): the per-step / fused-chunk / valid-update / streamed
     # score jits donate their loop-state inputs
@@ -301,11 +300,12 @@ _PARAMS: Dict[str, Tuple[str, Any, Tuple[str, ...], Optional[Tuple[float, float]
     # the TPU backend only (the measured waste lives there; CPU test
     # runs keep today's copy semantics), "true" forces donation on any
     # backend that supports it (the CPU bit-identity tests), "false"
-    # disables it everywhere (the bench.py --no-donate A/B). Donated
-    # buffers are DELETED at dispatch — a stale Python reference read
-    # after the call is a bug; tpu_debug_checks names the donating
-    # site, and the donation-discipline linter (tools/analyze) flags
-    # the static shape of that mistake.
+    # disables it everywhere (the off arm of tests/test_donation.py's
+    # bit-identity cases). Donated buffers are DELETED at dispatch — a
+    # stale Python reference read after the call is a bug;
+    # tpu_debug_checks names the donating site, and the
+    # donation-discipline linter (tools/analyze) flags the static
+    # shape of that mistake.
     "tpu_donate": _P("str", "auto"),
     "tpu_mesh_shape": _P("str", ""),
     "tpu_double_precision_hist": _P("bool", False),
@@ -318,7 +318,6 @@ _PARAMS: Dict[str, Tuple[str, Any, Tuple[str, ...], Optional[Tuple[float, float]
     # leaves expanded per growth round; 1 = exact reference leaf-wise
     # order, larger batches fuse K leaf histograms into one data scan
     "tpu_leaf_batch": _P("int", 32, [], (1, 256)),
-    "tpu_use_pallas": _P("bool", True),
     # GOSS histogram-only row compaction (default on): one sort moves
     # the sampled rows into a fixed-size buffer so HISTOGRAM scans
     # shrink to ~(top+other)*n rows (the reference's bag subsets rows
@@ -409,8 +408,8 @@ _PARAMS: Dict[str, Tuple[str, Any, Tuple[str, ...], Optional[Tuple[float, float]
     # xprof) with the host orchestration view
     "tpu_trace_dir": _P("str", ""),
     # append one JSONL metrics-snapshot line to this path when
-    # training finishes (implies tpu_metrics); the same schema
-    # bench.py --metrics-json and scripts/check.sh consume
+    # training finishes (implies tpu_metrics); schema
+    # lightgbm-tpu-metrics-v1 (docs/observability.md)
     "tpu_metrics_dump": _P("str", ""),
     # ---- active observability plane (obs/slo.py, obs/server.py,
     # obs/aggregate.py; docs/observability.md) -------------------------
@@ -514,12 +513,6 @@ _PARAMS: Dict[str, Tuple[str, Any, Tuple[str, ...], Optional[Tuple[float, float]
     # construct+engine-init of the same shape compiles ZERO programs
     # (production retrains pay cold compiles on every job otherwise)
     "tpu_compile_cache_dir": _P("str", ""),
-    # leaf-histogram storage: "pool" keeps the [L+1, F, B, 3] carry and
-    # derives siblings by subtraction (the reference's HistogramPool);
-    # "rebuild" computes BOTH children per round in one scan — the masks
-    # pack into the matmul N dim, so the second child rides the MXU's
-    # 128-lane padding — bounding memory to O(leaf_batch * F * B)
-    "tpu_hist_mode": _P("str", "pool"),
     # leaf-ordered device row partition (ops/partition.py): rows ride
     # the grow-loop carry physically grouped by leaf, and each round's
     # histogram scans only the elected children's padded row spans
@@ -581,8 +574,7 @@ DISSOLVED_PARAMS: Dict[str, str] = {
                       "(feature-major bins_t + row-major bins)",
     "force_row_wise": "same as force_col_wise",
     "histogram_pool_size": "the histogram pool is a device array sized "
-                           "by num_leaves (tpu_hist_mode picks "
-                           "pool/rebuild); no LRU cache to bound",
+                           "by num_leaves; no LRU cache to bound",
     "is_enable_sparse": "sparse inputs are binned column-wise natively; "
                         "there is no dense/sparse bin representation "
                         "switch",
@@ -752,9 +744,6 @@ class Config:
         if str(self.tpu_hist_reduce) not in ("scatter", "psum"):
             log.fatal(f"Unknown tpu_hist_reduce {self.tpu_hist_reduce!r} "
                       f"(expected 'scatter' or 'psum')")
-        if str(self.tpu_hist_mode) not in ("pool", "rebuild"):
-            log.fatal(f"Unknown tpu_hist_mode {self.tpu_hist_mode!r} "
-                      f"(expected 'pool' or 'rebuild')")
         self.tpu_streaming = coerce_tristate(self.tpu_streaming,
                                              "tpu_streaming")
         self.tpu_stream_overlap = coerce_tristate(self.tpu_stream_overlap,

@@ -521,18 +521,14 @@ class Dataset:
 
     def _want_transposed_ingest(self, dtype) -> bool:
         """Emit the feature-major int8 ``bins_t`` tile during ingest?
-        Mirrors the engine's Pallas-kernel gate (uint8 bins + TPU +
-        tpu_use_pallas) so the host transpose in ``_DeviceData`` never
-        runs — the fused kernel writes both layouts per chunk."""
+        Where the engine's Pallas kernel will run, so the host transpose
+        in ``_DeviceData`` never runs — the fused kernel writes both
+        layouts per chunk."""
+        from ..capabilities import pallas_histogram_runs
         from ..config import get_param
-        if np.dtype(dtype) != np.uint8:
-            return False
-        if not get_param(self.params, "tpu_use_pallas"):
-            return False
-        if get_param(self.params, "tpu_double_precision_hist"):
-            return False
-        import jax
-        return jax.default_backend() == "tpu"
+        return pallas_histogram_runs(
+            np.iinfo(dtype).max + 1,
+            get_param(self.params, "tpu_double_precision_hist"))
 
     def _bin_all_columns(self, X, is_sparse: bool, dtype,
                          n_rows: int = None) -> np.ndarray:

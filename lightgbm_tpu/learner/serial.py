@@ -134,10 +134,6 @@ class GrowConfig:
     # the bundle maps before split finding. Mutually exclusive with
     # hist_scatter / feature_axis (engine enforces).
     has_bundles: bool = False
-    # True: no [L+1, F, B, 3] histogram pool — both children are
-    # histogrammed directly each round (one scan, masks packed into the
-    # matmul N dim), bounding memory to O(leaf_batch * F * B)
-    hist_rebuild: bool = False
     # leaf-ordered device row partition (ops/partition.py;
     # tpu_hist_partition): rows ride the carry physically grouped by
     # leaf (per-leaf offset/count tables + a stable cumsum front/back
@@ -145,7 +141,7 @@ class GrowConfig:
     # elected children's padded spans — a lax.switch over a static pow2
     # budget ladder, falling back to the masked full scan whenever the
     # spans would not shrink it. Siblings still come from pool
-    # subtraction (or ride the rebuild scan's N-packing).
+    # subtraction.
     partition: bool = False
     # block size of the TPU compact_rows-based repartition move
     # (<= 1024, divides the padded row count; the engine computes it)
@@ -422,7 +418,9 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         # block size must divide the padded row count; rows_per_block does
         # (padding guarantees it), so cap via gcd to keep the streamed
         # one-hot within scoped VMEM without breaking divisibility.
-        # R=4096 measured fastest on v5e at Higgs width, but the
+        # On the v5e the row block hardly matters since the kernel's
+        # lane loop (PERF.md §6, PR 30: R 2,048 / 4,096 / 8,192 read
+        # 7.44 / 7.42 / 7.28 ms a call at airline's 13 columns), but the
         # feature-blocked grid (more one-hot rows than one block holds,
         # e.g. MSLR widths) overflows the 16MB scoped-vmem budget at
         # 4096 — those shapes cap at 2048.
@@ -470,12 +468,11 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
     n_h = h_bins.shape[0]               # histogram-source row count
     if use_part:
         from ..ops import partition as part_ops
-        M_span = 2 * Kb if cfg.hist_rebuild else Kb
-        part_budgets = part_ops.span_budgets(n_h, M_span)
+        part_budgets = part_ops.span_budgets(n_h, Kb)
         # float32: the counter reaches n x rounds (x shards after the
         # psum) — int32 wraps at the very scales the metric watches
         _span_rows = jnp.asarray(
-            tuple(M_span * s for s in part_budgets) + (n_h,),
+            tuple(Kb * s for s in part_budgets) + (n_h,),
             jnp.float32)
 
         @obs.scope("grower/histogram")
@@ -740,8 +737,6 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
     if forced is not None:
         f_parent, f_is_left, f_feat, f_tbin, f_is_cat, f_bitset = forced
         M_f = cfg.n_forced
-        assert not cfg.hist_rebuild, \
-            "forced splits need the histogram pool"
 
     # ---- root ----------------------------------------------------------
     leaf_id0 = jnp.zeros(n_rows, dtype=i32)
@@ -803,12 +798,8 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
             has_split=(jnp.array(True) if forced is not None
                        else jnp.isfinite(root_best["gain"])),
             leaf_id=leaf_id0,
-            # rebuild mode carries no pool — a 1-element placeholder keeps
-            # the NamedTuple structure static
-            leaf_hist=(jnp.zeros((1, 1, 1, 1), jnp.float32)
-                       if cfg.hist_rebuild else
-                       set0(jnp.zeros((L + 1,) + root_hist.shape,
-                                      jnp.float32), root_hist)),
+            leaf_hist=set0(jnp.zeros((L + 1,) + root_hist.shape,
+                                     jnp.float32), root_hist),
             leaf_sums=set0(jnp.zeros((L + 1, 3), jnp.float32), root_sums),
             leaf_depth=jnp.zeros(L + 1, i32),
             best_gain=set0(jnp.full(L + 1, NEG_INF), root_best["gain"]),
@@ -1151,75 +1142,53 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         lsums = lsums_sel                      # [Kb, 3]
         rsums = rsums_sel
         psums = s.leaf_sums[tl_safe]
-        if cfg.hist_rebuild:
-            # ---- both children direct, one fused scan ------------------
-            # 2*Kb membership masks pack into the matmul N dimension;
-            # the sibling's histogram rides the MXU padding that the
-            # subtraction trick exists to avoid on CPUs
-            both_ids = jnp.concatenate([
-                jnp.where(valid, top_leaf, -1),
-                jnp.where(valid, new_ids, -1)]).astype(i32)
-            if use_part:
-                # partitioned: scan only the 2Kb children's padded spans
-                offs_k, cnts_k = span_tables(both_ids)
-                raw2, span_rows = span_hist(p_bins, p_vals, p_leaf,
-                                            both_ids, offs_k, cnts_k)
-                hist2 = hist_reduce(raw2)            # [2Kb, F, B, 3]
-            else:
-                hist2 = hist_multi(hist_lid, both_ids)
-                span_rows = jnp.asarray(n_h, jnp.float32)
-            left_hist, right_hist = hist2[:Kb], hist2[Kb:]
-            leaf_hist = s.leaf_hist
-            hist_ids = both_ids
+        # ---- smaller-child histogram + sibling subtraction ---------
+        left_smaller = lsums[:, 2] <= rsums[:, 2]
+        small_ids = jnp.where(
+            valid, jnp.where(left_smaller, top_leaf, new_ids),
+            -1).astype(i32)
+        if use_part:
+            # partitioned: scan only the Kb smaller children's spans
+            offs_k, cnts_k = span_tables(small_ids)
+            raw_s, span_rows = span_hist(p_bins, p_vals, p_leaf,
+                                         small_ids, offs_k, cnts_k)
+            hist_small = hist_reduce(raw_s)      # [Kb, F, B, 3]
         else:
-            # ---- smaller-child histogram + sibling subtraction ---------
-            left_smaller = lsums[:, 2] <= rsums[:, 2]
-            small_ids = jnp.where(
-                valid, jnp.where(left_smaller, top_leaf, new_ids),
-                -1).astype(i32)
-            if use_part:
-                # partitioned: scan only the Kb smaller children's spans
-                offs_k, cnts_k = span_tables(small_ids)
-                raw_s, span_rows = span_hist(p_bins, p_vals, p_leaf,
-                                             small_ids, offs_k, cnts_k)
-                hist_small = hist_reduce(raw_s)      # [Kb, F, B, 3]
-            else:
-                hist_small = hist_multi(hist_lid, small_ids)
-                span_rows = jnp.asarray(n_h, jnp.float32)
-            hist_ids = small_ids
-            with obs.scope("grower/histogram"):
-                # TPU note: the [L+1, F, B, 3] pool gather/scatter by leaf id
-                # lowers to serialized dynamic slices (~13 ms/round at
-                # nl=127); both become one-hot matmuls on the MXU instead.
-                # 0/1 weights with disjoint rows keep values exact; the
-                # trash lane L may accumulate a SUM of invalid lanes rather
-                # than the last write, but slot L is never an active leaf.
-                F_h = s.leaf_hist.shape[1]
-                pool_flat = s.leaf_hist.reshape(L + 1, -1)
-                leaf_ids_ax = jnp.arange(L + 1, dtype=i32)
-                oh_parent = (tl_safe[:, None]
-                             == leaf_ids_ax[None, :]).astype(jnp.float32)
-                parent_hist = jax.lax.dot_general(
-                    oh_parent, pool_flat,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST).reshape(
-                        Kb, F_h, B, 3)
-                hist_large = parent_hist - hist_small
-                ls4 = left_smaller[:, None, None, None]
-                left_hist = jnp.where(ls4, hist_small, hist_large)
-                right_hist = jnp.where(ls4, hist_large, hist_small)
-                oh_new = (new_ids[:, None]
-                          == leaf_ids_ax[None, :]).astype(jnp.float32)
-                upd = jax.lax.dot_general(
-                    jnp.concatenate([oh_parent, oh_new]).T,
-                    jnp.concatenate([left_hist, right_hist]).reshape(
-                        2 * Kb, -1),
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST)
-                written = (jnp.sum(oh_parent, axis=0)
-                           + jnp.sum(oh_new, axis=0)) > 0       # [L+1]
-                leaf_hist = jnp.where(written[:, None], upd,
-                                      pool_flat).reshape(s.leaf_hist.shape)
+            hist_small = hist_multi(hist_lid, small_ids)
+            span_rows = jnp.asarray(n_h, jnp.float32)
+        with obs.scope("grower/histogram"):
+            # TPU note: the [L+1, F, B, 3] pool gather/scatter by leaf id
+            # lowers to serialized dynamic slices (~13 ms/round at
+            # nl=127); both become one-hot matmuls on the MXU instead.
+            # 0/1 weights with disjoint rows keep values exact; the
+            # trash lane L may accumulate a SUM of invalid lanes rather
+            # than the last write, but slot L is never an active leaf.
+            F_h = s.leaf_hist.shape[1]
+            pool_flat = s.leaf_hist.reshape(L + 1, -1)
+            leaf_ids_ax = jnp.arange(L + 1, dtype=i32)
+            oh_parent = (tl_safe[:, None]
+                         == leaf_ids_ax[None, :]).astype(jnp.float32)
+            parent_hist = jax.lax.dot_general(
+                oh_parent, pool_flat,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST).reshape(
+                    Kb, F_h, B, 3)
+            hist_large = parent_hist - hist_small
+            ls4 = left_smaller[:, None, None, None]
+            left_hist = jnp.where(ls4, hist_small, hist_large)
+            right_hist = jnp.where(ls4, hist_large, hist_small)
+            oh_new = (new_ids[:, None]
+                      == leaf_ids_ax[None, :]).astype(jnp.float32)
+            upd = jax.lax.dot_general(
+                jnp.concatenate([oh_parent, oh_new]).T,
+                jnp.concatenate([left_hist, right_hist]).reshape(
+                    2 * Kb, -1),
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST)
+            written = (jnp.sum(oh_parent, axis=0)
+                       + jnp.sum(oh_new, axis=0)) > 0       # [L+1]
+            leaf_hist = jnp.where(written[:, None], upd,
+                                  pool_flat).reshape(s.leaf_hist.shape)
 
         depth2 = s.leaf_depth[tl_safe] + 1
         lvals = leaf_out(lsums)
@@ -1530,7 +1499,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                 rows_scanned=s.rows_scanned + span_rows,
                 hist_calls=s.hist_calls + 1,
                 hist_slots_filled=s.hist_slots_filled
-                + jnp.sum(hist_ids >= 0).astype(i32),
+                + jnp.sum(small_ids >= 0).astype(i32),
             )
         next_gains = _masked_gains(new.best_gain, new.leaf_depth,
                                    new.num_leaves, cfg.max_depth)
@@ -1572,11 +1541,9 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         "leaf_weight": final.leaf_vcw[:L, 2],
         "hist_rows": rows_scanned,
         # the grower's own work counts (boosting/gbdt.py feeds them to
-        # the hist.* counters); every call after the root's has
-        # hist_slots_per_call slots
+        # the hist.* counters); every call has Kb slots
         "hist_calls": final.hist_calls,
-        "hist_slots": Kb + (final.hist_calls - 1)
-        * (2 * Kb if cfg.hist_rebuild else Kb),
+        "hist_slots": Kb * final.hist_calls,
         "hist_slots_filled": final.hist_slots_filled,
     }
     if cfg.has_categorical:

@@ -35,8 +35,8 @@ two readers to the same numbers on the benchmark's recorded dump.
 ``train.copy_share`` / ``train.comm_share`` /
 ``train.wall_busy_gap_ms`` / ``train.layer_ms{scope=...}``.
 
-Consumed by ``engine.train`` (after a ``tpu_profile_dir`` trace stops),
-``bench.py --profile-dir``, and the ``scripts/trace_attr.py`` CLI. A
+Consumed by ``engine.train`` (after a ``tpu_profile_dir`` trace stops)
+and the ``scripts/trace_attr.py`` CLI. A
 dump with no device plane (the CPU backend's) is read through the
 host threads that ran XLA's ops, so the join can be checked without a
 chip; a dump with neither reports "no device plane found" instead of

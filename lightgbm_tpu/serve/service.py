@@ -230,8 +230,8 @@ class PredictService:
                                "— call start() first")
         # walk every pow2 bucket from the ENGINE's floor up to the
         # batch cap: steady-state dispatches of any coalesced size then
-        # reuse a compiled program (CompileWatch pins zero warm
-        # compiles across swap + eviction in serve_bench)
+        # reuse a compiled program (tests/test_serve_queue.py holds
+        # zero warm compiles across swap + eviction)
         from ..boosting.gbdt import PREDICT_ROW_BUCKET_FLOOR
         cap = self.queue.max_batch_rows
         for kind in kinds:

@@ -89,7 +89,7 @@ def donation_enabled(config) -> bool:
     and CPU tier-1 runs keep copy semantics; "true" forces it on any
     backend (the CPU client honors donation, which is what makes the
     donation-on/off bit-identity tests real); "false" disables it
-    everywhere (the ``bench.py --no-donate`` A/B arm). Donation and
+    everywhere (the off arm of the same tests). Donation and
     the persistent compilation cache combine freely on both backends
     (docs/perf.md "Iteration floor" has the check that showed it)."""
     v = str(getattr(config, "tpu_donate", "auto"))

@@ -15,9 +15,9 @@ the ops by self time with their scope, the ``%copy`` and collective
 shares, the device's idle gaps named by the program's host span that
 was open, and the host spans themselves. Parsing lives in
 ``lightgbm_tpu/obs/trace_attr.py`` (stdlib-only, no protobuf/jax
-import) so ``engine.train`` and ``bench.py --profile-dir`` feed the same
-numbers into the ``train.copy_share`` / ``train.wall_busy_gap_ms`` /
-``train.layer_ms`` gauges that scripts/obs_trend.py guards.
+import); ``engine.train`` feeds the same numbers into the
+``train.copy_share`` / ``train.wall_busy_gap_ms`` / ``train.layer_ms``
+gauges.
 
 ``--window`` names the host annotation that is the window (default: the
 outermost ``lgbm/train/*`` spans in the dump, else first op to last

@@ -238,6 +238,19 @@ def test_heartbeat_file_stamps_and_retires(tmp_path):
     assert not os.path.exists(path)        # clean finish = absent
 
 
+def test_heartbeat_dir_param_stamps_a_rank_file_while_training(tmp_path):
+    """``tpu_heartbeat_dir`` through the train params: the round loop
+    stamps ``heartbeat.train.rank<r>`` there (what the launcher's
+    watchdog reads), and a clean finish retires it."""
+    X, y = _data(n=1_500)
+    seen = []
+    lgb.train(dict(PARAMS, tpu_heartbeat_dir=str(tmp_path)),
+              lgb.Dataset(X, label=y), num_boost_round=3,
+              callbacks=[lambda env: seen.append(os.listdir(tmp_path))])
+    assert seen and all(s == ["heartbeat.train.rank0"] for s in seen)
+    assert os.listdir(tmp_path) == []      # clean finish = absent
+
+
 def test_stale_heartbeat_detection(tmp_path):
     from lightgbm_tpu.parallel.launch import _stale_heartbeats
     p = tmp_path / "heartbeat.train.rank2"
@@ -317,6 +330,32 @@ def test_hot_swap_zero_recompiles_and_degradation(tmp_path):
     assert not np.allclose(p_new, p_stale)
     assert not watch.stale and watch.swaps == 4
     assert obs.registry().get("serve.model_stale").value == 0.0
+
+
+def test_model_watch_params_start_the_watcher(tmp_path):
+    """``tpu_model_watch`` / ``tpu_model_watch_interval`` through the
+    params: the Booster is built watching that directory at that
+    interval, pinned to bucketed predict shapes, and adopts what is
+    published there; without the params there is no watcher."""
+    X, y = _data(n=1_500)
+    pub = tmp_path / "pub"
+    assert lgb.train(dict(PARAMS), lgb.Dataset(X, label=y),
+                     num_boost_round=2)._model_watch is None
+    server = lgb.train(dict(PARAMS, tpu_model_watch=str(pub),
+                            tpu_model_watch_interval=0.0),
+                       lgb.Dataset(X, label=y), num_boost_round=6)
+    watch = server._model_watch
+    assert watch is not None and watch.dir == str(pub)
+    assert watch.interval == 0.0
+    assert server.engine._stable_predict_shapes
+    p0 = server.predict(X[:200])
+    _publish(pub, seed=42, rounds=6)
+    assert not np.allclose(p0, server.predict(X[:200]))
+    assert watch.swaps == 1
+    timed = lgb.Booster(model_str=server.model_to_string(),
+                        params={"tpu_model_watch": str(pub),
+                                "tpu_model_watch_interval": 7.5})
+    assert timed._model_watch.interval == 7.5
 
 
 def test_watch_never_downgrades_a_newer_in_memory_model(tmp_path):

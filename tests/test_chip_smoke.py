@@ -121,19 +121,36 @@ def test_rehearsal_cannot_print_the_ok_line(monkeypatch, capsys, chips,
         "count": jax.device_count()}}
 
 
-def test_environment_names_the_compile_cache(monkeypatch, tmp_path):
+def _cache_by_call(path):
+    from lightgbm_tpu import config as config_mod
+    config_mod.setup_compile_cache(path)
+
+
+def _cache_by_dataset_params(path):
+    import lightgbm_tpu as lgb
+    X = np.random.default_rng(0).normal(size=(64, 3))
+    lgb.Dataset(X, label=(X[:, 0] > 0).astype(np.float64),
+                params={"tpu_compile_cache_dir": path,
+                        "verbosity": -1}).construct()
+
+
+@pytest.mark.parametrize("route", [_cache_by_call,
+                                   _cache_by_dataset_params])
+def test_environment_names_the_compile_cache(route, monkeypatch,
+                                             tmp_path):
     """Where a cache is live (the suite's comes from
     JAX_COMPILATION_CACHE_DIR via conftest), ``tpu_compile_cache_dir``
-    naming another directory warns and moves nothing."""
+    naming another directory warns and moves nothing, whether it comes
+    by a direct call or through a Dataset's params at construct."""
     from lightgbm_tpu import config as config_mod
     live = jax.config.jax_compilation_cache_dir
     assert live == os.environ["JAX_COMPILATION_CACHE_DIR"]
     warned = []
     monkeypatch.setattr(config_mod.log, "warning", warned.append)
-    config_mod.setup_compile_cache(str(tmp_path / "elsewhere"))
+    route(str(tmp_path / "elsewhere"))
     assert jax.config.jax_compilation_cache_dir == live
     assert len(warned) == 1 and "ignored" in warned[0]
-    config_mod.setup_compile_cache(live)         # the same one: silent
-    config_mod.setup_compile_cache("")
+    route(live)                                  # the same one: silent
+    route("")
     assert len(warned) == 1
     assert not (tmp_path / "elsewhere").exists()

@@ -94,6 +94,34 @@ def test_elastic_resume_4_to_2_and_8_bit_equal(tmp_path):
     assert after >= before + 2        # each re-cut resume counted
 
 
+def test_narrower_resume_publish_hot_swaps_into_a_warm_server(tmp_path):
+    """The resize cycle seen from serving: a warm resident server
+    watching the trainer's checkpoint directory adopts the dying 4-shard
+    trainer's publish, then the 2-shard resume's, drops no predict on
+    the way, and ends up serving the resized model."""
+    X, y = _data()
+    pub = tmp_path / "pub"
+    _kill_mid_run(X, y, 4, pub)
+    server = lgb.train({"objective": "binary", "num_leaves": 16,
+                        "max_depth": 4, "verbosity": -1},
+                       lgb.Dataset(X, label=y), num_boost_round=2)
+    server.watch_checkpoints(str(pub), interval=0.0)
+    Xq = X[:512]
+    server.predict(Xq)              # adopts the dying trainer's publish
+    assert server._model_watch.swaps == 1
+    resized = lgb.train(_params(2, pub), lgb.Dataset(X, label=y),
+                        num_boost_round=ROUNDS, resume_from=str(pub))
+    served = [server.predict(Xq) for _ in range(3)]   # none may raise
+    assert server._model_watch.swaps >= 2
+    assert not server._model_watch.stale
+    # checkpoints land every 2 rounds: the newest publish of the
+    # 5-round run is the resized model's first 4 trees
+    assert server.num_trees() == 4
+    np.testing.assert_allclose(
+        served[-1], resized.predict(Xq, num_iteration=4),
+        rtol=1e-5, atol=1e-6)
+
+
 def test_elastic_resume_mid_bagging_window(tmp_path):
     """Kill INSIDE a bagging_freq window, resume NARROWER: the bagging
     salt is a counter-hash of (bagging_seed, iter//freq, GLOBAL row

@@ -121,23 +121,3 @@ def test_bagging_mask_excludes_rows():
     assert tree["leaf_count"][:nl].sum() == 256
     # but all rows get routed to leaves
     assert leaf_id.shape[0] == n
-
-
-def test_hist_rebuild_equals_pool():
-    """tpu_hist_mode=rebuild (no histogram pool, both children direct)
-    must produce the same model as the subtraction pool, and its jitted
-    step must reserve far less memory at MSLR-ish widths."""
-    import lightgbm_tpu as lgb
-    rng = np.random.default_rng(42)
-    X = rng.normal(size=(3000, 12))
-    y = (X @ rng.normal(size=12) + rng.normal(scale=0.5, size=3000) > 0)
-    preds = {}
-    for mode in ("pool", "rebuild"):
-        bst = lgb.train(
-            {"objective": "binary", "num_leaves": 31, "verbosity": -1,
-             "min_data_in_leaf": 5, "tpu_hist_mode": mode,
-             "tpu_double_precision_hist": True},
-            lgb.Dataset(X, label=y.astype(float)), num_boost_round=8)
-        preds[mode] = bst.predict(X, raw_score=True)
-    np.testing.assert_allclose(preds["pool"], preds["rebuild"],
-                               rtol=1e-4, atol=1e-4)

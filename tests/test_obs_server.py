@@ -150,6 +150,34 @@ def test_start_server_is_idempotent_and_daemonized():
     obs_server.stop_server()             # idempotent
 
 
+def test_metrics_port_param_starts_the_endpoint():
+    """``tpu_metrics_port`` through the train params: 0 (the default)
+    starts nothing; a free port serves the run's counters on /metrics
+    with the windowed SLOs on, and ``tpu_heartbeat_timeout`` is the
+    endpoint's staleness limit."""
+    from lightgbm_tpu.parallel.launch import _free_port
+    rng = np.random.default_rng(9)
+    X = rng.normal(size=(800, 6))
+    y = (X[:, 0] > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 7, "verbosity": -1}
+    lgb.train(dict(params, tpu_metrics_port=0), lgb.Dataset(X, label=y),
+              num_boost_round=2)
+    assert obs_server.server() is None and not obs.slo_enabled()
+    port = _free_port()
+    try:
+        lgb.train(dict(params, tpu_metrics_port=port,
+                       tpu_heartbeat_timeout=7.0),
+                  lgb.Dataset(X, label=y), num_boost_round=3)
+        srv = obs_server.server()
+        assert srv is not None and srv.port == port
+        assert srv._httpd.heartbeat_timeout_s == 7.0
+        assert obs.slo_enabled()
+        code, text = _get(srv.url + "/metrics")
+        assert code == 200 and "train_iterations 3" in text
+    finally:
+        obs_server.stop_server()
+
+
 def _bucket_width_at(bounds, v):
     lo = 0.0
     for hi in bounds:

@@ -269,7 +269,7 @@ def test_profile_gauges_feed_obs_registry(dump_dir, monkeypatch):
             if not m.get("labels")}
     assert vals["train.copy_share"] == pytest.approx(0.35)
     # the synthetic dump has no collectives: comm_share feeds as 0,
-    # not as a missing gauge (obs_trend skips missing signals)
+    # not as a missing gauge
     assert vals["train.comm_share"] == pytest.approx(0.0)
     assert vals["train.wall_busy_gap_ms"] == pytest.approx(5.0)
     # degradation feeds nothing and reports why

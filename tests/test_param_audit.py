@@ -15,6 +15,9 @@ import pathlib
 import re
 import tokenize
 
+import numpy as np
+import pytest
+
 import lightgbm_tpu.config as C
 
 
@@ -88,3 +91,53 @@ def test_tables_are_disjoint_and_valid():
     # every dissolved rationale is a real sentence, not a stub
     for k, v in {**C.UNIMPLEMENTED_PARAMS, **C.DISSOLVED_PARAMS}.items():
         assert len(v) > 15, (k, v)
+
+
+# ---------------------------------------------------------------------------
+# names that left the table (PR 31) are unknown names like any other
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("name,value", [
+    ("tpu_hist_mode", "rebuild"),
+    ("tpu_use_pallas", False),
+    ("tpu_rows_per_block", 1024),
+])
+def test_removed_option_is_an_unknown_name(name, value):
+    """No alias, no shim: the name passes through ``Config.update`` to
+    ``raw_params`` as any unrecognized key does, nothing reads it, and
+    the model trained with it set is the model trained without it."""
+    import lightgbm_tpu as lgb
+    assert name not in C.Config.param_names()
+    unknown = C.Config({"verbosity": -1, "some_unknown_key": 1})
+    cfg = C.Config({"verbosity": -1, name: value})
+    assert not hasattr(cfg, name)
+    assert cfg.raw_params[name] == value
+    assert unknown.raw_params["some_unknown_key"] == 1
+    rng = np.random.default_rng(7)
+    X = rng.normal(size=(1500, 6))
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float64)
+    base = {"objective": "binary", "num_leaves": 15, "verbosity": -1}
+    models = [lgb.train(p, lgb.Dataset(X, label=y), num_boost_round=4)
+              .model_to_string() for p in (base, {**base, name: value})]
+    assert models[0] == models[1]
+
+
+# ---------------------------------------------------------------------------
+# option census: every tpu_* option has a caller that sets it
+# ---------------------------------------------------------------------------
+def test_every_tpu_option_is_set_somewhere():
+    """A ``tpu_*`` option that no test, no benchmark configuration or
+    entry and not ``chip_smoke.py`` ever sets has one value in use: it
+    should be a constant (two such options went in PR 31). "Set" means
+    the quoted name or a keyword argument in code, not prose."""
+    root = pathlib.Path(C.__file__).parent.parent
+    files = [p for p in (root / "tests").rglob("*.py")
+             if p.name != pathlib.Path(__file__).name]
+    files += sorted((root / "benchmark" / "configs").glob("*.json"))
+    files += sorted((root / "benchmark" / "entries").glob("*.py"))
+    files.append(root / "chip_smoke.py")
+    text = "\n".join(
+        _strip_comments_and_docstrings(p.read_text())
+        if p.suffix == ".py" else p.read_text() for p in files)
+    unset = [n for n in C.Config.param_names() if n.startswith("tpu_")
+             and not re.search(rf"[\"']{n}[\"'=]|\b{n}\s*=", text)]
+    assert not unset, f"tpu_* options nothing sets: {unset}"

@@ -169,7 +169,6 @@ def _grow_pair(cfg_kw, n=2048, f=6, seed=0):
 @pytest.mark.parametrize("cfg_kw", [
     {"leaf_batch": 1},
     {"leaf_batch": 8},
-    {"leaf_batch": 8, "hist_rebuild": True},
     {"leaf_batch": 4, "max_depth": 4},
 ])
 def test_grow_tree_partitioned_bit_identical(cfg_kw):
@@ -206,7 +205,6 @@ def _model_text(X, y, extra, rounds=6):
 
 QUANT_MATRIX = [
     ("pool", {"use_quantized_grad": True}),
-    ("rebuild", {"tpu_hist_mode": "rebuild", "use_quantized_grad": True}),
     ("goss", {"data_sample_strategy": "goss", "top_rate": 0.3,
               "other_rate": 0.2, "use_quantized_grad": True}),
     ("goss_compact", {"data_sample_strategy": "goss", "top_rate": 0.3,

@@ -289,6 +289,55 @@ def test_bounded_buffer_under_serving_load(trained, tmp_path,
     assert "serve/batch" in names and "serve/dispatch" in names
 
 
+STAGES = ("serve/queue_wait", "serve/coalesce", "serve/registry_checkout",
+          "serve/dispatch", "serve/postprocess")
+
+
+def test_tracing_flipped_on_warm_compiles_nothing_and_stages_add_up(
+        trained, tmp_path):
+    """Request tracing flipped ON over a warm service adds no XLA
+    program, and no stage of a request's life is unaccounted for: over
+    a window of one-rider batches the stages' p50s sum to within 10% of
+    the p50 from enqueue (the queue wait's start) to resolve (the batch
+    span's end)."""
+    from lightgbm_tpu.utils.debug import CompileWatch
+    bst, X = trained
+    obs.enable(metrics=True)
+    svc = _service(tpu_serve_batch_budget_ms=2.0)
+    try:
+        svc.add_model("m", bst)
+        svc.warmup("m", X[:1])
+        Xq = X[:64]
+        svc.predict("m", Xq, timeout=20)
+        with CompileWatch("trace-on-warm") as w:
+            svc.predict("m", Xq, timeout=20)
+            obs.enable(metrics=False, trace_dir=str(tmp_path))
+            for _ in range(60):
+                svc.predict("m", Xq, timeout=20)
+        w.assert_compiles(0)
+    finally:
+        svc.close()
+    # a group closes at its serve/batch event (the batch span exits
+    # last) and counts when it holds every stage once, for the batch's
+    # own request
+    groups, cur = [], {}
+    for e in obs_tracing.events():
+        if e["name"] in STAGES:
+            cur[e["name"]] = e
+        elif e["name"] == "serve/batch":
+            qw = cur.get("serve/queue_wait")
+            if len(cur) == len(STAGES) and \
+                    qw["args"].get("req") == e["args"].get("req"):
+                groups.append((cur, e))
+            cur = {}
+    assert len(groups) >= 50
+    e2e = np.median([b["ts"] + b["dur"] - g["serve/queue_wait"]["ts"]
+                     for g, b in groups])
+    staged = np.median([sum(g[s]["dur"] for s in STAGES)
+                        for g, _b in groups])
+    assert abs(staged - e2e) <= 0.10 * e2e, (staged, e2e)
+
+
 def test_tracing_off_leaves_no_serve_events(trained):
     """Off-by-default: metrics-only serving records histograms but no
     trace events and no flow points (the zero-cost-off bar)."""

@@ -296,3 +296,54 @@ def test_service_warmup_contrib_then_zero_compiles():
             atol=1e-12)
     finally:
         svc.close()
+
+
+def test_service_mixed_load_drops_nothing_and_feeds_the_explain_slo():
+    """Concurrent clients sending predicts and explains at once through
+    one warm service: every request resolves, nothing compiles,
+    ``serve.explain_requests`` counts every explain rider, and the
+    explain window behind ``slo.explain_p99_ms`` is live."""
+    from lightgbm_tpu.obs import slo as obs_slo
+    bst, X = _train(rounds=4, num_leaves=8)
+    obs.enable(metrics=True, slo=True)
+    svc = PredictService({"tpu_serve_batch_budget_ms": 2.0,
+                          "tpu_serve_max_batch_rows": 512,
+                          "tpu_serve_shard_trees": "false"})
+    explains, predicts, drops = [], [], []
+    stop = threading.Event()
+
+    def client(seed):
+        rng = np.random.default_rng(seed)
+        while not stop.is_set():
+            rows = X[rng.integers(0, len(X), size=64)]
+            try:
+                if rng.uniform() < 0.5:
+                    svc.submit("m", rows, kind="contrib").result(
+                        timeout=30)
+                    explains.append(1)
+                else:
+                    svc.predict("m", rows, timeout=30)
+                    predicts.append(1)
+            except Exception as e:   # noqa: BLE001 - a drop IS the bug
+                drops.append(e)
+
+    try:
+        svc.add_model("m", bst)
+        svc.warmup("m", X[:1], kinds=("predict", "contrib"))
+        threads = [threading.Thread(target=client, args=(i,),
+                                    daemon=True) for i in range(4)]
+        with CompileWatch("warm-mixed-load") as w:
+            for t in threads:
+                t.start()
+            stop.wait(0.8)
+            stop.set()
+            for t in threads:
+                t.join(timeout=30)
+        w.assert_compiles(0)
+    finally:
+        svc.close()
+    assert not drops, f"dropped {len(drops)}: {drops[:3]}"
+    assert explains and predicts, "the window ran only one kind"
+    assert obs.registry().get("serve.explain_requests").value >= \
+        len(explains)
+    assert obs_slo.tracker().compute()["slo.explain_p99_ms"] is not None

@@ -306,3 +306,24 @@ def test_slo_window_knob_alone_starts_tracker():
             "verbosity": -1})
     assert obs.slo_enabled()
     assert obs_slo.tracker().window_s == 60.0
+
+
+def test_slo_error_ratio_param_arms_the_breach_alert():
+    """``tpu_slo_error_ratio`` through the train params starts the
+    tracker with that threshold, and a window in which more than that
+    share of predicts raise flips ``slo.breached{slo=error_ratio}``."""
+    X, y = _data()
+    assert not obs.slo_enabled()
+    bst = lgb.train(dict(PARAMS, tpu_slo_error_ratio=0.25),
+                    lgb.Dataset(X, label=y), num_boost_round=3)
+    assert obs.slo_enabled()
+    assert obs_slo.tracker().thresholds == {"error_ratio": 0.25}
+    bst.predict(X[:10])
+    obs_slo.tracker().evaluate()
+    reg = obs.registry()
+    assert reg.get("slo.breached", slo="error_ratio").value == 0.0
+    for _ in range(3):
+        with pytest.raises(lgb.LightGBMError):
+            bst.predict(X[:10, :3])      # wrong feature count: raises
+    obs_slo.tracker().evaluate()
+    assert reg.get("slo.breached", slo="error_ratio").value == 1.0

@@ -5,8 +5,8 @@ Round 4 shipped a one-line NameError in ``GBDT.predict`` that failed
 train+predict ran before the snapshot. This file is the cheap gate:
 train + predict on dense AND scipy-sparse input in-session, model
 round-trip through the v4 text format, and sklearn predict — the four
-surfaces that NameError took down. It runs in seconds; ``make check``
-(scripts/check.sh) runs it before every snapshot.
+surfaces that NameError took down. It runs in seconds, as part of
+tier-1.
 
 Reference behavior being pinned: ``Booster.predict`` over dense/CSR
 inputs (upstream ``python-package/lightgbm/basic.py`` predict paths,

@@ -142,7 +142,7 @@ def test_streaming_valid_eval_and_early_stopping():
 def test_streaming_compatible_never_routes_fatal_configs():
     """_streaming_compatible must be a SUBSET of what StreamingGBDT
     accepts: auto-routing a config into its _no() fatals would turn a
-    train() that the resident engine handles into a crash (ADVICE r5:
+    train() that the resident engine handles into a crash (round-5 review:
     use_quantized_grad and bare cegb_tradeoff were missing gates;
     PR 7 lifted the quantization gate — explicit use_quantized_grad is
     now streaming-compatible and must construct, not fatal)."""
@@ -164,7 +164,7 @@ def test_streaming_compatible_never_routes_fatal_configs():
 def test_streaming_extra_trees_binds():
     """extra_trees must actually randomize streamed thresholds (it
     used to silently fall back to plain GBDT: find_best_split skips
-    the filter when extra_u is None — ADVICE r5)."""
+    the filter when extra_u is None — round-5 review)."""
     X, y = _data(n=8_000, seed=5)
     def train(extra_trees, seed=1):
         return lgb.train(dict(BASE, tpu_streaming="true",
@@ -184,7 +184,7 @@ def test_streaming_extra_trees_binds():
 def test_streaming_sparse_valid_rejected():
     """scipy-sparse raw valid features fail early with the standard
     unsupported message instead of crashing mid-eval on len(sparse)
-    (ADVICE r5)."""
+    (round-5 review)."""
     pytest.importorskip("scipy")
     import scipy.sparse as sp
     from lightgbm_tpu.utils.log import LightGBMError

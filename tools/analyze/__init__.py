@@ -1,8 +1,7 @@
 """Repo-native static analysis: six drift linters + allowlists.
 
 ``python -m tools.analyze`` — dependency-free (stdlib ``ast``), < 10 s,
-wired into scripts/check.sh (``lint_findings=`` on the obs line, exit
-code 6) and enforced absolutely by scripts/obs_trend.py. Catalogue,
+held at zero findings by ``tests/test_analysis.py`` (tier-1). Catalogue,
 allowlist workflow and how-to-add-a-checker: docs/static-analysis.md.
 
 Checkers (each with ``tools/analyze/allowlists/<name>.txt``):
@@ -90,9 +89,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="run only this checker (repeatable)")
     ap.add_argument("--no-allowlists", action="store_true",
                     help="show findings the allowlists would suppress")
-    ap.add_argument("--emit-count", metavar="FILE",
-                    help="write the finding count to FILE regardless "
-                         "of exit status (scripts/check.sh reads it)")
     args = ap.parse_args(argv)
     for c in (args.checker or []):
         if c not in CHECKERS:
@@ -104,9 +100,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     for f in findings:
         print(f.render())
     n = len(findings)
-    if args.emit_count:
-        with open(args.emit_count, "w") as fh:
-            fh.write(f"{n}\n")
     print(f"tools.analyze: {n} finding(s) across "
           f"{len(args.checker or CHECKERS)} checker(s) "
           f"in {time.monotonic() - t0:.2f}s")

@@ -126,9 +126,9 @@ class SourceSet:
 
 
 def discover_sources(root: str) -> List[str]:
-    """Repo-relative python files the suite lints: the library, the
-    benches, and the entry scripts (tests and tools lint themselves via
-    their own suites)."""
+    """Repo-relative python files the suite lints: the library and the
+    two entry scripts (tests and tools lint themselves via their own
+    suites)."""
     out: List[str] = []
     lib = os.path.join(root, "lightgbm_tpu")
     for dirpath, _dirs, files in os.walk(lib):
@@ -136,14 +136,9 @@ def discover_sources(root: str) -> List[str]:
             if fn.endswith(".py"):
                 out.append(os.path.relpath(os.path.join(dirpath, fn),
                                            root))
-    for extra in ("bench.py", "__graft_entry__.py"):
+    for extra in ("__graft_entry__.py", "chip_smoke.py"):
         if os.path.exists(os.path.join(root, extra)):
             out.append(extra)
-    bdir = os.path.join(root, "benchmarks")
-    if os.path.isdir(bdir):
-        for fn in sorted(os.listdir(bdir)):
-            if fn.endswith(".py"):
-                out.append(os.path.join("benchmarks", fn))
     return out
 
 

@@ -10,7 +10,7 @@ the metric/span/heartbeat name catalogue, in BOTH directions.
   by hand).
 
 Docs side: backticked tokens in docs/observability.md shaped like a
-metric name (lowercase dotted/slashed path). ``bench.*``-style entries
+metric name (lowercase dotted/slashed path). ``slo.*``-style entries
 are prefix wildcards. ``{label=...}`` suffixes are stripped. Tokens
 that are obviously API/file references (``obs.enable``, ``*.py``) are
 ignored. Code side: names built dynamically (f-strings, dict-driven
