@@ -145,7 +145,7 @@ def phase_device(run: Run) -> dict:
 
 
 def phase_kernels(run: Run, rows: int = 1 << 17) -> dict:
-    """The two Pallas kernels against their XLA references at the
+    """The three Pallas kernels against their XLA references at the
     flagship shape — the content of the on-chip test groups
     (tests/test_multi_leaf_histogram.py, tests/test_compact.py), run
     where it can run. Off the chip they run in interpret mode."""
@@ -159,6 +159,8 @@ def phase_kernels(run: Run, rows: int = 1 << 17) -> dict:
                                           plan_compaction)
     from lightgbm_tpu.ops.pallas_histogram import (
         multi_leaf_histogram, multi_leaf_histogram_xla)
+    from lightgbm_tpu.ops.route import (route_nodes, route_rows,
+                                        route_rows_xla)
     F, B, K, R = N_FEATURES, 256, 32, 4096
     check(rows % R == 0, f"kernel rows {rows} not a multiple of {R}")
     pallas = (contextlib.nullcontext if run.on_tpu
@@ -230,11 +232,36 @@ def phase_kernels(run: Run, rows: int = 1 << 17) -> dict:
                               rows_per_block=Rc)
     np.testing.assert_array_equal(ob, np.asarray(eb))
     np.testing.assert_array_equal(ov, np.asarray(ev))
+
+    # the table routed through a finished tree of 127 leaves (node j
+    # split a leaf made before it; half the nodes set-splits over 256
+    # bins, NaN bins on both sides): bit-equal to the loop over nodes
+    n_nodes = 126
+    feat = rng.integers(0, F, size=n_nodes)
+    nodes = route_nodes(
+        n_nodes, jnp.asarray(feat),
+        jnp.asarray(rng.integers(0, B, size=n_nodes)),
+        jnp.asarray(rng.random(n_nodes) < 0.5),
+        jnp.asarray(rng.integers(0, np.arange(n_nodes) + 1)),
+        jnp.full(F, B, jnp.int32), jnp.asarray(rng.random(F) < 0.5),
+        is_cat=jnp.asarray(rng.random(n_nodes) < 0.5),
+        cat_bitset=jnp.asarray(rng.integers(
+            0, 2**32, size=(n_nodes, B // 32), dtype=np.uint64
+        ).astype(np.uint32)))
+    tail = rows - R     # no multiple of the kernel's block: a ragged one
+    with pallas():
+        ids = np.asarray(route_rows(bins_t[:, :tail], nodes))
+    np.testing.assert_array_equal(
+        ids, np.asarray(route_rows_xla(jnp.asarray(bins[:tail]), nodes)))
+    leaves = int(len(np.unique(ids)))
+    check(leaves > 64, "route_rows reached few leaves")
     return {"rows": rows, "shape": {"F": F, "B": B, "K": K},
             "pallas": "compiled" if run.on_tpu else "interpret",
             "hist_f32_max_abs_err": f32_err, "hist_int8": "exact",
             "hist_int8_ragged": "exact",
-            "compact_rows": "bit-equal", "kept_rows": int(mask.sum())}
+            "compact_rows": "bit-equal", "kept_rows": int(mask.sum()),
+            "route_rows": "bit-equal",
+            "route_rows_leaves": leaves}
 
 
 def phase_ingest(run: Run) -> dict:

@@ -1441,10 +1441,11 @@ class GBDT:
         # (goss.hpp bag_data_indices_). Here: ONE lax.sort moves the
         # sampled rows into a fixed-size front buffer (static n_sub >=
         # worst-case sample), HISTOGRAMS scan only that buffer, and the
-        # full-row leaf_id partition + one-hot score update stay exactly
-        # as in the masked path (perf.md measured them cheap — the
-        # round-2 traversal-based score update is what made full
-        # compaction lose). Sample choice is bit-identical to the
+        # one-hot score update stays exactly as in the masked path; the
+        # full-row leaf ids it reads are routed once a tree, after the
+        # grower's loop (learner/serial.py `defer_full`, PERF.md §6 PR
+        # 32: routing them at every loop trip was a quarter of an
+        # iteration). Sample choice is bit-identical to the
         # masked path (same RNG stream); histogram float sums may
         # differ only in accumulation order (exact in quantized mode).
         renews_obj = (type(obj).renew_tree_output
@@ -1592,9 +1593,10 @@ class GBDT:
                         U_eff = U_new | ~in_sample[:, None]
                         tree = {kk: v for kk, v in tree.items()
                                 if kk != "leaf_used"}
-                    # FULL leaf ids came from the in-loop partition; the
+                    # FULL leaf ids: routed once after the loop, through
+                    # the finished tree (in the loop under lazy CEGB); the
                     # score update is the same one-hot matmul as the
-                    # masked path (no per-row traversal)
+                    # masked path
                     new_score = add_contrib(new_score, k, tree, leaf_id)
                     trees.append(tree)
                     leaf_ids.append(leaf_id)
@@ -1789,7 +1791,8 @@ class GBDT:
                          # psum'd inside grow_tree, calls and slots are
                          # the same on every shard
                          "hist_rows", "hist_calls", "hist_slots",
-                         "hist_slots_filled"]
+                         "hist_slots_filled", "route_rows",
+                         "route_final"]
             if self.has_categorical:
                 tree_keys += ["is_cat", "cat_bitset"]
             tree_specs = {k: rep for k in tree_keys}
@@ -2238,9 +2241,11 @@ class GBDT:
         host = dict(host)
         total = {k: float(np.sum(host.pop(k), dtype=np.float64))
                  for k in ("hist_rows", "hist_calls", "hist_slots",
-                           "hist_slots_filled")}
+                           "hist_slots_filled", "route_rows",
+                           "route_final")}
         cols = total["hist_rows"]
         label = int(bool(sampled))
+        n_trees = host["num_leaves"].size
         for name, value in (
                 # columns the calls were handed: the static buffer
                 # length, or the elected spans under hist_partition
@@ -2251,7 +2256,14 @@ class GBDT:
                 ("hist.leaf_slots_filled", total["hist_slots_filled"]),
                 # the kernel's VPU and MXU work by its own account: the
                 # one-hot rows it builds for a column scanned
-                ("hist.onehot_elems", cols * self._hist_onehot_per_col)):
+                ("hist.onehot_elems", cols * self._hist_onehot_per_col),
+                # rows the grower's row -> leaf passes were handed, over
+                # the table's rows a tree: how often the table is walked
+                ("partition.rows_routed", total["route_rows"]),
+                ("partition.rows_table",
+                 float(self.data.n_pad) * n_trees),
+                # trees whose table was routed once, after the loop
+                ("partition.final_routes", total["route_final"])):
             obs.inc(name, value, force=True, sampled=label)
         # splits these trees made, and how many of them are set-splits
         n_nodes = host["num_leaves"][..., None] - 1
@@ -2263,7 +2275,7 @@ class GBDT:
                     float(np.sum(host["is_cat"].astype(bool) & live)),
                     force=True, sampled=label)
         if sampled:
-            n_iters = host["num_leaves"].size // self.num_class
+            n_iters = n_trees // self.num_class
             rows_in, rows_kept = self._goss_rows
             obs.inc("goss.rows_in", float(rows_in * n_iters), force=True)
             obs.inc("goss.rows_kept", float(rows_kept * n_iters),

@@ -40,6 +40,7 @@ from .. import obs
 from ..ops.pallas_histogram import (multi_leaf_histogram,
                                     multi_leaf_histogram_xla,
                                     onehot_layout)
+from ..ops.route import route_nodes, route_rows, route_rows_xla
 from ..ops.split import (NEG_INF, SplitConfig, calc_leaf_output,
                          elect_best, find_best_split, per_feature_gains,
                          smooth_output)
@@ -74,8 +75,9 @@ class GrowConfig:
     # rows for those bins only. () = every column has num_bins
     hist_col_bins: Tuple[int, ...] = ()
     # GOSS histogram-only compaction: histograms scan the compacted
-    # sampled-row buffer (grow_tree's `compact` argument) while the
-    # full-row partition/score path stays masked
+    # sampled-row buffer (grow_tree's `compact` argument); the table's
+    # own leaf ids, which the score update reads, are routed once after
+    # the loop (in it only where lazy CEGB reads them)
     hist_compact: bool = False
     # forced splits (forcedsplits_filename): number of entries in the
     # PREORDER-flattened forced-split table (grow_tree's `forced`
@@ -277,6 +279,10 @@ class GrowState(NamedTuple):
     # compact-row leaf ids for GOSS histogram-only compaction ([1]
     # placeholder otherwise): partitioned by the same splits as leaf_id
     leaf_id_c: jnp.ndarray
+    # the leaf each node split ([L]; [1] placeholder unless the table is
+    # routed after the loop): left_child forgets it when that leaf
+    # splits again, and ops/route.py replays the nodes by it
+    node_leaf: jnp.ndarray
     # forced-split machinery (placeholder when cfg.n_forced == 0):
     # each entry's state: -1 waiting on parent, >=0 realized target
     # leaf slot, -2 cancelled (skipped parent), -3 applied
@@ -350,11 +356,10 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
     scfg = cfg.split_config
 
     # GOSS histogram-only compaction (cfg.hist_compact): histograms scan
-    # a COMPACTED buffer of just the sampled rows while the full-row
-    # leaf_id partition/score path stays masked (the split perf.md
-    # proved cheap) — the reference's bag_data_indices_ subset scan,
-    # without its gather. Both partitions run the same split logic; the
-    # compact leaf ids ride the carry alongside the full ones.
+    # a COMPACTED buffer of just the sampled rows — the reference's
+    # bag_data_indices_ subset scan, without its gather. The buffer's
+    # leaf ids ride the carry and are routed at every loop trip; the
+    # table's are not (`defer_full` below).
     if not cfg.hist_compact:
         compact = None
     if compact is not None:
@@ -592,6 +597,15 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
 
     if not cfg.has_cegb_lazy:
         lazy = None
+    # Under a compact buffer the histograms read leaf_id_c alone, and
+    # inside the loop nothing else reads the table's own ids but the
+    # lazy penalty: so the loop does not route the table's rows at all.
+    # They are routed once, after it, through the finished tree
+    # (ops/route.py), for the one reader a tree has: the score update.
+    defer_full = compact is not None and lazy is None
+    if defer_full:
+        assert not cfg.has_bundles and not cfg.feature_axis, \
+            "a compact buffer is handed by the serial, unbundled step only"
     if lazy is not None:
         lazy_U, lazy_pen = lazy
         notU = (1.0 - lazy_U.astype(jnp.float32)).astype(jnp.bfloat16)
@@ -739,7 +753,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         M_f = cfg.n_forced
 
     # ---- root ----------------------------------------------------------
-    leaf_id0 = jnp.zeros(n_rows, dtype=i32)
+    leaf_id0 = jnp.zeros(1 if defer_full else n_rows, dtype=i32)
     leaf_id0_c = jnp.zeros(n_rows_c, dtype=i32)
     if use_part:
         # initial layout: every histogram-source row belongs to the
@@ -845,6 +859,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                       if use_mono_adv else jnp.zeros((1, 1), i32)),
             leaf_id_c=(leaf_id0_c if compact is not None
                        else jnp.zeros(1, i32)),
+            node_leaf=jnp.zeros(L if defer_full else 1, i32),
             forced_target=(jnp.where(f_parent < 0, 0, -1).astype(i32)
                            if forced is not None else jnp.zeros(1, i32)),
             part_bins=part_bins0,
@@ -1091,7 +1106,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                 goes_left = jnp.where(is_cat_r, cat_left, goes_left)
             return jnp.where(sel_rows & ~goes_left, new_leaf_r, lf_vec)
 
-        leaf_id = apply_splits(lf, bins)
+        leaf_id = lf if defer_full else apply_splits(lf, bins)
         # under the leaf-ordered partition the compact-buffer masked ids
         # are dead (histograms read part_leaf instead) — skip the pass
         leaf_id_c = (apply_splits(s.leaf_id_c, bins_c)
@@ -1489,6 +1504,8 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                 leaf_flo=leaf_flo2,
                 leaf_fhi=leaf_fhi2,
                 leaf_id_c=leaf_id_c,
+                node_leaf=(s.node_leaf.at[node_ids].set(tl_safe)
+                           if defer_full else s.node_leaf),
                 forced_target=(forced_tgt_next if forced is not None
                                else s.forced_target),
                 part_bins=p_bins,
@@ -1520,6 +1537,34 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
     final = jax.lax.while_loop(cond, body, state)
 
     nn = max(L - 1, 1)
+    if defer_full:
+        # nodes are numbered in the order they were made and node j's
+        # right child is leaf j + 1, so replaying nodes 0 .. num_leaves
+        # - 2 in order gives every row the id the in-loop pass would
+        # have: the decision is apply_splits' own, in integers
+        with obs.scope("grower/partition"):
+            nodes = route_nodes(
+                final.num_leaves - 1, final.split_feature[:nn],
+                final.threshold_bin[:nn], final.default_left[:nn],
+                final.node_leaf[:nn], feat_num_bin, feat_has_nan,
+                is_cat=(final.node_is_cat[:nn] if cfg.has_categorical
+                        else None),
+                cat_bitset=(final.node_cat_bitset[:nn]
+                            if cfg.has_categorical else None))
+            leaf_id_out = (route_rows(bins_t, nodes) if cfg.use_pallas
+                           else route_rows_xla(bins, nodes))
+    else:
+        leaf_id_out = final.leaf_id
+    # rows the row -> leaf passes were handed this tree (the table's and
+    # the compact buffer's; the leaf-ordered partition's per-position
+    # pass is the move's own): each loop trip's, and the one after it
+    trips = (final.hist_calls - 1).astype(jnp.float32)
+    rows_routed = trips * float(
+        (0 if defer_full else n_rows)
+        + (n_rows_c if compact is not None and not use_part else 0)
+    ) + float(n_rows if defer_full else 0)
+    if cfg.axis_name:
+        rows_routed = jax.lax.psum(rows_routed, cfg.axis_name)
     # total rows the histogram scans touched this tree: the structural
     # "fewer rows" win of the partition path (masked = n per round);
     # summed over shards so every device reports the global figure
@@ -1545,6 +1590,9 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         "hist_calls": final.hist_calls,
         "hist_slots": Kb * final.hist_calls,
         "hist_slots_filled": final.hist_slots_filled,
+        # and its row -> leaf work (the partition.* counters)
+        "route_rows": rows_routed,
+        "route_final": jnp.array(int(defer_full), i32),
     }
     if cfg.has_categorical:
         # only emitted when categorical features exist, so downstream
@@ -1558,4 +1606,4 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         # (rows acquire a feature when a split on it is applied above
         # them — cost_effective_gradient_boosting.hpp)
         tree["leaf_used"] = final.leaf_used[:L]
-    return tree, final.leaf_id
+    return tree, leaf_id_out

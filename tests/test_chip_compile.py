@@ -19,6 +19,7 @@ compilation cache is off around them (a described-chip executable is
 written but cannot be read back without the chip).
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,6 +125,30 @@ def test_compact_rows_compiles(one_chip, F, C):
     assert "tpu_custom_call" in text
 
 
+def _route_nodes(s, n_nodes, W):
+    from lightgbm_tpu.ops.route import RouteNodes
+    col = s((n_nodes,), jnp.int32)
+    return RouteNodes(count=s((), jnp.int32), feature=col, threshold=col,
+                      flip_bin=col, leaf=col, is_cat=col if W else None,
+                      bitset=s((n_nodes, W), jnp.int32) if W else None)
+
+
+@pytest.mark.parametrize("F,W,n", [
+    (13, 0, 57_503_744),   # the airline cell's table: thresholds only
+    (39, 8, 22_921_216),   # the click-log cell's: 256-bin bitsets
+], ids=["airline", "criteo"])
+def test_route_rows_compiles(one_chip, F, W, n):
+    """The table routed through a finished tree of 127 leaves, at the
+    cells' own lengths (airline's is no multiple of the kernel's block:
+    its last block is ragged) and widths."""
+    from lightgbm_tpu.ops.route import route_rows
+    s = functools.partial(_sds, one_chip)
+    text = _compiled_text(route_rows.lower(
+        s((F, n), jnp.int8), _route_nodes(s, 126, W)),
+        temp_limit=2**28)       # ids out and nothing else
+    assert "%route_rows." in text
+
+
 @pytest.mark.parametrize("kernel", ["multi_leaf_histogram",
                                     "compact_rows"])
 def test_pallas_call_lowers_with_its_pinned_name(one_chip, kernel):
@@ -212,6 +237,54 @@ def test_grow_tree_compiles(one_chip, as_tpu, variant):
                        if "%multi_leaf_histogram." in ln
                        and "custom-call(" in ln)
     assert "lgbm/grower/histogram" in kernel_line
+    # the in-loop row -> leaf pass gives every row its split by a
+    # float32 attribute matrix, [rows, 6] (thresholds) or [rows, 6 + 1 +
+    # 2 x 8] (bitsets). Handed a compact buffer, the loop builds the
+    # buffer's alone, and the table is routed once, after it, by the
+    # route_rows kernel under the same scope
+    attr = f"f32[{{}},{23 if cfg.has_categorical else 6}]"
+    assert (attr.format(N) in text) == (not cfg.hist_compact)
+    assert ("%route_rows." in text) == cfg.hist_compact
+    if cfg.hist_compact:
+        assert attr.format(n_c) in text
+        route_line = next(ln for ln in text.splitlines()
+                          if "%route_rows." in ln and "custom-call(" in ln)
+        assert "lgbm/grower/partition" in route_line
+
+
+def test_goss_compact_chunk_program_compiles(one_chip, as_tpu):
+    """The program both benchmark cells run in their window: a fused
+    chunk of GOSS iterations on the compact buffer (airline's 13
+    columns at 131,072 rows; the engine's sizes are constants of its
+    programs). ``route_rows`` sits inside it, and no float32 attribute
+    matrix of the FULL table is left (the ``[n_pad, 23]`` one is what
+    kept the click-log table's 45.8M rows from compiling, PERF.md §4)."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.boosting.gbdt import GBDT
+    from lightgbm_tpu.config import Config
+    rng = np.random.default_rng(0)
+    n = 1 << 17
+    X = np.stack([rng.integers(0, b, n) for b in AIRLINE_BINS],
+                 axis=1).astype(np.float32)
+    eng = GBDT(Config({"objective": "binary", "verbosity": -1,
+                       "num_leaves": 127, "use_quantized_grad": True,
+                       "data_sample_strategy": "goss", "top_rate": 0.2,
+                       "other_rate": 0.1}),
+               lgb.Dataset(X, label=(X[:, 0] > 3).astype(np.float32)))
+    assert eng._use_goss_compact and eng.use_pallas
+    chunk = eng._make_chunk(True)
+    program = next(c.cell_contents for c in chunk.__closure__
+                   if hasattr(c.cell_contents, "lower"))
+    s = functools.partial(_sds, one_chip)
+    d = eng.data
+    n_pad, F = d.bins.shape
+    text = _compiled_text(program.lower(
+        s((n_pad, F), d.bins.dtype), s((F, n_pad), jnp.int8),
+        s((n_pad,), jnp.float32), None, s((n_pad, 1), jnp.float32),
+        s((n_pad,), jnp.float32), s((5, 2), jnp.uint32)))
+    for kernel in ("compact_rows", "multi_leaf_histogram", "route_rows"):
+        assert f"%{kernel}." in text, kernel
+    assert not re.search(rf"f32\[{n_pad},(6|23)\]", text)
 
 
 def test_grow_tree_compiles_under_shard_map_on_four_chips(topo, as_tpu):
@@ -256,8 +329,6 @@ def test_goss_sample_program_orders_no_row(one_chip):
     the optimised program holds no sort and no top-k, and every pass of
     a select is ONE fusion that reads the rows once and leaves only
     counts (``goss.select_passes`` counts those reads)."""
-    import re
-
     import lightgbm_tpu as lgb
     from lightgbm_tpu.boosting.gbdt import GBDT
     from lightgbm_tpu.config import Config

@@ -323,7 +323,8 @@ def test_metrics_off_by_default_records_nothing():
     # counters the grower and ingest always keep (once a step or chunk)
     names = {m.name for m in obs.registry().metrics()}
     assert names and all(n.startswith(("hist.", "goss.", "ingest.",
-                                       "split.", "tree.", "bundle."))
+                                       "split.", "tree.", "bundle.",
+                                       "partition."))
                          for n in names), names
     assert not obs.enabled()
 
