@@ -217,21 +217,28 @@ def phase_kernels(run: Run, rows: int = 1 << 17) -> dict:
         np.testing.assert_array_equal(h_pl, h_xla)
         check(float(np.abs(h_pl).sum()) > 0, "ragged histogram all zero")
 
-    # row compaction: bit-equal at F=28, C=3, keep-fraction 0.3
+    # row compaction: bit-equal at F=28, C=3, keep shares 0.3 (GOSS's:
+    # a block fills 3 or 4 of its 9 destination groups) and 1.0 (all 9:
+    # what the partition move's passes can see)
     Rc = 1024
-    mask = rng.uniform(size=rows) < 0.3
-    out_cols = compaction_out_cols(int(mask.sum()), Rc, 1024)
     v3 = jnp.asarray(rng.normal(size=(3, rows)).astype(np.float32))
-    dest, algn, rem = plan_compaction(jnp.asarray(mask), Rc, out_cols)
-    cargs = (bins_t, v3, dest, algn, rem)
-    with pallas():
-        ob, ov = compact_rows(*cargs, out_cols=out_cols,
-                              rows_per_block=Rc)
-        ob, ov = np.asarray(ob), np.asarray(ov)
-    eb, ev = compact_rows_xla(*cargs, out_cols=out_cols,
-                              rows_per_block=Rc)
-    np.testing.assert_array_equal(ob, np.asarray(eb))
-    np.testing.assert_array_equal(ov, np.asarray(ev))
+    onehot_rows = {}
+    for share in (0.3, 1.0):
+        mask = rng.uniform(size=rows) < share
+        out_cols = compaction_out_cols(int(mask.sum()), Rc, 1024)
+        dest, algn, rem, nch = plan_compaction(jnp.asarray(mask), Rc,
+                                               out_cols)
+        with pallas():
+            ob, ov = compact_rows(bins_t, v3, dest, algn, rem, nch,
+                                  out_cols=out_cols, rows_per_block=Rc)
+            ob, ov = np.asarray(ob), np.asarray(ov)
+        eb, ev = compact_rows_xla(bins_t, v3, dest, algn, rem,
+                                  out_cols=out_cols, rows_per_block=Rc)
+        np.testing.assert_array_equal(ob, np.asarray(eb))
+        np.testing.assert_array_equal(ov, np.asarray(ev))
+        onehot_rows[share] = 128.0 * float(np.mean(np.asarray(nch)))
+    check(onehot_rows[0.3] < 0.5 * onehot_rows[1.0] <= 0.5 * (Rc + 128),
+          f"compact_rows builds {onehot_rows} one-hot rows a block")
 
     # the table routed through a finished tree of 127 leaves (node j
     # split a leaf made before it; half the nodes set-splits over 256
@@ -259,7 +266,8 @@ def phase_kernels(run: Run, rows: int = 1 << 17) -> dict:
             "pallas": "compiled" if run.on_tpu else "interpret",
             "hist_f32_max_abs_err": f32_err, "hist_int8": "exact",
             "hist_int8_ragged": "exact",
-            "compact_rows": "bit-equal", "kept_rows": int(mask.sum()),
+            "compact_rows": "bit-equal",
+            "compact_onehot_rows": onehot_rows[0.3],
             "route_rows": "bit-equal",
             "route_rows_leaves": leaves}
 

@@ -1460,9 +1460,10 @@ class GBDT:
         import math as _math
         from ..ops.compact import (compact_rows, compact_rows_xla,
                                    compaction_out_cols, plan_compaction)
-        # compaction block size: <= 1024 (kernel VMEM budget) and a
-        # divisor of n_pad (a rows_per_block multiple, itself a
-        # multiple of 256: R_c is 256, 512 or 1024)
+        # compaction block size: 1024 where it divides n_pad (the
+        # kernel's best on the chip, PERF.md §6 PR 35; n_pad is a
+        # rows_per_block multiple, itself a multiple of 256: R_c is
+        # 256, 512 or 1024)
         R_c = _math.gcd(1024, gcfg.rows_per_block)
         frac = top_rate + other_rate
         n_sub = compaction_out_cols(
@@ -1538,10 +1539,11 @@ class GBDT:
                     vals_all = jnp.concatenate(
                         [g2.T, h2.T, mask_gh[None], mask_count[None]],
                         axis=0).astype(jnp.float32)       # [2K+2, n]
-                    dest, algn, rem = plan_compaction(sel, R_c, n_sub)
+                    dest, algn, rem, nch = plan_compaction(sel, R_c,
+                                                           n_sub)
                     if bins_t is not None:
                         bins_t_c, vc = compact_rows(
-                            bins_t, vals_all, dest, algn, rem,
+                            bins_t, vals_all, dest, algn, rem, nch,
                             out_cols=n_sub, rows_per_block=R_c)
                         # int8 -> uint8 reinterpret restores bin values
                         # for the row-major partition path
@@ -1552,6 +1554,10 @@ class GBDT:
                             out_cols=n_sub, rows_per_block=R_c)
                         bins_c = bt_any.T
                         bins_t_c = None
+                    # one-hot destination rows built, blocks handed
+                    compact_work = {
+                        "compact_onehot_rows": 128 * jnp.sum(nch),
+                        "compact_blocks": jnp.array(nch.shape[0], jnp.int32)}
                     g_c = vc[:K].T
                     h_c = vc[K:2 * K].T
                     mgh_c = vc[2 * K]
@@ -1598,6 +1604,11 @@ class GBDT:
                     # score update is the same one-hot matmul as the
                     # masked path
                     new_score = add_contrib(new_score, k, tree, leaf_id)
+                    # the iteration's one compaction, by the plan's own
+                    # account (the compact.* counters), on class 0's tree
+                    tree = dict(tree, **(
+                        compact_work if k == 0
+                        else jax.tree.map(jnp.zeros_like, compact_work)))
                     trees.append(tree)
                     leaf_ids.append(leaf_id)
                 stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
@@ -2243,6 +2254,9 @@ class GBDT:
                  for k in ("hist_rows", "hist_calls", "hist_slots",
                            "hist_slots_filled", "route_rows",
                            "route_final")}
+        # only the step that compacts GOSS's sample carries these
+        compact = {k: float(np.sum(host.pop(k, 0.0), dtype=np.float64))
+                   for k in ("compact_onehot_rows", "compact_blocks")}
         cols = total["hist_rows"]
         label = int(bool(sampled))
         n_trees = host["num_leaves"].size
@@ -2273,6 +2287,13 @@ class GBDT:
             live = np.arange(host["is_cat"].shape[-1]) < n_nodes
             obs.inc("split.chosen_cat",
                     float(np.sum(host["is_cat"].astype(bool) & live)),
+                    force=True, sampled=label)
+        if compact["compact_blocks"]:
+            # one-hot destination rows compact_rows built (128 a group
+            # a block fills) and the blocks it was handed
+            obs.inc("compact.onehot_rows", compact["compact_onehot_rows"],
+                    force=True, sampled=label)
+            obs.inc("compact.blocks", compact["compact_blocks"],
                     force=True, sampled=label)
         if sampled:
             n_iters = n_trees // self.num_class

@@ -13,21 +13,28 @@ This kernel removes both limits with the TPU's two strong units:
 - per row-block, the kept rows' within-block destinations (a cheap XLA
   segmented cumsum, computed OUTSIDE the kernel) become a one-hot
   permutation matrix ``P_T[d, s] = [dest[s] + rem == d]`` generated on
-  the VPU in natural [sublane=dst, lane=src] layout;
-- the block's columns are moved by ONE MXU matmul per operand group
-  (int8 for bins — wrap-exact; bf16 for value channels — exact for the
-  histogram operands, which are themselves bf16/int-level downstream);
+  the VPU in natural [sublane=dst, lane=src] layout, ONE 128-row
+  destination group at a time and only the groups the block fills
+  (``plan_compaction``'s ``nch``: a block that keeps 30% of 1,024 rows
+  fills 3 or 4 of its window's 9);
+- a group moves everything a row carries by ONE bf16 MXU matmul with
+  f32 accumulation (bins are exact in bf16; the value channels ride as
+  a 3-way bf16 significand split and come out bit-exact);
 - the compacted block is DMA'd to HBM at the 128-aligned floor of its
   exact stream position. The ≤127 columns of *partial* output group at
-  that position are first DMA'd back in and re-emitted (the grid is
-  sequential on TPU, so the read sees the predecessor's write), which
-  makes the packing EXACT — kept rows land contiguously, no per-block
-  padding waste.
+  that position are re-emitted from the predecessor's window, which is
+  still in VMEM (the grid is sequential on TPU; two window slots), and
+  that makes the packing EXACT — kept rows land contiguously, no
+  per-block padding waste. A block's write runs under the next block's
+  matmuls; the windows overlap in HBM, so each waits for the one before.
 
-Cost is O(n·R) compares + O(n·R·F) int8 MACs — independent of F's
-*operand packing*, so wide datasets (Bosch F=200, Criteo F=199) compact
-as cheaply per byte as the Higgs shape. Measured numbers live in
-docs/perf.md ("Row compaction kernel").
+Cost is O(n·128·nch) compares + as many (F + 24)-row MAC columns — a
+block's work follows the rows it KEEPS, not the window it may write —
+plus a fixed part a block (grid step, operand split, DMAs). Chip
+figures are in PERF.md §6 "PR 35" (the probe behind this shape: whole
+window, four matmuls, head read back from HBM 3.76 us a block; this
+kernel 0.84, of which 0.40 fixed; 1,024 rows a block beat 512 and 2,048);
+docs/perf.md "Row compaction kernel" has the design notes.
 """
 from __future__ import annotations
 
@@ -54,7 +61,7 @@ def compaction_out_cols(max_selected: int, rows_per_block: int,
 
 def plan_compaction(mask: jax.Array, rows_per_block: int,
                     out_cols: int
-                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Within-block destinations + per-block aligned write positions.
 
     Args:
@@ -70,7 +77,9 @@ def plan_compaction(mask: jax.Array, rows_per_block: int,
       (dest ``[n]`` int32 within-block destination or -1 for dropped
       rows, aligned ``[nb]`` int32 block write positions in 128-lane
       GROUP units, rem ``[nb]`` int32 partial-group length at each
-      block's start).
+      block's start, nch ``[nb]`` int32 the 128-lane destination groups
+      each block fills, ``ceil((rem + kept) / 128)``: all the kernel
+      builds of a block's ``R / 128 + 1``).
     """
     n = mask.shape[0]
     R = rows_per_block
@@ -84,88 +93,112 @@ def plan_compaction(mask: jax.Array, rows_per_block: int,
     aligned = jnp.minimum(stream // _LANE,
                           (out_cols - R - _LANE) // _LANE)
     rem = stream - aligned * _LANE
-    return dest, aligned, rem
+    # (a clamped block's rem may pass 127: never past the window)
+    nch = jnp.minimum(-(-(rem + cnt) // _LANE), R // _LANE + 1)
+    return dest, aligned, rem, nch
 
 
-def _compact_kernel(algn_ref, rem_ref, dest_ref, bins_ref, vals_ref,
-                    bins_out, vals_out, bins_vmem, vals_vmem,
-                    bins_head, vals_head, sem_b, sem_v, sem_hb, sem_hv,
+def _compact_kernel(algn_ref, fill_ref, dest_ref, bins_ref, vals_ref,
+                    bins_out, vals_out, bins_vmem, vals_vmem, sem_b, sem_v,
                     *, rows_per_block: int):
     b = pl.program_id(0)
     R = rows_per_block
     W = R + _LANE
-    off = algn_ref[b] * _LANE
-    rem = rem_ref[b]
-    # read back the predecessor's partial output group at this block's
-    # aligned position (sequential grid -> the write has landed); at
-    # b == 0 this reads uninitialized columns, masked off below (rem=0)
-    rb = pltpu.make_async_copy(
-        bins_out.at[:, pl.ds(off, _LANE)], bins_head, sem_hb)
-    rv = pltpu.make_async_copy(
-        vals_out.at[:, pl.ds(off, _LANE)], vals_head, sem_hv)
-    rb.start()
-    rv.start()
-    # one-hot permutation, transposed layout [dst(sublane), src(lane)]:
+    F_pad, C_pad = bins_ref.shape[0], vals_ref.shape[0]
+    # two window slots: this block builds one while the predecessor's
+    # is on its way to HBM
+    slot = b % 2
+    prev = 1 - slot
+    a_now = algn_ref[b]
+    a_prev = algn_ref[jnp.maximum(b - 1, 0)]
+    # rem and nch in one SMEM word (see compact_rows)
+    rem = fill_ref[b] & 0xFFFF
+    nch = fill_ref[b] >> 16
     # dropped rows (dest == -1) match no destination; kept rows land
     # after the rem carried-over columns (the shift must not touch the
     # -1 sentinel, which rem > 0 would otherwise lift to a real column)
     d0 = dest_ref[...]
     dest = jnp.where(d0 >= 0, d0 + rem, -1)                 # [1, R]
-    iota_d = jax.lax.broadcasted_iota(jnp.int32, (W, R), 0)
-    eq = iota_d == dest                                     # [W, R]
-    moved = jax.lax.dot_general(
-        bins_ref[...], eq.astype(jnp.int8),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)                   # [F, W]
-    # value channels move EXACTLY via a 3-way bf16 significand split
-    # (8+8+8 >= f32's 24 mantissa bits — the bf16x3 decomposition XLA
-    # itself uses for f32 emulation): each one-hot product selects one
-    # chunk unrounded, and the f32 chunk sum reconstructs the value
-    # bit-for-bit. A single bf16 pass would RE-ROUND grads and GOSS
-    # amplification weights; f32-HIGHEST costs +4.4 ms (measured).
-    p_bf = eq.astype(jnp.bfloat16)
+    # ONE bf16 operand for everything a row carries. The bins' -128..127
+    # are exact in bf16. The value channels move EXACTLY via a 3-way
+    # bf16 significand split (8+8+8 >= f32's 24 mantissa bits — the
+    # bf16x3 decomposition XLA itself uses for f32 emulation): a one-hot
+    # product with f32 accumulation selects one chunk unrounded, and
+    # the f32 chunk sum reconstructs the value bit-for-bit. A single
+    # bf16 pass would RE-ROUND grads and GOSS amplification weights.
     v = vals_ref[...]
-    h1 = v.astype(jnp.bfloat16)
-    r1 = v - h1.astype(jnp.float32)
-    h2 = r1.astype(jnp.bfloat16)
-    h3 = (r1 - h2.astype(jnp.float32)).astype(jnp.bfloat16)
-    _dn = (((1,), (1,)), ((), ()))
-    vmoved = (jax.lax.dot_general(h1, p_bf, dimension_numbers=_dn,
-                                  preferred_element_type=jnp.float32)
-              + jax.lax.dot_general(h2, p_bf, dimension_numbers=_dn,
-                                    preferred_element_type=jnp.float32)
-              + jax.lax.dot_general(h3, p_bf, dimension_numbers=_dn,
-                                    preferred_element_type=jnp.float32))
-    rb.wait()
-    rv.wait()
+    h1 = v.astype(jnp.bfloat16).astype(jnp.float32)
+    r1 = v - h1
+    h2 = r1.astype(jnp.bfloat16).astype(jnp.float32)
+    h3 = r1 - h2
+    rows = jnp.concatenate(
+        [bins_ref[...].astype(jnp.int32).astype(jnp.float32), h1, h2, h3],
+        axis=0).astype(jnp.bfloat16)                # [F_pad + 3 C_pad, R]
+    iota_d = jax.lax.broadcasted_iota(jnp.int32, (_LANE, R), 0)
+    bw = bins_vmem.at[slot]
+    vw = vals_vmem.at[slot]
+
+    def group(c, carry):
+        # one 128-wide destination group: its slice of the one-hot
+        # permutation, transposed layout [dst(sublane), src(lane)]
+        col = pl.multiple_of(c * _LANE, _LANE)
+        eq = (iota_d == dest - col).astype(jnp.bfloat16)    # [128, R]
+        m = jax.lax.dot_general(
+            rows, eq, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        bw[:, pl.ds(col, _LANE)] = m[:F_pad].astype(jnp.int32) \
+            .astype(jnp.int8)
+        vw[:, pl.ds(col, _LANE)] = (
+            m[F_pad:F_pad + C_pad] + m[F_pad + C_pad:F_pad + 2 * C_pad]
+            + m[F_pad + 2 * C_pad:])
+        return carry
+
+    # only the groups the block fills: the rest of the window keeps what
+    # an earlier step left there, past the stream's end at every step
+    jax.lax.fori_loop(0, nch, group, 0)
+    # the predecessor's partial output group is group (off - off_prev)
+    # / 128 of the window it built (the grid is sequential); at b == 0
+    # that slot is uninitialized, masked off below (rem = 0)
+    g = jnp.clip(a_now - a_prev, 0, W // _LANE - 1)
+    hcol = pl.multiple_of(g * _LANE, _LANE)
     head_ok = (jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
                < rem)
-    zero_w = jnp.zeros((bins_head.shape[0], R), jnp.int32)
-    head_b = jnp.concatenate(
-        [jnp.where(head_ok, bins_head[...].astype(jnp.int32), 0),
-         zero_w], axis=1)
-    # signed-wrap back to the int8 storage convention (uint8 values
-    # stored with wraparound; a plain astype would CLAMP 128..255)
-    m8 = (moved + head_b) & 0xFF
-    bins_vmem[...] = (m8 - ((m8 >> 7) << 8)).astype(jnp.int8)
-    zero_vw = jnp.zeros((vals_head.shape[0], R), jnp.float32)
-    vals_vmem[...] = vmoved + jnp.concatenate(
-        [jnp.where(head_ok, vals_head[...], 0.0), zero_vw], axis=1)
-    cb = pltpu.make_async_copy(
-        bins_vmem, bins_out.at[:, pl.ds(off, W)], sem_b)
-    cv = pltpu.make_async_copy(
-        vals_vmem, vals_out.at[:, pl.ds(off, W)], sem_v)
-    cb.start()
-    cv.start()
-    cb.wait()
-    cv.wait()
+    bw[:, :_LANE] = jnp.where(
+        head_ok, bins_vmem[prev, :, pl.ds(hcol, _LANE)].astype(jnp.int32),
+        bw[:, :_LANE].astype(jnp.int32)).astype(jnp.int8)
+    vw[:, :_LANE] = jnp.where(
+        head_ok, vals_vmem[prev, :, pl.ds(hcol, _LANE)], vw[:, :_LANE])
+
+    def write(s, group0):
+        at = pl.ds(group0 * _LANE, W)
+        return (pltpu.make_async_copy(bins_vmem.at[s], bins_out.at[:, at],
+                                      sem_b.at[s]),
+                pltpu.make_async_copy(vals_vmem.at[s], vals_out.at[:, at],
+                                      sem_v.at[s]))
+
+    # successive windows OVERLAP in HBM, so two writes are never in
+    # flight together: the predecessor's ran under this block's loop
+    # and has to land before this one starts
+    @pl.when(b > 0)
+    def _():
+        for dma in write(prev, a_prev):
+            dma.wait()
+
+    mine = write(slot, a_now)
+    for dma in mine:
+        dma.start()
+
+    @pl.when(b == pl.num_programs(0) - 1)
+    def _():
+        for dma in mine:
+            dma.wait()
 
 
 @functools.partial(jax.jit,
                    static_argnames=("out_cols", "rows_per_block"))
 def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
-                 aligned: jax.Array, rem: jax.Array, *, out_cols: int,
-                 rows_per_block: int = 1024
+                 aligned: jax.Array, rem: jax.Array, nch: jax.Array, *,
+                 out_cols: int, rows_per_block: int = 1024
                  ) -> Tuple[jax.Array, jax.Array]:
     """Compact kept columns of feature-major arrays (TPU Pallas path).
 
@@ -174,7 +207,7 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
       vals_t: ``[C, n]`` float32 channel-major per-row values (grad,
         hess, count-mask, optionally leaf_id+1 — any C). Moved
         bit-exactly (bf16x3 significand split in the kernel).
-      dest / aligned / rem: from ``plan_compaction`` (same
+      dest / aligned / rem / nch: from ``plan_compaction`` (same
         rows_per_block).
       out_cols: static output width (``compaction_out_cols``).
 
@@ -188,10 +221,11 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
     C = vals_t.shape[0]
     R = rows_per_block
     assert n % R == 0, f"n={n} must be a multiple of rows_per_block={R}"
-    # the [R+128, R] permutation's bf16 copy + the streamed operands
-    # fit comfortably at R=1024 (~3.5 MB); R=2048 measured slower
-    # anyway (P generation cost scales n*R)
-    assert R <= 1024, f"rows_per_block={R} exceeds the VMEM-safe 1024"
+    # whole 128-lane groups; 512 to 2048 probed on the chip (1024 best:
+    # a group's compares and MXU tiles scale with R, the fixed part a
+    # block with 1 / R), nothing larger
+    assert R % _LANE == 0 and R <= 2048, \
+        f"rows_per_block={R}: a multiple of 128, at most 2048"
     assert out_cols >= R + _LANE, "out_cols below one write window"
     nb = n // R
     W = R + _LANE
@@ -205,6 +239,10 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
     if C_pad > C:
         vals_t = jnp.concatenate(
             [vals_t, jnp.zeros((C_pad - C, n), vals_t.dtype)])
+    # the per-block scalars live in SMEM, 1 MB in all: rem and nch share
+    # a word, so a block costs 8 bytes there and 115M rows still fit (a
+    # clamped block's rem, already past saving, is cut to 16 bits)
+    fill = (nch << 16) | jnp.minimum(rem, 0xFFFF)
     # NO input_output_aliases on the output windows (examined, round 7
     # — docs/perf.md "Iteration floor"): out_cols != n by construction
     # (compaction_out_cols adds one block of write slack + lane
@@ -218,23 +256,19 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
             num_scalar_prefetch=2,
             grid=(nb,),
             in_specs=[
-                pl.BlockSpec((1, R), lambda b, a, r: (0, b)),
-                pl.BlockSpec((F_pad, R), lambda b, a, r: (0, b)),
-                pl.BlockSpec((C_pad, R), lambda b, a, r: (0, b)),
+                pl.BlockSpec((1, R), lambda b, a, f: (0, b)),
+                pl.BlockSpec((F_pad, R), lambda b, a, f: (0, b)),
+                pl.BlockSpec((C_pad, R), lambda b, a, f: (0, b)),
             ],
             out_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             scratch_shapes=[
-                pltpu.VMEM((F_pad, W), jnp.int8),
-                pltpu.VMEM((C_pad, W), jnp.float32),
-                pltpu.VMEM((F_pad, _LANE), jnp.int8),
-                pltpu.VMEM((C_pad, _LANE), jnp.float32),
-                pltpu.SemaphoreType.DMA,
-                pltpu.SemaphoreType.DMA,
-                pltpu.SemaphoreType.DMA,
-                pltpu.SemaphoreType.DMA,
+                pltpu.VMEM((2, F_pad, W), jnp.int8),
+                pltpu.VMEM((2, C_pad, W), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
             ],
         ),
         out_shape=[
@@ -243,11 +277,14 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
         ],
         # the device op's name, pinned: profile readers match it
         name="compact_rows",
-    )(aligned, rem, dest.reshape(1, n), bins_t, vals_t)
-    # Pallas outputs are uninitialized; zero everything past the last
-    # block's write window so downstream scans see zero contributions
-    col_ok = (jnp.arange(out_cols, dtype=jnp.int32)
-              < aligned[-1] * _LANE + W)[None, :]
+    )(aligned, fill, dest.reshape(1, n), bins_t, vals_t)
+    # Pallas outputs are uninitialized and a window's groups past the
+    # ones its block filled hold whatever an earlier step left: zero
+    # everything past the stream's exact end (the last block's start
+    # plus its kept rows) so downstream scans see zero contributions
+    end = (aligned[-1] * _LANE + rem[-1]
+           + jnp.max(dest[n - R:]) + 1)
+    col_ok = (jnp.arange(out_cols, dtype=jnp.int32) < end)[None, :]
     return (jnp.where(col_ok, out_b[:F], jnp.int8(0)),
             jnp.where(col_ok, out_v[:C], jnp.float32(0.0)))
 

@@ -148,14 +148,16 @@ def move_cols_tpu(bins_fm: jax.Array, vals_fm: jax.Array,
     n = bins_fm.shape[1]
     out_cols = compaction_out_cols(n, rows_per_block, rows_per_block)
     keep_front = ~moved
-    d1, a1, r1 = plan_compaction(keep_front, rows_per_block, out_cols)
-    fb, fv = compact_rows(bins_fm, vals_fm, d1, a1, r1,
-                          out_cols=out_cols,
-                          rows_per_block=rows_per_block)
-    d2, a2, r2 = plan_compaction(moved, rows_per_block, out_cols)
-    bb, bv = compact_rows(bins_fm, vals_fm, d2, a2, r2,
-                          out_cols=out_cols,
-                          rows_per_block=rows_per_block)
+    # the two masks are complements: between them the passes build
+    # about R / 128 + 1 destination groups a block, not twice that
+    fb, fv = compact_rows(
+        bins_fm, vals_fm,
+        *plan_compaction(keep_front, rows_per_block, out_cols),
+        out_cols=out_cols, rows_per_block=rows_per_block)
+    bb, bv = compact_rows(
+        bins_fm, vals_fm,
+        *plan_compaction(moved, rows_per_block, out_cols),
+        out_cols=out_cols, rows_per_block=rows_per_block)
     sel = (jnp.arange(n, dtype=i32) < n_front)[None, :]
     bb_r = jnp.roll(bb[:, :n], n_front, axis=1)
     bv_r = jnp.roll(bv[:, :n], n_front, axis=1)

@@ -136,6 +136,10 @@ def test_slow_fault_delays_without_changing_the_model():
     """A straggler rank is SLOW, not wrong: the injected delay must
     cost wall clock and change nothing else."""
     X, y = _data(n=1_000)
+    # an untimed run first: the timed pair then both find their programs
+    # compiled (a cold first run of 1.4 s once read slower than the
+    # delayed second, under six busy workers)
+    lgb.train(dict(PARAMS), lgb.Dataset(X, label=y), num_boost_round=4)
     t0 = time.monotonic()
     clean = lgb.train(dict(PARAMS), lgb.Dataset(X, label=y),
                       num_boost_round=4)

@@ -110,17 +110,37 @@ def test_multi_leaf_histogram_compiles(one_chip, col_bins, B, K, int_mode):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("F,C", [(28, 3), (200, 4)])
+# Higgs and Bosch widths, then the benchmark cells' (airline, criteo:
+# their four value channels are g, h and GOSS's two masks)
+@pytest.mark.parametrize("F,C", [(28, 3), (200, 4), (13, 4), (39, 4)])
 def test_compact_rows_compiles(one_chip, F, C):
     from lightgbm_tpu.ops.compact import (compact_rows,
                                           compaction_out_cols)
     s = functools.partial(_sds, one_chip)
     R = 1024
     out_cols = compaction_out_cols(int(SLAB * 0.3), R, 4096)
+    per_block = s((SLAB // R,), jnp.int32)
     text = _compiled_text(compact_rows.lower(
         s((F, SLAB), jnp.int8), s((C, SLAB), jnp.float32),
-        s((SLAB,), jnp.int32), s((SLAB // R,), jnp.int32),
-        s((SLAB // R,), jnp.int32), out_cols=out_cols,
+        s((SLAB,), jnp.int32), per_block, per_block, per_block,
+        out_cols=out_cols, rows_per_block=R))
+    assert "tpu_custom_call" in text
+
+
+def test_compact_rows_block_scalars_fit_smem(one_chip):
+    """The kernel keeps two int32 words a block in SMEM (1 MB in all),
+    so Airline's full 115M rows, 112,305 blocks of 1,024, still
+    compile; a third word a block would not fit."""
+    from lightgbm_tpu.ops.compact import (compact_rows,
+                                          compaction_out_cols)
+    s = functools.partial(_sds, one_chip)
+    R, nb = 1024, 112_305
+    n = nb * R
+    per_block = s((nb,), jnp.int32)
+    text = _compiled_text(compact_rows.lower(
+        s((13, n), jnp.int8), s((4, n), jnp.float32), s((n,), jnp.int32),
+        per_block, per_block, per_block,
+        out_cols=compaction_out_cols(int(n * 0.3) + 8192, R, 4096),
         rows_per_block=R))
     assert "tpu_custom_call" in text
 
@@ -172,6 +192,7 @@ def test_pallas_call_lowers_with_its_pinned_name(one_chip, kernel):
                 *a, out_cols=out_cols, rows_per_block=1024)
         args = (s((13, SLAB), jnp.int8), s((4, SLAB), jnp.float32),
                 s((SLAB,), jnp.int32), s((SLAB // 1024,), jnp.int32),
+                s((SLAB // 1024,), jnp.int32),
                 s((SLAB // 1024,), jnp.int32))
     lowered = jax.jit(renamed).lower(*args)
     assert f'kernel_name = "{kernel}"' in lowered.as_text()

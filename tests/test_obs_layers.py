@@ -208,6 +208,26 @@ def test_counters_are_kept_with_obs_off_and_carry_sampled():
     assert per_call[1] < per_call[0] == bst.engine.data.n_pad
 
 
+def test_compact_counters_count_the_groups_a_block_fills():
+    """``compact.*``: the sampled program's one compaction an iteration,
+    by the plan's own account. GOSS keeps 0.3 of the rows, so a block
+    fills under half of the destination groups its window has."""
+    import math
+    n = 40000
+    bst = _train({"tpu_leaf_batch": 1}, rounds=6, n=n)
+    eng = bst.engine
+    assert eng._use_goss_compact
+    R_c = math.gcd(1024, eng.grow_cfg.rows_per_block)
+    blocks = _counter("compact.blocks", sampled=1)
+    assert blocks == 4 * (eng.data.n_pad // R_c)
+    rows = _counter("compact.onehot_rows", sampled=1)
+    assert rows % 128 == 0
+    assert _counter("goss.rows_kept") <= rows < blocks * (R_c + 128) / 2
+    # the un-sampled program compacts nothing
+    assert _counter("compact.blocks", sampled=0) is None
+    assert _counter("compact.onehot_rows", sampled=0) is None
+
+
 def test_ingest_counters_are_kept_with_obs_off():
     X, y = _data(10000)
     ds = lgb.Dataset(X, label=y, params={
