@@ -1806,6 +1806,8 @@ class GBDT:
                          "route_final"]
             if self.has_categorical:
                 tree_keys += ["is_cat", "cat_bitset"]
+            if self.hist_partition:
+                tree_keys += ["move_calls", "move_rows"]
             tree_specs = {k: rep for k in tree_keys}
             # 4th output = cegb_U (always None under mesh — lazy CEGB
             # requires the serial learner; the spec matches structure
@@ -2144,6 +2146,9 @@ class GBDT:
             with obs.span("train/valid_update"):
                 self.valid_scores = self._valid_update(self.valid_scores,
                                                        stacked)
+            # rows of the validation sets this round's trees scored
+            obs.inc("valid.rows_scored",
+                    float(sum(dd.n for dd in self.valid_data)), force=True)
         with obs.span("train/fetch_trees"):
             host_trees = self._fetch_tree_arrays(stacked)
         self._append_host_trees(self._count_work(host_trees, goss_active))
@@ -2254,9 +2259,11 @@ class GBDT:
                  for k in ("hist_rows", "hist_calls", "hist_slots",
                            "hist_slots_filled", "route_rows",
                            "route_final")}
-        # only the step that compacts GOSS's sample carries these
+        # only the step that compacts GOSS's sample carries the first
+        # two, only a tree grown under the leaf-ordered partition the rest
         compact = {k: float(np.sum(host.pop(k, 0.0), dtype=np.float64))
-                   for k in ("compact_onehot_rows", "compact_blocks")}
+                   for k in ("compact_onehot_rows", "compact_blocks",
+                             "move_rows", "move_calls")}
         cols = total["hist_rows"]
         label = int(bool(sampled))
         n_trees = host["num_leaves"].size
@@ -2294,6 +2301,13 @@ class GBDT:
             obs.inc("compact.onehot_rows", compact["compact_onehot_rows"],
                     force=True, sampled=label)
             obs.inc("compact.blocks", compact["compact_blocks"],
+                    force=True, sampled=label)
+        if compact["move_calls"]:
+            # rows the partition's mover was handed (the whole histogram
+            # source a loop trip) and its moves (two kernel passes each)
+            obs.inc("partition.rows_moved", compact["move_rows"],
+                    force=True, sampled=label)
+            obs.inc("partition.move_calls", compact["move_calls"],
                     force=True, sampled=label)
         if sampled:
             n_iters = n_trees // self.num_class
@@ -2707,6 +2721,7 @@ class GBDT:
         Returns list of (data_name, metric_name, value, higher_better).
         """
         from ..metric import eval_metric_rows
+        obs.inc("eval.calls", force=True)
         if which < 0:
             dd, name = self.data, "training"
             raw = np.asarray(self.score)[:dd.n]

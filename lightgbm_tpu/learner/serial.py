@@ -602,8 +602,14 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
     # lazy penalty: so the loop does not route the table's rows at all.
     # They are routed once, after it, through the finished tree
     # (ops/route.py), for the one reader a tree has: the score update.
-    defer_full = compact is not None and lazy is None
-    if defer_full:
+    # The same holds under the leaf-ordered partition, whose histograms
+    # read the partition's own per-position ids (where the route can
+    # replay the tree: unbundled columns, all of them on this device).
+    defer_full = lazy is None and (
+        compact is not None
+        or (cfg.partition and not cfg.has_bundles
+            and not cfg.feature_axis))
+    if compact is not None and defer_full:
         assert not cfg.has_bundles and not cfg.feature_axis, \
             "a compact buffer is handed by the serial, unbundled step only"
     if lazy is not None:
@@ -772,7 +778,10 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
     root_small = jnp.concatenate(
         [jnp.zeros(1, i32), jnp.full(Kb - 1, -1, i32)]) if Kb > 1 \
         else jnp.zeros(1, i32)
+    # every row of the histogram source starts in leaf 0 (the table's own
+    # ids are a placeholder where the loop does not route them)
     root_hist = hist_multi(leaf_id0_c if compact is not None
+                           else part_leaf0 if use_part
                            else leaf_id0, root_small)[0]
     root_sums = jnp.sum(h_vals, axis=0)
     if cfg.axis_name:
@@ -1594,6 +1603,16 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         "route_rows": rows_routed,
         "route_final": jnp.array(int(defer_full), i32),
     }
+    if use_part:
+        # the leaf-ordered partition's mover: one stable front/back move
+        # of the whole histogram source a loop trip (two kernel passes
+        # on the TPU); only emitted on that path, so every other
+        # program is the one it was
+        moves = trips
+        if cfg.axis_name:
+            moves = jax.lax.psum(moves, cfg.axis_name)
+        tree["move_calls"] = moves
+        tree["move_rows"] = moves * float(n_h)
     if cfg.has_categorical:
         # only emitted when categorical features exist, so downstream
         # traversal (tree_predict_binned) skips the bitset branch — and

@@ -195,10 +195,11 @@ def _compact_kernel(algn_ref, fill_ref, dest_ref, bins_ref, vals_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("out_cols", "rows_per_block"))
+                   static_argnames=("out_cols", "rows_per_block", "name"))
 def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
                  aligned: jax.Array, rem: jax.Array, nch: jax.Array, *,
-                 out_cols: int, rows_per_block: int = 1024
+                 out_cols: int, rows_per_block: int = 1024,
+                 name: str = "compact_rows"
                  ) -> Tuple[jax.Array, jax.Array]:
     """Compact kept columns of feature-major arrays (TPU Pallas path).
 
@@ -210,6 +211,9 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
       dest / aligned / rem / nch: from ``plan_compaction`` (same
         rows_per_block).
       out_cols: static output width (``compaction_out_cols``).
+      name: the device op's name (the leaf-ordered partition's mover
+        passes ``partition_move``, so a trace tells its passes from
+        GOSS's compaction).
 
     Returns:
       (``[F, out_cols]`` int8, ``[C, out_cols]`` float32): kept columns
@@ -276,7 +280,7 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
             jax.ShapeDtypeStruct((C_pad, out_cols), jnp.float32),
         ],
         # the device op's name, pinned: profile readers match it
-        name="compact_rows",
+        name=name,
     )(aligned, fill, dest.reshape(1, n), bins_t, vals_t)
     # Pallas outputs are uninitialized and a window's groups past the
     # ones its block filled hold whatever an earlier step left: zero

@@ -51,6 +51,9 @@ import jax
 import jax.numpy as jnp
 
 i32 = jnp.int32
+# the device op's name of the mover's two compaction passes, pinned:
+# profile readers match it (GOSS's compaction keeps "compact_rows")
+MOVE_OP = "partition_move"
 
 
 def plan_split_move(moved: jax.Array
@@ -153,11 +156,11 @@ def move_cols_tpu(bins_fm: jax.Array, vals_fm: jax.Array,
     fb, fv = compact_rows(
         bins_fm, vals_fm,
         *plan_compaction(keep_front, rows_per_block, out_cols),
-        out_cols=out_cols, rows_per_block=rows_per_block)
+        out_cols=out_cols, rows_per_block=rows_per_block, name=MOVE_OP)
     bb, bv = compact_rows(
         bins_fm, vals_fm,
         *plan_compaction(moved, rows_per_block, out_cols),
-        out_cols=out_cols, rows_per_block=rows_per_block)
+        out_cols=out_cols, rows_per_block=rows_per_block, name=MOVE_OP)
     sel = (jnp.arange(n, dtype=i32) < n_front)[None, :]
     bb_r = jnp.roll(bb[:, :n], n_front, axis=1)
     bv_r = jnp.roll(bv[:, :n], n_front, axis=1)

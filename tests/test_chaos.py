@@ -140,10 +140,14 @@ def test_slow_fault_delays_without_changing_the_model():
     # compiled (a cold first run of 1.4 s once read slower than the
     # delayed second, under six busy workers)
     lgb.train(dict(PARAMS), lgb.Dataset(X, label=y), num_boost_round=4)
-    t0 = time.monotonic()
-    clean = lgb.train(dict(PARAMS), lgb.Dataset(X, label=y),
-                      num_boost_round=4)
-    t_clean = time.monotonic() - t0
+    # the lesser of two clean runs: under six busy workers one clean run
+    # read 0.13 s over the other and hid a fifth of the delay (PR 36)
+    t_clean = float("inf")
+    for _ in range(2):
+        t0 = time.monotonic()
+        clean = lgb.train(dict(PARAMS), lgb.Dataset(X, label=y),
+                          num_boost_round=4)
+        t_clean = min(t_clean, time.monotonic() - t0)
     t0 = time.monotonic()
     slowed = lgb.train(dict(PARAMS, tpu_fault_inject="slow:iter=1,ms=200"),
                        lgb.Dataset(X, label=y), num_boost_round=4)

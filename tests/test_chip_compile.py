@@ -170,15 +170,23 @@ def test_route_rows_compiles(one_chip, F, W, n):
 
 
 @pytest.mark.parametrize("kernel", ["multi_leaf_histogram",
-                                    "compact_rows"])
+                                    "compact_rows", "partition_move"])
 def test_pallas_call_lowers_with_its_pinned_name(one_chip, kernel):
     """The benchmark's kernel metrics match the device op by name
-    (``^multi_leaf_histogram(\\.\\d+)?$``, ``^compact_rows(...)``). The
-    name is the ``pallas_call``'s own ``name=``: called from a function
-    of another name, the kernel still lowers and compiles under it."""
-    from lightgbm_tpu.ops import compact, pallas_histogram
+    (``^multi_leaf_histogram(\\.\\d+)?$``, ``^compact_rows(...)``,
+    ``^partition_move(...)``). The name is the ``pallas_call``'s own
+    ``name=``: called from a function of another name, the kernel still
+    lowers and compiles under it. The leaf-ordered partition's mover is
+    the compaction kernel under a name of its own, so that a trace tells
+    its two passes from GOSS's compaction."""
+    from lightgbm_tpu.ops import compact, pallas_histogram, partition
     s = functools.partial(_sds, one_chip)
-    if kernel == "multi_leaf_histogram":
+    if kernel == "partition_move":
+        def renamed(*a):
+            return partition.move_cols_tpu(*a, rows_per_block=1024)
+        args = (s((13, SLAB), jnp.int8), s((4, SLAB), jnp.float32),
+                s((SLAB,), jnp.bool_), s((), jnp.int32))
+    elif kernel == "multi_leaf_histogram":
         def renamed(*a):
             return pallas_histogram.multi_leaf_histogram.__wrapped__(
                 *a, num_bins=256, rows_per_block=4096, int_mode=True)
@@ -196,7 +204,10 @@ def test_pallas_call_lowers_with_its_pinned_name(one_chip, kernel):
                 s((SLAB // 1024,), jnp.int32))
     lowered = jax.jit(renamed).lower(*args)
     assert f'kernel_name = "{kernel}"' in lowered.as_text()
-    assert f"%{kernel}." in _compiled_text(lowered)
+    text = _compiled_text(lowered)
+    assert f"%{kernel}." in text
+    if kernel == "partition_move":
+        assert "%compact_rows." not in text
 
 
 # ---------------------------------------------------------------------
@@ -262,10 +273,12 @@ def test_grow_tree_compiles(one_chip, as_tpu, variant):
     # float32 attribute matrix, [rows, 6] (thresholds) or [rows, 6 + 1 +
     # 2 x 8] (bitsets). Handed a compact buffer, the loop builds the
     # buffer's alone, and the table is routed once, after it, by the
-    # route_rows kernel under the same scope
+    # route_rows kernel under the same scope; under the leaf-ordered
+    # partition (since PR 36) it builds the partition's own alone, as
+    # long as the table's, and routes the table after it too
     attr = f"f32[{{}},{23 if cfg.has_categorical else 6}]"
     assert (attr.format(N) in text) == (not cfg.hist_compact)
-    assert ("%route_rows." in text) == cfg.hist_compact
+    assert ("%route_rows." in text) == (cfg.hist_compact or cfg.partition)
     if cfg.hist_compact:
         assert attr.format(n_c) in text
         route_line = next(ln for ln in text.splitlines()
@@ -306,6 +319,60 @@ def test_goss_compact_chunk_program_compiles(one_chip, as_tpu):
     for kernel in ("compact_rows", "multi_leaf_histogram", "route_rows"):
         assert f"%{kernel}." in text, kernel
     assert not re.search(rf"f32\[{n_pad},(6|23)\]", text)
+
+
+def test_plain_chunk_program_compiles_at_the_click_rate_tables_shape(
+        one_chip, as_tpu):
+    """The program `criteo-tb-1700m.train-plain` runs in its window: a
+    fused chunk of UNSAMPLED iterations at 255 leaves over 67 all-full
+    columns under the leaf-ordered partition, at the row count the
+    configuration ships (the engine is built on a small table of the same
+    columns and asked for the partition by name; its programs take their
+    row counts from the shapes handed). The chip's compiler has to fit it
+    and leave 15% of the 15.75 GiB a v5e's programs may use: the
+    configuration's rows were cut by that rule (PERF.md section 4). The
+    mover's two passes are there under their own name, the table is
+    routed once after the loop (`route_rows`), and GOSS's compaction is
+    not there."""
+    import json
+    import os
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.boosting.gbdt import GBDT
+    from lightgbm_tpu.config import Config
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "criteo-tb-1700m.json")) as f:
+        config = json.load(f)
+    rng = np.random.default_rng(0)
+    n, F = 1 << 17, 67
+    X = rng.integers(0, 255, (n, F)).astype(np.float32)
+    params = dict(config["params"], verbosity=-1, use_quantized_grad=True,
+                  tpu_hist_partition="true")
+    eng = GBDT(Config(params),
+               lgb.Dataset(X, label=(X[:, 0] > 100).astype(np.float32)))
+    assert eng.use_pallas and eng.hist_partition and eng.grow_cfg.int_hist
+    assert not eng._use_goss_compact and eng.grow_cfg.num_leaves == 255
+    chunk = eng._make_chunk(False)
+    program = next(c.cell_contents for c in chunk.__closure__
+                   if hasattr(c.cell_contents, "lower"))
+    s = functools.partial(_sds, one_chip)
+    rpb = eng.rows_per_block
+    n_pad = -(-int(config["rows"]) // rpb) * rpb
+    compiled = program.lower(
+        s((n_pad, F), eng.data.bins.dtype), s((F, n_pad), jnp.int8),
+        s((n_pad,), jnp.float32), None, s((n_pad, 1), jnp.float32),
+        s((n_pad,), jnp.float32),
+        s((int(params["tpu_fuse_iters"]), 2), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    held = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < 0.85 * 15.75 * 2**30, mem
+    text = compiled.as_text()
+    for kernel in ("partition_move", "multi_leaf_histogram", "route_rows"):
+        assert f"%{kernel}." in text, kernel
+    assert "%compact_rows." not in text
+    # the one float32 attribute matrix left is the partition's own pass
+    assert f"f32[{n_pad},6]" in text
 
 
 def test_grow_tree_compiles_under_shard_map_on_four_chips(topo, as_tpu):

@@ -324,7 +324,8 @@ def test_metrics_off_by_default_records_nothing():
     names = {m.name for m in obs.registry().metrics()}
     assert names and all(n.startswith(("hist.", "goss.", "ingest.",
                                        "split.", "tree.", "bundle.",
-                                       "partition.", "compact."))
+                                       "partition.", "compact.",
+                                       "valid.", "eval."))
                          for n in names), names
     assert not obs.enabled()
 

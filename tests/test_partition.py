@@ -174,12 +174,19 @@ def _grow_pair(cfg_kw, n=2048, f=6, seed=0):
 def test_grow_tree_partitioned_bit_identical(cfg_kw):
     (t0, lid0), (t1, lid1) = _grow_pair(cfg_kw)
     for k in t0:
-        if k == "hist_rows":
+        if k in ("hist_rows", "route_rows", "route_final"):
             continue
         np.testing.assert_array_equal(t0[k], t1[k], err_msg=k)
     np.testing.assert_array_equal(lid0, lid1)
     # the structural win: the partitioned tree scanned fewer rows
     assert int(t1["hist_rows"]) <= int(t0["hist_rows"])
+    # and its histograms read the partition's own ids, so the table is
+    # routed once, after the loop, where the masked path routes it at
+    # every trip (the ids above are equal all the same)
+    trips = int(t0["hist_calls"]) - 1
+    assert int(t1["route_final"]) == 1 and int(t0["route_final"]) == 0
+    assert float(t1["route_rows"]) == len(lid1)
+    assert float(t0["route_rows"]) == trips * len(lid0)
 
 
 # ---------------------------------------------------------------------------
