@@ -1807,7 +1807,7 @@ class GBDT:
             if self.has_categorical:
                 tree_keys += ["is_cat", "cat_bitset"]
             if self.hist_partition:
-                tree_keys += ["move_calls", "move_rows"]
+                tree_keys += ["move_calls", "move_rows", "full_scans"]
             tree_specs = {k: rep for k in tree_keys}
             # 4th output = cegb_U (always None under mesh — lazy CEGB
             # requires the serial learner; the spec matches structure
@@ -2263,7 +2263,7 @@ class GBDT:
         # two, only a tree grown under the leaf-ordered partition the rest
         compact = {k: float(np.sum(host.pop(k, 0.0), dtype=np.float64))
                    for k in ("compact_onehot_rows", "compact_blocks",
-                             "move_rows", "move_calls")}
+                             "move_rows", "move_calls", "full_scans")}
         cols = total["hist_rows"]
         label = int(bool(sampled))
         n_trees = host["num_leaves"].size
@@ -2308,6 +2308,9 @@ class GBDT:
             obs.inc("partition.rows_moved", compact["move_rows"],
                     force=True, sampled=label)
             obs.inc("partition.move_calls", compact["move_calls"],
+                    force=True, sampled=label)
+            # the loop trips whose children did not fit the block table
+            obs.inc("partition.full_scans", compact["full_scans"],
                     force=True, sampled=label)
         if sampled:
             n_iters = n_trees // self.num_class

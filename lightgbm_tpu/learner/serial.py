@@ -139,10 +139,10 @@ class GrowConfig:
     # leaf-ordered device row partition (ops/partition.py;
     # tpu_hist_partition): rows ride the carry physically grouped by
     # leaf (per-leaf offset/count tables + a stable cumsum front/back
-    # move per round), and each round's histogram scans only the
-    # elected children's padded spans — a lax.switch over a static pow2
-    # budget ladder, falling back to the masked full scan whenever the
-    # spans would not shrink it. Siblings still come from pool
+    # move per round), and each round's histogram reads, in place, only
+    # the row blocks that hold the elected children's rows — a block
+    # table the kernel walks, falling back to the masked full scan when
+    # the blocks would not fit it. Siblings still come from pool
     # subtraction.
     partition: bool = False
     # block size of the TPU compact_rows-based repartition move
@@ -304,6 +304,9 @@ class GrowState(NamedTuple):
     # (id not -1): the hist.calls / hist.leaf_slots_filled counters
     hist_calls: jnp.ndarray
     hist_slots_filled: jnp.ndarray
+    # under the leaf-ordered partition: the loop trips that took the
+    # masked full scan (the partition.full_scans counter)
+    full_scans: jnp.ndarray
 
 
 def _masked_gains(gain, leaf_depth, num_leaves, max_depth):
@@ -430,32 +433,37 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         # e.g. MSLR widths) overflows the 16MB scoped-vmem budget at
         # 4096 — those shapes cap at 2048.
         import math
-        r_cap = 4096 if onehot_layout(col_bins, B).n_fb == 1 else 2048
+        h_lay = onehot_layout(col_bins, B)
+        r_cap = 4096 if h_lay.n_fb == 1 else 2048
+        # the kernel's feature windows cover F_pad columns: a source
+        # that has them already is read as it is, and one that has not
+        # is copied whole, zero columns added, at every call
+        hist_f_pad = h_lay.n_fb * h_lay.f_blk
         pr = math.gcd(cfg.rows_per_block, r_cap)
         base_rpb = pr
 
         @obs.scope("grower/histogram")
-        def hist_kernel(b_src, v_src, l_src, ids, rpb):
+        def hist_kernel(b_src, v_src, l_src, ids, rpb, blocks=None):
             """Raw local multi-leaf histogram over an arbitrary source
-            (the whole data, the GOSS buffer, or partition spans) —
-            cross-device reduction stays with the caller so the span
-            lax.switch never encloses a collective."""
+            (the whole data, the GOSS buffer, or the partition's listed
+            blocks) — cross-device reduction stays with the caller so
+            the partition's lax.cond never encloses a collective."""
             return multi_leaf_histogram(
                 b_src, v_src, l_src, ids, num_bins=B, col_bins=col_bins,
-                rows_per_block=rpb, int_mode=cfg.int_hist)
+                rows_per_block=rpb, int_mode=cfg.int_hist, blocks=blocks)
 
         def hist_multi(leaf_id, small_ids):
             return hist_reduce(hist_kernel(
                 h_bins_t, h_vals_t, leaf_id, small_ids, pr))
     else:
-        import math
         base_rpb = cfg.rows_per_block
 
         @obs.scope("grower/histogram")
-        def hist_kernel(b_src, v_src, l_src, ids, rpb):
+        def hist_kernel(b_src, v_src, l_src, ids, rpb, blocks=None):
             return multi_leaf_histogram_xla(
                 b_src, v_src, l_src, ids, num_bins=B,
-                rows_per_block=rpb, precise=cfg.precise_histogram)
+                rows_per_block=rpb, precise=cfg.precise_histogram,
+                blocks=blocks)
 
         def hist_multi(leaf_id, small_ids):
             return hist_reduce(hist_kernel(
@@ -465,52 +473,37 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
     # ---- leaf-ordered row partition (cfg.partition) -------------------
     # ops/partition.py: rows (of the histogram SOURCE — the GOSS buffer
     # under hist_compact, else all rows) ride the carry grouped by leaf;
-    # each round's histogram scans only the elected children's padded
-    # spans via a static pow2 budget ladder, falling back to the masked
-    # full scan when the spans would not shrink it.
+    # each round's histogram reads, in place, only the row blocks that
+    # hold an elected child's rows (a block table of static length the
+    # kernel walks), falling back to the masked full scan when the
+    # blocks would not fit the table.
     use_part = cfg.partition
     part_fm = cfg.use_pallas            # feature-major partition layout
     n_h = h_bins.shape[0]               # histogram-source row count
     if use_part:
         from ..ops import partition as part_ops
-        part_budgets = part_ops.span_budgets(n_h, Kb)
-        # float32: the counter reaches n x rounds (x shards after the
-        # psum) — int32 wraps at the very scales the metric watches
-        _span_rows = jnp.asarray(
-            tuple(Kb * s for s in part_budgets) + (n_h,),
-            jnp.float32)
+        n_blocks = n_h // base_rpb
+        nb_max = part_ops.span_block_cap(n_h, base_rpb, Kb)
 
         @obs.scope("grower/histogram")
         def span_hist(pb, pv, pl, ids, offs, cnts):
-            """[M, F_h, B, 3] local histograms of the elected children
-            + the rows this round's scan touched."""
-            branches = []
-            for S in part_budgets:
-                def mk(S):
-                    rpb_b = math.gcd(S, base_rpb)
-
-                    def br(pb, pv, pl, ids, offs, cnts):
-                        bcat, vcat, lcat = part_ops.slice_spans(
-                            pb, pv, pl, offs, cnts, S, part_fm)
-                        return hist_kernel(bcat, vcat, lcat, ids, rpb_b)
-                    return br
-                branches.append(mk(S))
-
-            def full_br(pb, pv, pl, ids, offs, cnts):
-                # masked full scan over the partition (pl is a valid
-                # per-position leaf vector) — the degenerate-budget
-                # fallback, never worse than the masked path
-                return hist_kernel(pb, pv, pl, ids, base_rpb)
-            branches.append(full_br)
-            need = jnp.max(jnp.where(ids >= 0, cnts, 0))
-            if not part_budgets:
-                return full_br(pb, pv, pl, ids, offs, cnts), \
-                    jnp.asarray(n_h, jnp.float32)
-            idx = jnp.sum((jnp.asarray(part_budgets, i32) < need)
-                          .astype(i32))
-            hist = jax.lax.switch(idx, branches, pb, pv, pl, ids,
-                                  offs, cnts)
-            return hist, _span_rows[idx]
+            """[M, F_h, B, 3] local histograms of the elected children,
+            the blocks this round's call read, and whether it took the
+            full scan."""
+            blocks = part_ops.span_blocks(offs, cnts, base_rpb, n_blocks,
+                                          nb_max)
+            if nb_max == n_blocks:
+                # the table holds every block: it never overflows
+                return (hist_kernel(pb, pv, pl, ids, base_rpb, blocks),
+                        blocks[1], jnp.asarray(False))
+            full = blocks[1] > nb_max
+            # pl is a valid per-position leaf vector: the masked full
+            # scan over the partition is right whatever the counts say
+            hist = jax.lax.cond(
+                full,
+                lambda: hist_kernel(pb, pv, pl, ids, base_rpb),
+                lambda: hist_kernel(pb, pv, pl, ids, base_rpb, blocks))
+            return hist, jnp.where(full, n_blocks, blocks[1]), full
 
     W = cfg.cat_words
     if not cfg.has_categorical:
@@ -764,7 +757,12 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
     if use_part:
         # initial layout: every histogram-source row belongs to the
         # root, one contiguous span covering the whole buffer
-        part_bins0 = h_bins_t if part_fm else h_bins
+        # feature-major, the partition carries the kernel's F_pad
+        # columns (the zero ones added once a tree), so neither its
+        # histogram calls nor the mover copy it to pad it
+        part_bins0 = (jnp.pad(h_bins_t,
+                              ((0, hist_f_pad - h_bins_t.shape[0]), (0, 0)))
+                      if part_fm else h_bins)
         part_vals0 = h_vals_t if part_fm else h_vals
         part_leaf0 = jnp.zeros(n_h, dtype=i32)
         part_off0 = jnp.zeros(L + 1, dtype=i32)
@@ -881,6 +879,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
             rows_scanned=jnp.asarray(n_h, jnp.float32),
             hist_calls=jnp.array(1, i32),
             hist_slots_filled=jnp.array(1, i32),
+            full_scans=jnp.array(0, i32),
         )
 
     node_trash = L - 1  # real nodes occupy 0..L-2
@@ -1079,9 +1078,10 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                 col_ids = col_ids + off
             if fm:
                 # int8 wraparound storage -> restore uint8 bin values
+                # (the partition's zero columns past F are not read)
                 oh_f = pcol_r[None, :] == col_ids[:, None]     # [F, n]
                 col = jnp.sum(
-                    jnp.where(oh_f, bins_mat.astype(i32) & 0xFF, 0),
+                    jnp.where(oh_f, bins_mat[:F].astype(i32) & 0xFF, 0),
                     axis=0)
             else:
                 oh_f = pcol_r[:, None] == col_ids[None, :]
@@ -1158,8 +1158,8 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                 p_off, p_cnt = s.part_off, s.part_cnt
 
         def span_tables(ids):
-            """Per-elected-child (offset, count) rows for slice_spans
-            (-1 lanes get count 0, so they match nothing)."""
+            """Per-elected-child (offset, count) rows for span_blocks
+            (-1 lanes get count 0, so they list no block)."""
             safe = jnp.clip(ids, 0, L)
             return p_off[safe], jnp.where(ids >= 0, p_cnt[safe], 0)
 
@@ -1172,14 +1172,20 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
             valid, jnp.where(left_smaller, top_leaf, new_ids),
             -1).astype(i32)
         if use_part:
-            # partitioned: scan only the Kb smaller children's spans
+            # partitioned: read only the blocks of the Kb smaller
+            # children's spans
             offs_k, cnts_k = span_tables(small_ids)
-            raw_s, span_rows = span_hist(p_bins, p_vals, p_leaf,
-                                         small_ids, offs_k, cnts_k)
+            raw_s, blocks_read, full = span_hist(
+                p_bins, p_vals, p_leaf, small_ids, offs_k, cnts_k)
             hist_small = hist_reduce(raw_s)      # [Kb, F, B, 3]
+            # float32: the counter reaches n x rounds (x shards after
+            # the psum) — int32 wraps at the very scales it watches
+            span_rows = blocks_read.astype(jnp.float32) * float(base_rpb)
+            full_scans = s.full_scans + full.astype(i32)
         else:
             hist_small = hist_multi(hist_lid, small_ids)
             span_rows = jnp.asarray(n_h, jnp.float32)
+            full_scans = s.full_scans
         with obs.scope("grower/histogram"):
             # TPU note: the [L+1, F, B, 3] pool gather/scatter by leaf id
             # lowers to serialized dynamic slices (~13 ms/round at
@@ -1526,6 +1532,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                 hist_calls=s.hist_calls + 1,
                 hist_slots_filled=s.hist_slots_filled
                 + jnp.sum(small_ids >= 0).astype(i32),
+                full_scans=full_scans,
             )
         next_gains = _masked_gains(new.best_gain, new.leaf_depth,
                                    new.num_leaves, cfg.max_depth)
@@ -1613,6 +1620,11 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
             moves = jax.lax.psum(moves, cfg.axis_name)
         tree["move_calls"] = moves
         tree["move_rows"] = moves * float(n_h)
+        # the loop trips whose children did not fit the block table
+        full_scans = final.full_scans.astype(jnp.float32)
+        if cfg.axis_name:
+            full_scans = jax.lax.psum(full_scans, cfg.axis_name)
+        tree["full_scans"] = full_scans
     if cfg.has_categorical:
         # only emitted when categorical features exist, so downstream
         # traversal (tree_predict_binned) skips the bitset branch — and

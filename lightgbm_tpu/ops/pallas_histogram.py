@@ -40,7 +40,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _hist_kernel(bins_ref, vals_ref, leaf_ref, small_ref, out_ref, *,
                  rows: Tuple[int, ...], n_chan: int, chunk: int,
-                 int_mode: bool = False):
+                 int_mode: bool = False, nb_ref=None):
     i = pl.program_id(1)      # row-block index (feature block is dim 0)
     small = small_ref[...]                               # [K, 1]
     # int_mode (use_quantized_grad): grad/hess are small integer levels,
@@ -92,7 +92,22 @@ def _hist_kernel(bins_ref, vals_ref, leaf_ref, small_ref, out_ref, *,
                                     else jnp.float32))  # [sum(rows), C*K]
         return carry
 
-    jax.lax.fori_loop(0, bins_ref.shape[1] // chunk, scan, 0)
+    def accumulate():
+        jax.lax.fori_loop(0, bins_ref.shape[1] // chunk, scan, 0)
+
+    if nb_ref is None:
+        accumulate()
+    else:
+        # a block table's padded steps hold the block already fetched:
+        # they start no DMA and add nothing
+        pl.when(i < nb_ref[0])(accumulate)
+
+
+def _table_kernel(blk_ref, nb_ref, *refs, **kw):
+    """The kernel under a scalar-prefetched block table: ``blk_ref``
+    only steers the index maps, ``nb_ref`` holds the live steps."""
+    del blk_ref
+    _hist_kernel(*refs, nb_ref=nb_ref, **kw)
 
 
 # one-hot rows of a column: its bin count rounded up to the int8
@@ -180,13 +195,17 @@ def multi_leaf_histogram(bins_t: jax.Array, vals_t: jax.Array,
                          num_bins: int,
                          col_bins: Optional[Tuple[int, ...]] = None,
                          rows_per_block: int = 2048,
-                         int_mode: bool = False) -> jax.Array:
+                         int_mode: bool = False,
+                         blocks: Optional[Tuple[jax.Array, jax.Array]]
+                         = None) -> jax.Array:
     """Histograms of K leaves in one fused scan (TPU Pallas path).
 
     Args:
       bins_t: ``[F, n]`` int8 FEATURE-MAJOR binned matrix (transposed once
         at setup so row blocks are lane-contiguous; uint8 values stored
-        with int8 wraparound).
+        with int8 wraparound). It may carry the layout's zero columns up
+        to ``F_pad`` already; else they are added here, a copy of the
+        whole matrix.
       vals_t: ``[C, n]`` float32 channel-major per-row values
         (grad*m, hess*m, count-mask) — bagging masks pre-applied.
       leaf_id: ``[n]`` int32 current leaf of each row.
@@ -196,20 +215,23 @@ def multi_leaf_histogram(bins_t: jax.Array, vals_t: jax.Array,
       col_bins: static bin count of each of the F columns (a value of
         column f is below ``col_bins[f]``); the one-hot holds rows for
         those bins only. None: every column has ``num_bins``.
+      blocks: None scans every row block. Else ``(blk [NB] int32,
+        nb int32)``, a table of ``rows_per_block``-row blocks
+        (ops/partition.py::span_blocks): the call reads blocks
+        ``blk[:nb]`` only, in place, and each once; ``blk``'s tail
+        repeats ``blk[nb - 1]``.
 
     Returns:
       ``[K, F, B, C]`` float32.
     """
-    F, n = bins_t.shape
+    F_in, n = bins_t.shape
     C = vals_t.shape[0]
     K = small_ids.shape[0]
     R = rows_per_block
     assert n % R == 0, f"n={n} must be a multiple of rows_per_block={R}"
     if col_bins is None:
-        col_bins = (num_bins,) * F
-    if len(col_bins) != F:
-        raise ValueError(
-            f"col_bins names {len(col_bins)} columns, bins_t has {F}")
+        col_bins = (num_bins,) * F_in
+    F = len(col_bins)
 
     # feature blocking keeps the [rows, K*C] VMEM accumulator (and the
     # transient one-hot) bounded for wide datasets (MSLR F=136+); up to
@@ -218,9 +240,12 @@ def multi_leaf_histogram(bins_t: jax.Array, vals_t: jax.Array,
     # a half-size row block.
     lay = onehot_layout(tuple(col_bins), num_bins)
     F_pad = lay.n_fb * lay.f_blk
-    if F_pad > F:
+    if F_in not in (F, F_pad):
+        raise ValueError(
+            f"col_bins names {F} columns, bins_t has {F_in}")
+    if F_pad > F_in:
         bins_t = jnp.concatenate(
-            [bins_t, jnp.zeros((F_pad - F, n), bins_t.dtype)])
+            [bins_t, jnp.zeros((F_pad - F_in, n), bins_t.dtype)])
 
     kernel = functools.partial(
         _hist_kernel, rows=lay.rows, n_chan=C, int_mode=int_mode,
@@ -232,32 +257,52 @@ def multi_leaf_histogram(bins_t: jax.Array, vals_t: jax.Array,
     # operand shares its shape/dtype, and threading a caller-supplied
     # zeroed buffer just to alias it would ADD an HBM zero-fill per
     # call — strictly worse than the status quo.
-    out = pl.pallas_call(
-        kernel,
-        grid=(lay.n_fb, n // R),
+    if blocks is None:
+        steps, prefetch, body = n // R, (), kernel
+
+        def row(i):
+            return i
+    else:
+        blk, nb = blocks
+        steps = blk.shape[0]
+        prefetch = (blk.astype(jnp.int32),
+                    jnp.reshape(nb, (1,)).astype(jnp.int32))
+        body = functools.partial(_table_kernel, **kernel.keywords)
+
+        def row(i, b, _nb):
+            return b[i]      # the row axis walks the table
+    specs = dict(
+        grid=(lay.n_fb, steps),
         in_specs=[
-            pl.BlockSpec((lay.f_blk, R), lambda j, i: (j, i),
+            pl.BlockSpec((lay.f_blk, R), lambda j, i, *t: (j, row(i, *t)),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((C, R), lambda j, i: (0, i),
+            pl.BlockSpec((C, R), lambda j, i, *t: (0, row(i, *t)),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, R), lambda j, i: (0, i),
+            pl.BlockSpec((1, R), lambda j, i, *t: (0, row(i, *t)),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((K, 1), lambda j, i: (0, 0),
+            pl.BlockSpec((K, 1), lambda j, i, *t: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((lay.block_rows, C * K),
-                               lambda j, i: (j, 0),
-                               memory_space=pltpu.VMEM),
+                               lambda j, i, *t: (j, 0),
+                               memory_space=pltpu.VMEM))
+    if prefetch:
+        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), **specs))
+    out = pl.pallas_call(
+        body,
         out_shape=jax.ShapeDtypeStruct((lay.onehot_rows, C * K),
                                        jnp.int32 if int_mode
                                        else jnp.float32),
         cost_estimate=pl.CostEstimate(
-            flops=2 * lay.onehot_rows * n * K * C,
-            bytes_accessed=bins_t.size + vals_t.size * 4 + leaf_id.size * 4,
+            flops=2 * lay.onehot_rows * steps * R * K * C,
+            bytes_accessed=steps * R * (F_pad + C * 4 + 4),
             transcendentals=0),
         # the device op's name, pinned: profile readers match it
         name="multi_leaf_histogram",
-    )(bins_t, vals_t, leaf_id.reshape(1, n), small_ids.reshape(K, 1))
+        **specs,
+    )(*prefetch, bins_t, vals_t, leaf_id.reshape(1, n),
+      small_ids.reshape(K, 1))
     if int_mode:
         out = out.astype(jnp.float32)
     return dense_histograms(out, lay, F, num_bins, K, C)
@@ -267,12 +312,27 @@ def multi_leaf_histogram_xla(bins: jax.Array, vals: jax.Array,
                              leaf_id: jax.Array, small_ids: jax.Array, *,
                              num_bins: int,
                              rows_per_block: int = 1024,
-                             precise: bool = False) -> jax.Array:
+                             precise: bool = False,
+                             blocks: Optional[Tuple[jax.Array, jax.Array]]
+                             = None) -> jax.Array:
     """XLA fallback (CPU tests / non-TPU backends): same contract via the
     einsum-based build_histogram with leaf masks packed into channels.
     ``precise`` keeps grad/hess in float32 (tpu_double_precision_hist)
-    instead of the default bfloat16 operands."""
+    instead of the default bfloat16 operands. ``blocks`` as the Pallas
+    kernel's: the table's ``rows_per_block``-row blocks are gathered
+    from the row-major source, and the rows of its padded steps get
+    values 0 (leaf id -1 would match an empty lane's -1)."""
     from .histogram import build_histogram
+    if blocks is not None:
+        blk, nb = blocks
+        R = rows_per_block
+        NB = blk.shape[0]
+        F, C = bins.shape[1], vals.shape[1]
+        live = (jnp.arange(NB) < nb)[:, None, None]
+        bins = bins.reshape(-1, R, F)[blk].reshape(NB * R, F)
+        vals = jnp.where(live, vals.reshape(-1, R, C)[blk], 0
+                         ).reshape(NB * R, C)
+        leaf_id = leaf_id.reshape(-1, R)[blk].reshape(-1)
     K = small_ids.shape[0]
     n, _F = bins.shape
     C = vals.shape[1]

@@ -27,16 +27,20 @@ Design (all fixed-shape, jit/while_loop/shard_map-safe):
   (ops/compact.py): two compaction passes (front keys, back keys), the
   back buffer rolled to its start position, one ``where`` blend. Off
   TPU a computed-index scatter is cheap and exact.
-- Each growth round then histograms only the K smaller children's
-  spans: a ``lax.switch`` over a static pow2 ladder of span budgets
-  keeps every shape static and the compile footprint bounded (the same
-  trick as predict's batch-shape bucketing); rounds whose largest
-  elected child would make ``K * budget >= n`` take a full masked-scan
-  fallback branch instead (the span path can never scan MORE rows than
-  the masked formulation). Rows sliced from a neighbouring leaf inside
-  a span are sentinel-masked, so each row contributes exactly once.
+- Each growth round then histograms only the row blocks that hold a
+  row of one of the K smaller children: :func:`span_blocks` lists them
+  (sorted, each once) in a table of static length, and the histogram
+  kernel walks that table over the partition's own buffers in place
+  (``multi_leaf_histogram(..., blocks=)``: the table is scalar-
+  prefetched and picks each grid step's block). A listed block's rows
+  of a leaf not elected this round match no lane, as in the masked
+  scan, so each row of an elected child counts once. The smaller
+  children hold at most half the rows, so the table's length
+  (:func:`span_block_cap`) is about n / 2R blocks; a round whose count
+  channel is not a row count (bagging, weights) can list more, and
+  then takes the masked full scan over the partition.
 
-Bit-exactness: the span histogram sums exactly the same per-row terms
+Bit-exactness: the block histogram sums exactly the same per-row terms
 as the masked scan, in a different accumulation order — EXACT under
 quantized gradients (integer sums are order-free; the flagship config),
 float-accumulation-order-close otherwise, mirroring the GOSS
@@ -168,56 +172,45 @@ def move_cols_tpu(bins_fm: jax.Array, vals_fm: jax.Array,
             jnp.where(sel, fv[:, :n], bv_r))
 
 
-def span_budgets(n_rows: int, n_spans: int, min_budget: int = 256
-                 ) -> Tuple[int, ...]:
-    """Static pow2 span-budget ladder for the ``lax.switch``: budgets S
-    with ``n_spans * S < n_rows`` (a span round never scans more rows
-    than the masked full scan it replaces — the caller's final branch).
-    The ladder is O(log n) entries, so the compile footprint stays
-    bounded exactly like predict's pow2 batch buckets."""
-    budgets = []
-    s = min_budget
-    while s < n_rows and n_spans * s < n_rows:
-        budgets.append(s)
-        s *= 2
-    return tuple(budgets)
 
 
-def slice_spans(bins_p: jax.Array, vals_p: jax.Array, leaf_p: jax.Array,
-                offs: jax.Array, cnts: jax.Array, budget: int,
-                feature_major: bool
-                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Assemble the K children's padded row spans into one histogram
-    input: K static-width ``dynamic_slice``s (starts clamped into
-    range), concatenated along the row axis. Positions inside a span
-    that belong to a NEIGHBOURING leaf (the pow2 padding) get leaf id
-    -1, so they match no histogram lane — each row of each elected
-    child contributes exactly once, and only to its own lane.
+def span_block_cap(n_rows: int, rows_per_block: int, n_spans: int) -> int:
+    """``NB_max``, the static length of the block table: the smaller
+    children of one split batch hold at most half the rows, and each of
+    the ``n_spans`` children adds at most two partial blocks. Never more
+    than the blocks the source has."""
+    R = rows_per_block
+    return min(n_rows // R, -(-n_rows // (2 * R)) + 2 * n_spans)
+
+
+def span_blocks(offs: jax.Array, cnts: jax.Array, rows_per_block: int,
+                n_blocks: int, nb_max: int) -> Tuple[jax.Array, jax.Array]:
+    """The row blocks that hold a row of an elected child.
+
+    Args:
+      offs / cnts: ``[K]`` the children's spans (count 0 for a lane
+        that holds no leaf).
+      rows_per_block: R, the histogram kernel's row block.
+      n_blocks: blocks of the source (its rows over R).
+      nb_max: the table's static length (:func:`span_block_cap`).
+
+    Returns:
+      (blk ``[nb_max]`` int32 — the sorted distinct blocks, the tail
+      past ``nb`` repeating the last of them so that a padded grid step
+      asks for the block already fetched; nb int32 — how many there
+      are, which may pass ``nb_max``: the table is then cut short and
+      the caller scans the whole source instead).
     """
-    n = leaf_p.shape[0]
-    K = int(offs.shape[0])
-    S = int(budget)
-    starts = jnp.clip(offs, 0, n - S)
-    rel = jnp.arange(S, dtype=i32)
-    bs, vs, ls = [], [], []
-    for k in range(K):
-        st = starts[k]
-        if feature_major:
-            bk = jax.lax.dynamic_slice(
-                bins_p, (i32(0), st), (bins_p.shape[0], S))
-            vk = jax.lax.dynamic_slice(
-                vals_p, (i32(0), st), (vals_p.shape[0], S))
-        else:
-            bk = jax.lax.dynamic_slice(
-                bins_p, (st, i32(0)), (S, bins_p.shape[1]))
-            vk = jax.lax.dynamic_slice(
-                vals_p, (st, i32(0)), (S, vals_p.shape[1]))
-        lk = jax.lax.dynamic_slice(leaf_p, (st,), (S,))
-        keep = (rel >= offs[k] - st) & (rel < offs[k] - st + cnts[k])
-        ls.append(jnp.where(keep, lk, -1))
-        bs.append(bk)
-        vs.append(vk)
-    axis = 1 if feature_major else 0
-    return (jnp.concatenate(bs, axis=axis),
-            jnp.concatenate(vs, axis=axis),
-            jnp.concatenate(ls))
+    R = rows_per_block
+    live = cnts > 0
+    # a difference array over the blocks: +1 at a span's first block,
+    # -1 after its last; a dead lane adds both to the dropped slot
+    first = jnp.where(live, offs // R, n_blocks)
+    after = jnp.where(live, (offs + cnts - 1) // R + 1, n_blocks)
+    diff = (jnp.zeros(n_blocks + 1, i32).at[first].add(1)
+            .at[after].add(-1))
+    cover = jnp.cumsum(diff[:n_blocks]) > 0
+    nb = jnp.sum(cover, dtype=i32)
+    blk = jnp.nonzero(cover, size=nb_max, fill_value=0)[0].astype(i32)
+    tail = blk[jnp.clip(nb - 1, 0, nb_max - 1)]
+    return jnp.where(jnp.arange(nb_max) < nb, blk, tail), nb

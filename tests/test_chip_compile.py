@@ -169,16 +169,46 @@ def test_route_rows_compiles(one_chip, F, W, n):
     assert "%route_rows." in text
 
 
-@pytest.mark.parametrize("kernel", ["multi_leaf_histogram",
-                                    "compact_rows", "partition_move"])
-def test_pallas_call_lowers_with_its_pinned_name(one_chip, kernel):
+def test_block_table_histogram_compiles_at_the_plain_cells_width(
+        one_chip):
+    """The kernel walking a block table, as `criteo-tb-1700m.train-plain`
+    calls it at every loop trip: 67 all-full columns (a feature-blocked
+    grid of 5 x 16), K = 32, int8, R = 2,048, over the partition's
+    12,001,280 rows in place, with the table's length for them. The
+    partition carries the grid's 80 columns already, so the call copies
+    nothing to pad it."""
+    from lightgbm_tpu.ops.pallas_histogram import multi_leaf_histogram
+    from lightgbm_tpu.ops.partition import span_block_cap
+    s = functools.partial(_sds, one_chip)
+    n, R, K, F = 12_001_280, 2048, 32, 67
+    nb_max = span_block_cap(n, R, K)
+    assert nb_max == 2930 + 64
+    text = _compiled_text(multi_leaf_histogram.lower(
+        s((80, n), jnp.int8), s((3, n), jnp.float32), s((n,), jnp.int32),
+        s((K,), jnp.int32), num_bins=256, col_bins=(256,) * F,
+        rows_per_block=R, int_mode=True,
+        blocks=(s((nb_max,), jnp.int32), s((), jnp.int32))),
+        temp_limit=2**30)
+    assert "%multi_leaf_histogram." in text
+    table = f"s8[80,{n}]"
+    assert not [ln for ln in text.splitlines() if table in ln.split("=")[-1]
+                and ("concatenate(" in ln or " pad(" in ln)]
+
+
+@pytest.mark.parametrize("kernel,table", [
+    ("multi_leaf_histogram", False), ("multi_leaf_histogram", True),
+    ("compact_rows", False), ("partition_move", False)],
+    ids=["multi_leaf_histogram", "multi_leaf_histogram_blocks",
+         "compact_rows", "partition_move"])
+def test_pallas_call_lowers_with_its_pinned_name(one_chip, kernel, table):
     """The benchmark's kernel metrics match the device op by name
     (``^multi_leaf_histogram(\\.\\d+)?$``, ``^compact_rows(...)``,
     ``^partition_move(...)``). The name is the ``pallas_call``'s own
     ``name=``: called from a function of another name, the kernel still
     lowers and compiles under it. The leaf-ordered partition's mover is
     the compaction kernel under a name of its own, so that a trace tells
-    its two passes from GOSS's compaction."""
+    its two passes from GOSS's compaction. The histogram kernel walking
+    a block table keeps the kernel's name."""
     from lightgbm_tpu.ops import compact, pallas_histogram, partition
     s = functools.partial(_sds, one_chip)
     if kernel == "partition_move":
@@ -189,9 +219,13 @@ def test_pallas_call_lowers_with_its_pinned_name(one_chip, kernel):
     elif kernel == "multi_leaf_histogram":
         def renamed(*a):
             return pallas_histogram.multi_leaf_histogram.__wrapped__(
-                *a, num_bins=256, rows_per_block=4096, int_mode=True)
+                *a[:4], num_bins=256, rows_per_block=4096, int_mode=True,
+                blocks=a[4:] or None)
         args = (s((13, SLAB), jnp.int8), s((3, SLAB), jnp.float32),
                 s((SLAB,), jnp.int32), s((8,), jnp.int32))
+        if table:
+            args += (s((SLAB // 4096 // 2 + 16,), jnp.int32),
+                     s((), jnp.int32))
     else:
         out_cols = compact.compaction_out_cols(int(SLAB * 0.3), 1024, 4096)
 
@@ -375,14 +409,19 @@ def test_plain_chunk_program_compiles_at_the_click_rate_tables_shape(
     assert f"f32[{n_pad},6]" in text
 
 
-def test_grow_tree_compiles_under_shard_map_on_four_chips(topo, as_tpu):
+@pytest.mark.parametrize("partition", [False, True],
+                         ids=["masked", "partition"])
+def test_grow_tree_compiles_under_shard_map_on_four_chips(topo, as_tpu,
+                                                          partition):
     """tree_learner=data: the Pallas kernel inside a manual-axes region,
     rows sharded over a 4-device mesh of described devices, the
-    histogram all-reduce put in by the grower."""
+    histogram all-reduce put in by the grower. Under the leaf-ordered
+    partition each shard walks the block table of its own rows."""
     from lightgbm_tpu.learner.serial import grow_tree
     from lightgbm_tpu.parallel.mesh import DATA_AXIS, shard_map
     mesh = Mesh(np.array(topo.devices).reshape(4), (DATA_AXIS,))
-    cfg = _grow_cfg(int_hist=True, axis_name=DATA_AXIS, num_shards=4)
+    cfg = _grow_cfg(int_hist=True, axis_name=DATA_AXIS, num_shards=4,
+                    partition=partition, part_rpb=1024)
     rows, rep = P(DATA_AXIS), P()
 
     def grow(bins, vals, nb, nan, allowed, bins_t, chan_scale):

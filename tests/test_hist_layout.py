@@ -118,3 +118,61 @@ def test_no_counts_means_every_column_is_full():
                                  rows_per_block=256)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert float(np.asarray(a).sum()) == 256 * 6 * 3
+
+
+@pytest.mark.parametrize("int_mode", [True, False], ids=["int", "f32"])
+@pytest.mark.parametrize("case", ["airline", "forty_full"])
+def test_block_table_matches_masked_scan(case, int_mode):
+    """The kernel walking a block table over a leaf-ordered source reads
+    only the listed blocks, each once, and gives the masked scan's
+    histograms of the elected leaves: to the bit in int mode, to float
+    tolerance otherwise. The XLA fallback under the same table too."""
+    from lightgbm_tpu.ops.partition import span_block_cap, span_blocks
+    col_bins, B, _ = CASES[case]
+    F = len(col_bins)
+    rng = np.random.default_rng(F + int_mode)
+    n, R, L = 4096, 256, 9
+    # leaf-ordered: leaf l holds one contiguous span of random length
+    leaf = np.sort(rng.integers(0, L, size=n)).astype(np.int32)
+    bins = np.stack([rng.integers(0, min(c, B), size=n)
+                     for c in col_bins], axis=1).astype(np.uint8)
+    if int_mode:
+        g, h = rng.integers(-8, 9, size=n), rng.integers(0, 9, size=n)
+    else:
+        g, h = rng.normal(size=n), rng.uniform(size=n)
+    vals = np.stack([g, h, np.ones(n)], axis=1).astype(np.float32)
+    small = np.array([2, 7, -1, 4], np.int32)
+    offs = np.array([np.searchsorted(leaf, s) if s >= 0 else 0
+                     for s in small], np.int32)
+    cnts = np.array([np.sum(leaf == s) if s >= 0 else 0 for s in small],
+                    np.int32)
+    blocks = span_blocks(jnp.asarray(offs), jnp.asarray(cnts), R, n // R,
+                         span_block_cap(n, R, len(small)))
+    assert 0 < int(blocks[1]) < n // R      # some blocks are skipped
+    ids = jnp.asarray(small)
+    fm = (jnp.asarray(bins.T.astype(np.int8)), jnp.asarray(vals.T),
+          jnp.asarray(leaf), ids)
+    kw = dict(num_bins=B, col_bins=col_bins, rows_per_block=R,
+              int_mode=int_mode)
+    # the partition's source carries the grid's zero columns already
+    lay = onehot_layout(tuple(col_bins), B)
+    padded = jnp.pad(fm[0], ((0, lay.n_fb * lay.f_blk - F), (0, 0)))
+    with pltpu.force_tpu_interpret_mode():
+        h_tab = np.asarray(multi_leaf_histogram(*fm, blocks=blocks, **kw))
+        h_all = np.asarray(multi_leaf_histogram(*fm, **kw))
+        h_pad = np.asarray(multi_leaf_histogram(padded, *fm[1:],
+                                                blocks=blocks, **kw))
+    np.testing.assert_array_equal(h_pad, h_tab)
+    rm = (jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(leaf), ids)
+    x_tab = np.asarray(multi_leaf_histogram_xla(
+        *rm, num_bins=B, rows_per_block=R, precise=True, blocks=blocks))
+    x_all = np.asarray(multi_leaf_histogram_xla(
+        *rm, num_bins=B, rows_per_block=R, precise=True))
+    if int_mode:
+        np.testing.assert_array_equal(h_tab, h_all)
+        np.testing.assert_array_equal(h_tab, x_all)
+    else:
+        np.testing.assert_allclose(h_tab, h_all, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(x_tab, x_all, rtol=1e-5, atol=1e-4)
+    # the count channel: every row of an elected leaf counted once
+    np.testing.assert_array_equal(h_tab[:, 0, :, 2].sum(axis=1), cnts)

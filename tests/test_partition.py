@@ -2,7 +2,7 @@
 
 Contract (mirroring the GOSS-compaction one): the partitioned path
 elects and applies the SAME splits as the masked full-scan path — its
-span histograms sum the same per-row terms in a different accumulation
+block-table histograms sum the same per-row terms in another accumulation
 order, so trees are bit-identical under quantized gradients (integer
 sums are order-free) and prediction-close under f32. Partition tables
 must stay a valid leaf-contiguous layout after every split batch:
@@ -10,6 +10,7 @@ spans disjoint, counts summing to n, within-leaf source order stable.
 """
 import dataclasses
 import json
+import math
 
 import numpy as np
 import pytest
@@ -108,36 +109,63 @@ def test_move_and_tables_invariants(seed):
                 assert np.all(np.diff(tag[rows]) > 0)
 
 
-def test_slice_spans_masks_neighbours():
-    """Rows sliced from a neighbouring leaf inside a padded span get
-    leaf id -1, so each row contributes to exactly one histogram lane."""
-    n, F = 64, 3
-    rng = np.random.default_rng(7)
-    bins = jnp.asarray(rng.integers(0, 8, size=(n, F)), jnp.uint8)
-    vals = jnp.asarray(rng.normal(size=(n, 2)), jnp.float32)
-    leaf = np.repeat(np.asarray([0, 1, 2, 3], np.int32), 16)
-    offs = jnp.asarray([16, 48], jnp.int32)      # leaves 1 and 3
-    cnts = jnp.asarray([16, 16], jnp.int32)
-    S = 32
-    bs, vs, ls = part_ops.slice_spans(bins, vals, jnp.asarray(leaf),
-                                      offs, cnts, S, False)
-    assert bs.shape == (2 * S, F) and vs.shape == (2 * S, 2)
-    ls = np.asarray(ls)
-    # span 0 covers positions 16..47: leaf-1 rows keep their id, the
-    # leaf-2 padding is sentinel-masked
-    np.testing.assert_array_equal(ls[:16], 1)
-    np.testing.assert_array_equal(ls[16:32], -1)
-    # span 1 was clamped into range (48 + 32 > 64 -> start 32)
-    np.testing.assert_array_equal(ls[32:48], -1)
-    np.testing.assert_array_equal(ls[48:], 3)
+def _random_spans(seed, n=4096, R=256, K=6):
+    """K disjoint children of a random leaf layout, some lanes empty."""
+    rng = np.random.default_rng(seed)
+    cuts = np.sort(rng.choice(np.arange(1, n), size=2 * K, replace=False))
+    edges = np.concatenate([[0], cuts, [n]])
+    pick = rng.choice(len(edges) - 1, size=K, replace=False)
+    offs = edges[pick]
+    cnts = edges[pick + 1] - edges[pick]
+    cnts[rng.random(K) < 0.3] = 0            # lanes that hold no leaf
+    return n, R, offs, cnts
 
 
-def test_span_budgets_never_exceed_full_scan():
-    for n in (1024, 4096, 100000):
-        for m in (1, 8, 32):
-            budgets = part_ops.span_budgets(n, m)
-            assert all(m * s < n for s in budgets)
-            assert list(budgets) == sorted(budgets)
+SPAN_CASES = {
+    "random_0": _random_spans(0),
+    "random_1": _random_spans(1),
+    "random_2": _random_spans(2, n=8192, R=512, K=8),
+    # two children share block 1, a third starts where it ends
+    "adjacent_share_a_block": (1024, 256, [300, 400, 512], [100, 112, 40]),
+    # a child ending at the last row, one starting at row 0
+    "ends_at_the_last_row": (1024, 256, [1000, 0], [24, 3]),
+    # every lane empty (-1): nothing is listed
+    "empty_lanes": (1024, 256, [0, 512, 7], [0, 0, 0]),
+    # more blocks than the table holds: nb passes NB_max
+    "overflow": (4096, 256, [0, 2048], [2000, 2048]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SPAN_CASES))
+def test_span_blocks_cover_each_child_row_once(case):
+    """The block table lists, sorted and each once, exactly the blocks
+    that hold a row of an elected child; its tail repeats the last real
+    block, so a padded grid step asks for the block already fetched."""
+    n, R, offs, cnts = SPAN_CASES[case]
+    offs, cnts = np.asarray(offs, np.int32), np.asarray(cnts, np.int32)
+    n_blocks = n // R
+    nb_max = part_ops.span_block_cap(n, R, len(offs))
+    if case == "overflow":
+        nb_max = 8          # as a trip under bagging can: counts lie
+    blk, nb = part_ops.span_blocks(jnp.asarray(offs), jnp.asarray(cnts),
+                                   R, n_blocks, nb_max)
+    blk, nb = np.asarray(blk), int(nb)
+    want = sorted({r // R for o, c in zip(offs, cnts)
+                   for r in range(o, o + c)})
+    assert nb == len(want)
+    assert blk.shape == (nb_max,) and blk.dtype == np.int32
+    if case == "overflow":
+        assert nb > nb_max
+        np.testing.assert_array_equal(blk, want[:nb_max])
+        return
+    assert nb <= nb_max
+    np.testing.assert_array_equal(blk[:nb], want)
+    # every row of every child lies in exactly one listed block
+    listed = blk[:nb]
+    for o, c in zip(offs, cnts):
+        for r in range(o, o + c):
+            assert np.sum(listed == r // R) == 1
+    np.testing.assert_array_equal(blk[nb:], blk[nb - 1] if nb else 0)
 
 
 # ---------------------------------------------------------------------------
@@ -189,6 +217,36 @@ def test_grow_tree_partitioned_bit_identical(cfg_kw):
     assert float(t0["route_rows"]) == trips * len(lid0)
 
 
+def test_grow_tree_partition_falls_back_when_counts_lie():
+    """Under weights the count channel is not a row count: the smaller
+    child by count can hold most of the rows, list more blocks than the
+    table holds, and the trip takes the masked scan over the partition.
+    Trees stay the masked path's (the sums are exact in any order)."""
+    from lightgbm_tpu.learner.serial import GrowConfig, grow_tree
+    n, f = 2048, 4
+    rng = np.random.default_rng(4)
+    bins = rng.integers(0, 32, size=(n, f)).astype(np.uint8)
+    few = bins[:, 0] < 4                   # an eighth of the rows
+    g = np.where(few, 2.0, -1.0) + rng.integers(-1, 2, size=n)
+    # the many rows weigh 1/16: their child is the smaller by count
+    count = np.where(few, 1.0, 1 / 16)
+    vals = jnp.asarray(np.stack([g, np.ones(n), count], 1), jnp.float32)
+    cfg = GrowConfig(num_leaves=8, num_bins=32, rows_per_block=128,
+                     leaf_batch=1, min_data_in_leaf=1)
+    args = (jnp.asarray(bins), vals, jnp.full(f, 32, jnp.int32),
+            jnp.zeros(f, bool), jnp.ones(f, bool))
+    t0, lid0 = grow_tree(*args, cfg)
+    t1, lid1 = grow_tree(*args, dataclasses.replace(cfg, partition=True))
+    assert float(t1["full_scans"]) >= 1
+    for k in t0:
+        if k not in ("hist_rows", "route_rows", "route_final"):
+            np.testing.assert_array_equal(t0[k], t1[k], err_msg=k)
+    np.testing.assert_array_equal(lid0, lid1)
+    # a trip that fell back read the whole source: the root's rows and
+    # each such trip's are all counted
+    assert float(t1["hist_rows"]) >= n * (1 + float(t1["full_scans"]))
+
+
 # ---------------------------------------------------------------------------
 # engine: model-text equality across the interop matrix
 # ---------------------------------------------------------------------------
@@ -230,6 +288,34 @@ def test_partition_bit_exact_quantized(name, extra):
     _, m0 = _model_text(X, y, {**extra, "tpu_hist_partition": "false"})
     _, m1 = _model_text(X, y, {**extra, "tpu_hist_partition": "true"})
     assert m0 == m1
+
+
+@pytest.mark.parametrize("f", [8, 40], ids=["one_block", "feature_blocked"])
+def test_partition_pallas_path_bit_exact(pallas_path, f):
+    """The Pallas path (interpret mode here): the histogram kernel walks
+    the block table over the partition's own feature-major buffers, and
+    the model is the masked path's byte for byte. At 40 columns of 256
+    bins the kernel's grid is feature-blocked, and the partition carries
+    its zero columns past the table's own."""
+    X, y = _data(n=6000 if f == 8 else 3000, f=f)
+    extra = {"use_quantized_grad": True, "tpu_leaf_batch": 2,
+             "tpu_metrics": True}
+    obs.enable(metrics=True)
+    b0, m0 = _model_text(X, y, {**extra, "tpu_hist_partition": "false"},
+                         rounds=2)
+    masked = obs.registry().counter("hist.cols_scanned", sampled=0).value
+    obs.reset()
+    b1, m1 = _model_text(X, y, {**extra, "tpu_hist_partition": "true"},
+                         rounds=2)
+    assert b1.engine.use_pallas and b1.engine.hist_partition
+    assert m0 == m1
+    reg = obs.registry()
+    part = reg.counter("hist.cols_scanned", sampled=0).value
+    assert 0 < part < masked
+    # the roots' rows, then whole blocks of the grower's row block (a
+    # feature-blocked grid caps it at 2,048)
+    R = math.gcd(b1.engine.rows_per_block, 4096 if f == 8 else 2048)
+    assert (part - 2 * b1.engine.data.n_pad) % R == 0
 
 
 def test_partition_close_under_f32():
@@ -283,9 +369,9 @@ def test_partition_multiclass_quantized():
 @pytest.mark.parametrize("learner", ["data", "voting", "feature"])
 def test_partition_parallel_learners(learner):
     """All three parallel learners keep per-shard partitions (tables
-    and spans are local; histogram reductions stay outside the span
-    switch) — quantized trees match the masked path bit-for-bit on the
-    8-device CPU mesh."""
+    and block tables are local; histogram reductions stay outside the
+    table's fallback cond) — quantized trees match the masked path
+    bit-for-bit on the 8-device CPU mesh."""
     X, y = _data(n=3072, seed=9)
     extra = {"tree_learner": learner, "min_data_in_leaf": 5,
              "use_quantized_grad": True}
@@ -302,10 +388,10 @@ def test_partition_parallel_learners(learner):
 
 def test_rows_scanned_metric():
     """hist.cols_scanned: masked = n_pad x realized rounds; the
-    partitioned path must record strictly fewer once spans engage.
-    (leaf_batch is kept small so the pow2 ladder has budgets under
-    n/Kb at this test size — with the 32-lane default the spans only
-    shrink million-row inputs.)"""
+    partitioned path must record strictly fewer, the roots' rows and
+    then whole blocks, and no trip overflows the table.
+    (leaf_batch is kept small so the table is shorter than the source
+    at this test size.)"""
     X, y = _data(n=6000)
     obs.enable(metrics=True)
     obs.reset()
@@ -322,13 +408,17 @@ def test_rows_scanned_metric():
     n_pad = b0.engine.data.n_pad
     # masked path scans the whole padded buffer every round
     assert masked % n_pad == 0
+    # the partitioned path: each tree's root once, then the blocks read
+    reg = obs.registry()
+    assert (part - 3 * n_pad) % b1.engine.rows_per_block == 0
+    assert reg.counter("partition.full_scans", sampled=0).value == 0
 
 
-def test_partition_budget_ladder_no_recompiles():
-    """pow2 span budgets keep shapes static: once the step program is
+def test_partition_block_table_no_recompiles():
+    """The block table has one static length: once the step program is
     built, further same-shape training compiles ZERO fresh programs —
-    span sizes shrinking round over round select lax.switch branches
-    inside the one compiled program, never new specializations."""
+    children shrinking round over round list fewer blocks inside the
+    one compiled program, never new specializations."""
     from lightgbm_tpu.boosting.gbdt import GBDT
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.utils.debug import CompileWatch
