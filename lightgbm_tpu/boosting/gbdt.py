@@ -31,7 +31,7 @@ from ..metric import Metric, metrics_for_config
 from ..objective import Objective, create_objective
 from ..ops.histogram import pad_rows
 from ..ops.predict import forest_predict_binned, tree_predict_binned
-from ..ops.select import PASSES as SELECT_PASSES, kth_largest, kth_smallest
+from ..ops.select import kth_largest, kth_smallest
 from ..tree import Tree
 from ..utils import log
 from ..utils.prefetch import InflightWindow
@@ -1801,9 +1801,8 @@ class GBDT:
                          # the grower's work counts: rows scanned are
                          # psum'd inside grow_tree, calls and slots are
                          # the same on every shard
-                         "hist_rows", "hist_calls", "hist_slots",
-                         "hist_slots_filled", "route_rows",
-                         "route_final"]
+                         "hist_rows", "hist_calls", "hist_slots_filled",
+                         "route_rows"]
             if self.has_categorical:
                 tree_keys += ["is_cat", "cat_bitset"]
             if self.hist_partition:
@@ -2151,7 +2150,10 @@ class GBDT:
                     float(sum(dd.n for dd in self.valid_data)), force=True)
         with obs.span("train/fetch_trees"):
             host_trees = self._fetch_tree_arrays(stacked)
-        self._append_host_trees(self._count_work(host_trees, goss_active))
+        with obs.span("train/count_work"):
+            host_trees = self._count_work(host_trees, goss_active)
+        with obs.span("train/append_trees"):
+            self._append_host_trees(host_trees)
         obs.inc("train.iterations")
         obs.heartbeat("train")
         if cegb_U_new is not None:
@@ -2256,9 +2258,8 @@ class GBDT:
         step or a chunk, a few dict lookups and adds."""
         host = dict(host)
         total = {k: float(np.sum(host.pop(k), dtype=np.float64))
-                 for k in ("hist_rows", "hist_calls", "hist_slots",
-                           "hist_slots_filled", "route_rows",
-                           "route_final")}
+                 for k in ("hist_rows", "hist_calls", "hist_slots_filled",
+                           "route_rows")}
         # only the step that compacts GOSS's sample carries the first
         # two, only a tree grown under the leaf-ordered partition the rest
         compact = {k: float(np.sum(host.pop(k, 0.0), dtype=np.float64))
@@ -2273,7 +2274,6 @@ class GBDT:
                 ("hist.cols_scanned", cols),
                 ("hist.cols_needed", _cols_needed(host)),
                 ("hist.calls", total["hist_calls"]),
-                ("hist.leaf_slots", total["hist_slots"]),
                 ("hist.leaf_slots_filled", total["hist_slots_filled"]),
                 # the kernel's VPU and MXU work by its own account: the
                 # one-hot rows it builds for a column scanned
@@ -2282,9 +2282,7 @@ class GBDT:
                 # the table's rows a tree: how often the table is walked
                 ("partition.rows_routed", total["route_rows"]),
                 ("partition.rows_table",
-                 float(self.data.n_pad) * n_trees),
-                # trees whose table was routed once, after the loop
-                ("partition.final_routes", total["route_final"])):
+                 float(self.data.n_pad) * n_trees)):
             obs.inc(name, value, force=True, sampled=label)
         # splits these trees made, and how many of them are set-splits
         n_nodes = host["num_leaves"][..., None] - 1
@@ -2318,9 +2316,6 @@ class GBDT:
             obs.inc("goss.rows_in", float(rows_in * n_iters), force=True)
             obs.inc("goss.rows_kept", float(rows_kept * n_iters),
                     force=True)
-            # full reads of the rows by goss_masks' two counting selects
-            obs.inc("goss.select_passes",
-                    float(2 * SELECT_PASSES * n_iters), force=True)
         return host
 
     def _append_host_trees(self, host: Dict[str, np.ndarray]) -> None:
@@ -2426,8 +2421,9 @@ class GBDT:
                 self.score = new_score
                 with obs.span("train/fetch_trees"):
                     host = self._fetch_tree_arrays(stacked)
-                with obs.span("train/append_trees"):
+                with obs.span("train/count_work"):
                     host = self._count_work(host, goss_now)
+                with obs.span("train/append_trees"):
                     for i in range(n):
                         self._append_host_trees(
                             {kk: v[i] for kk, v in host.items()})
@@ -2726,18 +2722,22 @@ class GBDT:
         from ..metric import eval_metric_rows
         obs.inc("eval.calls", force=True)
         if which < 0:
-            dd, name = self.data, "training"
-            raw = np.asarray(self.score)[:dd.n]
+            dd, name, score = self.data, "training", self.score
         else:
             dd = self.valid_data[which]
             name = self.valid_names[which]
-            raw = np.asarray(self.valid_scores[which])[:dd.n]
+            score = self.valid_scores[which]
+        # the host waits here for the device to finish the scores, then
+        # copies them over
+        with obs.span("eval/scores"):
+            raw = np.asarray(score)[:dd.n]
         label = np.asarray(dd.label)[:dd.n] if dd.label is not None else None
         weight = (np.asarray(dd.weight)[:dd.n]
                   if dd.weight is not None else None)
-        return eval_metric_rows(self.objective, self.metrics, name,
-                                raw, label, weight,
-                                dd.query_boundaries, self.num_class)
+        with obs.span("eval/metric"):
+            return eval_metric_rows(self.objective, self.metrics, name,
+                                    raw, label, weight,
+                                    dd.query_boundaries, self.num_class)
 
     def _convert_output_np(self, raw: np.ndarray) -> np.ndarray:
         if self.num_class == 1:

@@ -1128,8 +1128,10 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         # RIGHT child pack (stably) to the back of the buffer, everything
         # else packs to the front — per-leaf contiguity and within-leaf
         # source order both survive, and the (offset, count) tables
-        # update from the same prefix sums (ops/partition.py).
-        with obs.scope("grower/partition"):
+        # update from the same prefix sums (ops/partition.py). The
+        # per-position pass keeps apply_splits' own grower/partition
+        # scope (an op takes its innermost one)
+        with obs.scope("grower/move"):
             if use_part:
                 part_leaf_mv = apply_splits(s.part_leaf, s.part_bins,
                                             fm=part_fm)
@@ -1604,11 +1606,9 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         # the grower's own work counts (boosting/gbdt.py feeds them to
         # the hist.* counters); every call has Kb slots
         "hist_calls": final.hist_calls,
-        "hist_slots": Kb * final.hist_calls,
         "hist_slots_filled": final.hist_slots_filled,
         # and its row -> leaf work (the partition.* counters)
         "route_rows": rows_routed,
-        "route_final": jnp.array(int(defer_full), i32),
     }
     if use_part:
         # the leaf-ordered partition's mover: one stable front/back move

@@ -102,6 +102,9 @@ LAYERS: Dict[str, str] = {
     # split_search: an op takes the innermost scope
     "grower/cat_search": "grower",
     "grower/partition": "grower",
+    # the leaf-ordered partition's move (plan, tables, the mover and
+    # its blend), apart from the per-position pass under partition
+    "grower/move": "grower",
     "grower/leaf_values": "grower",
     "ingest/assign": "ingest",
     # the category-table lookup inside assign

@@ -31,8 +31,7 @@ _BITS = 3
 # (shift, bits) of each pass: bit 30 down to bit 0, the top pass short
 _PASSES = tuple((s, min(_BITS, 31 - s))
                 for s in range(30 // _BITS * _BITS, -1, -_BITS))
-# full reads of the input one select makes (the `goss.select_passes`
-# counter multiplies it out)
+# full reads of the input one select makes
 PASSES = len(_PASSES)
 
 

@@ -455,7 +455,7 @@ def test_goss_sample_program_orders_no_row(one_chip):
     shapes only): its two thresholds come from the counting select, so
     the optimised program holds no sort and no top-k, and every pass of
     a select is ONE fusion that reads the rows once and leaves only
-    counts (``goss.select_passes`` counts those reads)."""
+    counts (``ops/select.py::PASSES`` reads a select)."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.boosting.gbdt import GBDT
     from lightgbm_tpu.config import Config

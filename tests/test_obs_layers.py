@@ -138,8 +138,7 @@ def test_cols_needed_is_the_benchmarks_count_and_calls_are_exact():
     assert calls == sum(int(t["num_leaves"]) for t in trees)
     # one slot a call, and every slot held a leaf
     for s in (0, 1):
-        assert _counter("hist.leaf_slots", sampled=s) \
-            == _counter("hist.calls", sampled=s) \
+        assert _counter("hist.calls", sampled=s) \
             == _counter("hist.leaf_slots_filled", sampled=s)
 
 
@@ -156,7 +155,8 @@ def test_cols_scanned_is_at_least_cols_needed(extra):
             assert s == 1 and extra.get("data_sample_strategy")
             continue
         assert scanned >= _counter("hist.cols_needed", sampled=s) > 0
-        assert _counter("hist.leaf_slots", sampled=s) \
+        # a call has tpu_leaf_batch slots, not all of them filled
+        assert extra["tpu_leaf_batch"] * _counter("hist.calls", sampled=s) \
             >= _counter("hist.leaf_slots_filled", sampled=s) > 0
         assert _counter("hist.onehot_elems", sampled=s) \
             == scanned * 8 * 256
@@ -198,9 +198,6 @@ def test_counters_are_kept_with_obs_off_and_carry_sampled():
     assert _counter("hist.calls", sampled=1) == sum(n_leaves[2:])
     assert _counter("goss.rows_in") == 4 * n
     assert _counter("goss.rows_kept") == 4 * (int(n * 0.2) + int(n * 0.1))
-    # two counting selects' reads of the rows, a sampled iteration
-    from lightgbm_tpu.ops.select import PASSES
-    assert _counter("goss.select_passes") == 4 * 2 * PASSES
     # the compacted buffer is shorter than the table: fewer columns a
     # call under sampling
     per_call = [_counter("hist.cols_scanned", sampled=s)
@@ -294,6 +291,72 @@ def test_select_ops_are_joined_to_goss_sample(cpu_dump):
     # the gaps between the chunks belong to the program's own spans
     assert all(g["name"].startswith("lgbm/train/")
                for g in res["idle_gaps"] if g["ms"] > 1.0)
+
+
+def test_move_ops_carry_their_own_scope_and_the_pass_keeps_partition():
+    """Under the leaf-ordered partition (plain, off the TPU: the move is
+    one scatter a carried array) the chunk program as compiled, read by
+    the HLO join the program's reader makes off the TPU: the move's
+    scatters are under ``grower/move``, the per-position pass's one-hot
+    product stays under ``grower/partition``, and neither holds the
+    other's ops."""
+    bst = _train({"data_sample_strategy": "bagging", "tpu_leaf_batch": 2,
+                  "tpu_hist_partition": "true"}, rounds=2,
+                 keep_training_booster=True)
+    eng = bst.engine
+    assert eng.hist_partition and eng.can_fuse_iters()
+    keys = jax.numpy.zeros((2, 2), jax.numpy.uint32)
+    compiled = jax.jit(eng._make_chunk(False)).lower(eng.score,
+                                                     keys).compile()
+    (module,) = compiled.runtime_executable().hlo_modules()
+    hlo = module.as_serialized_hlo_module_proto()
+    # the dump embeds an HloProto: the module is its field 1
+    length, n = b"", len(hlo)
+    while True:
+        length += bytes([(n & 0x7F) | (0x80 if n >> 7 else 0)])
+        n >>= 7
+        if not n:
+            break
+    _name, scopes = trace_attr._hlo_instr_scopes(b"\x0a" + length + hlo)
+    by_scope = {}
+    for name, sc in scopes.items():
+        by_scope.setdefault(sc, set()).add(name)
+    move = by_scope["lgbm/grower/move"]
+    part = by_scope["lgbm/grower/partition"]
+    assert any("scatter" in n for n in move)
+    assert not any("scatter" in n for n in part)
+    assert any(n.startswith("dot") for n in part)
+    assert not any(n.startswith("dot") for n in move)
+
+
+def test_the_host_spans_of_a_chunk_and_an_eval_are_recorded():
+    """One fused chunk, one step and one ``eval_set`` with metrics on:
+    the counters' cost and the tree building are apart, and the wait for
+    the scores apart from the metric."""
+    def count(name):
+        hist = obs.registry().get(name)
+        return 0 if hist is None else hist.count
+
+    X, y = _data()
+    ds = lgb.Dataset(X, label=y)
+    fused = lgb.train(GOSS, ds, num_boost_round=2,
+                      keep_training_booster=True).engine
+    obs.enable(metrics=True)
+    assert fused.can_fuse_iters()
+    fused.train_chunk(2)
+    assert (count("train/count_work"), count("train/append_trees")) == (1, 1)
+    assert count("train/step") == 0
+    # the step path, which a validation set takes: the same two spans
+    dv = ds.create_valid(X[:1000], label=y[:1000])
+    step = lgb.train(dict(GOSS, metric="auc"), ds, num_boost_round=2,
+                     valid_sets=[dv], keep_training_booster=True).engine
+    obs.reset()
+    step.train_one_iter()
+    assert (count("train/step"), count("train/count_work"),
+            count("train/append_trees")) == (1, 1, 1)
+    ((_name, metric, value, _hb),) = step.eval_set(0)
+    assert metric == "auc" and 0.5 < value <= 1.0
+    assert (count("eval/scores"), count("eval/metric")) == (1, 1)
 
 
 def _chip_dump(tmp_path) -> str:

@@ -126,12 +126,11 @@ def test_ids_after_the_loop_are_the_in_loop_ids(grown):
     _, tree_in, ids_in = _grow(name, lazy=True)
     # the same tree either way, so the same ids are owed
     for k in tree:
-        if k not in ("route_rows", "route_final"):
+        if k != "route_rows":
             np.testing.assert_array_equal(tree[k], tree_in[k], err_msg=k)
     np.testing.assert_array_equal(ids, ids_in)
     # the table was routed once after the loop, against once a trip
     trips, n = int(tree["hist_calls"]) - 1, len(bins)
-    assert int(tree["route_final"]) == 1 and int(tree_in["route_final"]) == 0
     assert tree_in["route_rows"] - tree["route_rows"] == (trips - 1) * n
 
 
@@ -207,7 +206,6 @@ def test_goss_models_are_the_masked_paths(pallas_path):
         reg = obs.registry()
         routed = reg.get("partition.rows_routed", sampled=1).value
         table = reg.get("partition.rows_table", sampled=1).value
-        finals = reg.get("partition.final_routes", sampled=1).value
         eng = bst._engine
         n_pad, rpb = eng.data.n_pad, eng.rows_per_block
         trees = table / n_pad               # the sampled rounds' trees
@@ -217,9 +215,7 @@ def test_goss_models_are_the_masked_paths(pallas_path):
             # the buffer at every loop trip, the table once a tree
             n_sub = compaction_out_cols(
                 int(np.ceil(n_pad * 0.3)) + 8192, min(1024, rpb), rpb)
-            assert finals == trees
             assert routed == trips * n_sub + trees * n_pad
         else:
-            assert finals == 0
             assert routed == trips * n_pad
     assert texts[True] == texts[False]
