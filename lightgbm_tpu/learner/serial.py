@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Dict, NamedTuple, Tuple
 
 import jax
@@ -307,6 +308,9 @@ class GrowState(NamedTuple):
     # under the leaf-ordered partition: the loop trips that took the
     # masked full scan (the partition.full_scans counter)
     full_scans: jnp.ndarray
+    # under the leaf-ordered partition: rows of the blocks the mover
+    # was handed so far this tree (the partition.rows_moved counter)
+    rows_moved: jnp.ndarray
 
 
 def _masked_gains(gain, leaf_depth, num_leaves, max_depth):
@@ -432,7 +436,6 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         # feature-blocked grid (more one-hot rows than one block holds,
         # e.g. MSLR widths) overflows the 16MB scoped-vmem budget at
         # 4096 — those shapes cap at 2048.
-        import math
         h_lay = onehot_layout(col_bins, B)
         r_cap = 4096 if h_lay.n_fb == 1 else 2048
         # the kernel's feature windows cover F_pad columns: a source
@@ -484,6 +487,8 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         from ..ops import partition as part_ops
         n_blocks = n_h // base_rpb
         nb_max = part_ops.span_block_cap(n_h, base_rpb, Kb)
+        # the mover's row block
+        move_rpb = math.gcd(cfg.part_rpb, n_h)
 
         @obs.scope("grower/histogram")
         def span_hist(pb, pv, pl, ids, offs, cnts):
@@ -880,6 +885,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
             hist_calls=jnp.array(1, i32),
             hist_slots_filled=jnp.array(1, i32),
             full_scans=jnp.array(0, i32),
+            rows_moved=jnp.asarray(0.0, jnp.float32),
         )
 
     node_trash = L - 1  # real nodes occupy 0..L-2
@@ -1124,40 +1130,47 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         hist_lid = leaf_id_c if compact is not None else leaf_id
 
         # ---- leaf-ordered repartition (cfg.partition) ------------------
-        # one stable front/back move per round: rows that routed to a
-        # RIGHT child pack (stably) to the back of the buffer, everything
-        # else packs to the front — per-leaf contiguity and within-leaf
-        # source order both survive, and the (offset, count) tables
-        # update from the same prefix sums (ops/partition.py). The
-        # per-position pass keeps apply_splits' own grower/partition
-        # scope (an op takes its innermost one)
+        # each split leaf partitions stably inside its own span: the left
+        # child keeps the span's front, the right child takes the rows
+        # after it, and the rows of every other leaf stay where they are
+        # (ops/partition.py). The per-position pass keeps apply_splits'
+        # own grower/partition scope (an op takes its innermost one)
         with obs.scope("grower/move"):
             if use_part:
                 part_leaf_mv = apply_splits(s.part_leaf, s.part_bins,
                                             fm=part_fm)
                 moved = part_leaf_mv != s.part_leaf
-                dest, n_front, cum = part_ops.plan_split_move(moved)
+                offs_p = s.part_off[tl_safe]
+                cnts_p = jnp.where(valid, s.part_cnt[tl_safe], 0)
+                plan = part_ops.plan_split_move(moved, offs_p, cnts_p,
+                                                move_rpb)
                 p_off, p_cnt = part_ops.update_tables(
-                    s.part_off, s.part_cnt, cum, n_front, tl_safe, new_ids,
-                    valid)
+                    s.part_off, s.part_cnt, tl_safe, new_ids, valid, plan)
                 if part_fm:
-                    # TPU: two compact_rows passes (front keys, back keys);
-                    # the int32 leaf ids ride as one extra float32 value
-                    # channel (exact via the kernel's bf16x3 split)
+                    # TPU: two compact_rows passes over the split leaves'
+                    # blocks (left rows, right rows); the int32 leaf ids
+                    # ride as one extra float32 value channel (exact via
+                    # the kernel's bf16x3 split)
                     pv_aug = jnp.concatenate(
                         [s.part_vals,
                          part_leaf_mv[None].astype(jnp.float32)])
                     p_bins, pv2 = part_ops.move_cols_tpu(
-                        s.part_bins, pv_aug, moved, n_front, cfg.part_rpb)
+                        s.part_bins, pv_aug, plan, move_rpb)
                     p_vals = pv2[:-1]
                     p_leaf = pv2[-1].astype(i32)
                 else:
                     p_bins, p_vals, p_leaf = part_ops.move_rows_xla(
-                        [s.part_bins, s.part_vals, part_leaf_mv], dest)
+                        [s.part_bins, s.part_vals, part_leaf_mv],
+                        part_ops.row_dest(plan, move_rpb))
+                # the rows of the blocks the mover was handed
+                rows_moved = (s.rows_moved
+                              + plan.touched.astype(jnp.float32)
+                              * float(move_rpb))
             else:
                 p_bins, p_vals, p_leaf = (s.part_bins, s.part_vals,
                                           s.part_leaf)
                 p_off, p_cnt = s.part_off, s.part_cnt
+                rows_moved = s.rows_moved
 
         def span_tables(ids):
             """Per-elected-child (offset, count) rows for span_blocks
@@ -1535,6 +1548,7 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
                 hist_slots_filled=s.hist_slots_filled
                 + jnp.sum(small_ids >= 0).astype(i32),
                 full_scans=full_scans,
+                rows_moved=rows_moved,
             )
         next_gains = _masked_gains(new.best_gain, new.leaf_depth,
                                    new.num_leaves, cfg.max_depth)
@@ -1611,15 +1625,17 @@ def grow_tree(bins: jax.Array, vals: jax.Array,
         "route_rows": rows_routed,
     }
     if use_part:
-        # the leaf-ordered partition's mover: one stable front/back move
-        # of the whole histogram source a loop trip (two kernel passes
-        # on the TPU); only emitted on that path, so every other
-        # program is the one it was
+        # the leaf-ordered partition's mover: one move a loop trip (two
+        # kernel passes on the TPU) over the blocks that hold a row of a
+        # split leaf; only emitted on that path, so every other program
+        # is the one it was
         moves = trips
+        rows_moved = final.rows_moved
         if cfg.axis_name:
             moves = jax.lax.psum(moves, cfg.axis_name)
+            rows_moved = jax.lax.psum(rows_moved, cfg.axis_name)
         tree["move_calls"] = moves
-        tree["move_rows"] = moves * float(n_h)
+        tree["move_rows"] = rows_moved
         # the loop trips whose children did not fit the block table
         full_scans = final.full_scans.astype(jnp.float32)
         if cfg.axis_name:

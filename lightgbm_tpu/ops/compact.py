@@ -39,7 +39,7 @@ docs/perf.md "Row compaction kernel" has the design notes.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -98,10 +98,39 @@ def plan_compaction(mask: jax.Array, rows_per_block: int,
     return dest, aligned, rem, nch
 
 
-def _compact_kernel(algn_ref, fill_ref, dest_ref, bins_ref, vals_ref,
-                    bins_out, vals_out, bins_vmem, vals_vmem, sem_b, sem_v,
-                    *, rows_per_block: int):
+def _compact_kernel(algn_ref, fill_ref, *refs, rows_per_block: int,
+                    table: bool):
+    if table:
+        blk_ref, span_ref, base_ref, *refs = refs
+    (dest_ref, bins_ref, vals_ref, bins_out, vals_out, bins_vmem, vals_vmem,
+     sem_b, sem_v) = refs
     b = pl.program_id(0)
+    if not table:
+        _compact_step(b, algn_ref, fill_ref, dest_ref, bins_ref, vals_ref,
+                      bins_out, vals_out, bins_vmem, vals_vmem, sem_b, sem_v,
+                      rows_per_block=rows_per_block, piece=None,
+                      last=lambda: b == pl.num_programs(0) - 1)
+        return
+    # a block table: step b walks piece b, the rows [lo, hi) of block
+    # blk[b]; the steps past the last piece (span -1) do nothing, and
+    # the last piece waits for its own write
+    sp = span_ref[b]
+
+    def last():
+        nxt = span_ref[jnp.minimum(b + 1, pl.num_programs(0) - 1)]
+        return (b == pl.num_programs(0) - 1) | (nxt < 0)
+
+    @pl.when(sp >= 0)
+    def _():
+        _compact_step(b, algn_ref, fill_ref, dest_ref, bins_ref, vals_ref,
+                      bins_out, vals_out, bins_vmem, vals_vmem, sem_b, sem_v,
+                      rows_per_block=rows_per_block,
+                      piece=(sp & 0xFFFF, sp >> 16, base_ref[b]), last=last)
+
+
+def _compact_step(b, algn_ref, fill_ref, dest_ref, bins_ref, vals_ref,
+                  bins_out, vals_out, bins_vmem, vals_vmem, sem_b, sem_v, *,
+                  rows_per_block: int, piece, last):
     R = rows_per_block
     W = R + _LANE
     F_pad, C_pad = bins_ref.shape[0], vals_ref.shape[0]
@@ -118,6 +147,12 @@ def _compact_kernel(algn_ref, fill_ref, dest_ref, bins_ref, vals_ref,
     # after the rem carried-over columns (the shift must not touch the
     # -1 sentinel, which rem > 0 would otherwise lift to a real column)
     d0 = dest_ref[...]
+    if piece is not None:
+        # the piece's rows alone, ranked from its first: the block's
+        # kept rows before lo, and its rows of other leaves, drop out
+        lo, hi, base = piece
+        r = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
+        d0 = jnp.where((d0 >= 0) & (r >= lo) & (r < hi), d0 - base, -1)
     dest = jnp.where(d0 >= 0, d0 + rem, -1)                 # [1, R]
     # ONE bf16 operand for everything a row carries. The bins' -128..127
     # are exact in bf16. The value channels move EXACTLY via a 3-way
@@ -158,7 +193,10 @@ def _compact_kernel(algn_ref, fill_ref, dest_ref, bins_ref, vals_ref,
     jax.lax.fori_loop(0, nch, group, 0)
     # the predecessor's partial output group is group (off - off_prev)
     # / 128 of the window it built (the grid is sequential); at b == 0
-    # that slot is uninitialized, masked off below (rem = 0)
+    # that slot is uninitialized, masked off below (rem = 0). Under a
+    # block table a piece may start a new run past the predecessor's
+    # window: the head group then holds none of its rows, and what the
+    # clipped group puts there lies outside every run
     g = jnp.clip(a_now - a_prev, 0, W // _LANE - 1)
     hcol = pl.multiple_of(g * _LANE, _LANE)
     head_ok = (jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
@@ -188,7 +226,7 @@ def _compact_kernel(algn_ref, fill_ref, dest_ref, bins_ref, vals_ref,
     for dma in mine:
         dma.start()
 
-    @pl.when(b == pl.num_programs(0) - 1)
+    @pl.when(last())
     def _():
         for dma in mine:
             dma.wait()
@@ -199,7 +237,9 @@ def _compact_kernel(algn_ref, fill_ref, dest_ref, bins_ref, vals_ref,
 def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
                  aligned: jax.Array, rem: jax.Array, nch: jax.Array, *,
                  out_cols: int, rows_per_block: int = 1024,
-                 name: str = "compact_rows"
+                 name: str = "compact_rows",
+                 blocks: Optional[Tuple[jax.Array, jax.Array,
+                                        jax.Array]] = None
                  ) -> Tuple[jax.Array, jax.Array]:
     """Compact kept columns of feature-major arrays (TPU Pallas path).
 
@@ -214,12 +254,23 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
       name: the device op's name (the leaf-ordered partition's mover
         passes ``partition_move``, so a trace tells its passes from
         GOSS's compaction).
+      blocks: ``None`` (every block once, in order) or a table of
+        PIECES the grid walks instead (scalar-prefetched, as
+        ``multi_leaf_histogram(..., blocks=)``): ``(blk, span, base)``,
+        each ``[P]`` int32 — piece p reads block ``blk[p]``, keeps only
+        its rows ``[lo, hi)`` (``span = lo | hi << 16``; -1 past the
+        last piece, a step that does nothing) and ranks them from
+        ``dest - base[p]``; ``aligned`` / ``rem`` / ``nch`` are then
+        per piece, and the pieces' write positions may jump forward
+        between runs (ops/partition.py: the leaf-ordered mover).
 
     Returns:
       (``[F, out_cols]`` int8, ``[C, out_cols]`` float32): kept columns
       packed contiguously left-to-right in source order; the tail is
       zeros, so downstream histogram scans see zero contributions
-      there (and a leaf_id+1 channel decodes the tail to -1).
+      there (and a leaf_id+1 channel decodes the tail to -1). Under a
+      block table only the pieces' own runs are defined, and the rows
+      stay padded to a multiple of 8 (the kernel's own outputs).
     """
     F, n = bins_t.shape
     C = vals_t.shape[0]
@@ -231,7 +282,7 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
     assert R % _LANE == 0 and R <= 2048, \
         f"rows_per_block={R}: a multiple of 128, at most 2048"
     assert out_cols >= R + _LANE, "out_cols below one write window"
-    nb = n // R
+    nb = n // R if blocks is None else blocks[0].shape[0]
     W = R + _LANE
     # the manual output DMAs slice dim 0 whole, which Mosaic requires
     # 8-sublane aligned — pad the channel dims with zero rows
@@ -254,15 +305,26 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
     # [F_pad, n] input; and even at equal widths the kernel reads
     # block b's source columns AFTER earlier blocks wrote their packed
     # output left of them — in-place would clobber unread sources.
+    if blocks is None:
+        scalars = (aligned, fill)
+
+        def at(b, a, f):
+            return (0, b)
+    else:
+        scalars = (aligned, fill) + tuple(blocks)
+
+        def at(b, a, f, blk, span, base):
+            return (0, blk[b])
     out_b, out_v = pl.pallas_call(
-        functools.partial(_compact_kernel, rows_per_block=R),
+        functools.partial(_compact_kernel, rows_per_block=R,
+                          table=blocks is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(nb,),
             in_specs=[
-                pl.BlockSpec((1, R), lambda b, a, f: (0, b)),
-                pl.BlockSpec((F_pad, R), lambda b, a, f: (0, b)),
-                pl.BlockSpec((C_pad, R), lambda b, a, f: (0, b)),
+                pl.BlockSpec((1, R), at),
+                pl.BlockSpec((F_pad, R), at),
+                pl.BlockSpec((C_pad, R), at),
             ],
             out_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -281,7 +343,9 @@ def compact_rows(bins_t: jax.Array, vals_t: jax.Array, dest: jax.Array,
         ],
         # the device op's name, pinned: profile readers match it
         name=name,
-    )(aligned, fill, dest.reshape(1, n), bins_t, vals_t)
+    )(*scalars, dest.reshape(1, n), bins_t, vals_t)
+    if blocks is not None:
+        return out_b, out_v
     # Pallas outputs are uninitialized and a window's groups past the
     # ones its block filled hold whatever an earlier step left: zero
     # everything past the stream's exact end (the last block's start

@@ -145,6 +145,39 @@ def test_compact_rows_block_scalars_fit_smem(one_chip):
     assert "tpu_custom_call" in text
 
 
+# sha256 of the jaxpr of GOSS's compaction (no block table) at the
+# shapes below, taken before the mover walked a block table: the kernel
+# body and its scalars are what they were, op for op
+COMPACT_ROWS_JAXPR = ("ffcc73ebfe5fb3d957f331546f1201286f3287609a3de508b6"
+                      "3977c955f6b637")
+
+
+def test_compact_rows_without_a_table_is_gosss_program(one_chip):
+    """``compact_rows(..., blocks=None)`` is the program GOSS compiled
+    before the leaf-ordered mover took a block table (same jaxpr, the
+    Pallas kernel's included), and still compiles with its five
+    operands; the tabled program adds the table's three words a piece."""
+    import hashlib
+    from lightgbm_tpu.ops.compact import (compact_rows,
+                                          compaction_out_cols)
+    s = functools.partial(_sds, one_chip)
+    R = 1024
+    out_cols = compaction_out_cols(int(SLAB * 0.3), R, 4096)
+    per_block = s((SLAB // R,), jnp.int32)
+    args = (s((13, SLAB), jnp.int8), s((4, SLAB), jnp.float32),
+            s((SLAB,), jnp.int32), per_block, per_block, per_block)
+    jaxpr = str(jax.make_jaxpr(lambda *a: compact_rows.__wrapped__(
+        *a, out_cols=out_cols, rows_per_block=R))(*args))
+    assert hashlib.sha256(jaxpr.encode()).hexdigest() == COMPACT_ROWS_JAXPR
+    for blocks in (None, (per_block,) * 3):
+        text = _compiled_text(compact_rows.lower(
+            *args, out_cols=out_cols, rows_per_block=R, blocks=blocks))
+        call = next(ln for ln in text.splitlines()
+                    if "%compact_rows." in ln.split("=")[0])
+        assert call.count(f"s32[{SLAB // R}]") == (2 if blocks is None
+                                                   else 5), call
+
+
 def _route_nodes(s, n_nodes, W):
     from lightgbm_tpu.ops.route import RouteNodes
     col = s((n_nodes,), jnp.int32)
@@ -212,10 +245,13 @@ def test_pallas_call_lowers_with_its_pinned_name(one_chip, kernel, table):
     from lightgbm_tpu.ops import compact, pallas_histogram, partition
     s = functools.partial(_sds, one_chip)
     if kernel == "partition_move":
-        def renamed(*a):
-            return partition.move_cols_tpu(*a, rows_per_block=1024)
+        def renamed(bins, vals, moved, offs, cnts):
+            plan = partition.plan_split_move(moved, offs, cnts, 1024)
+            return partition.move_cols_tpu(bins, vals, plan,
+                                           rows_per_block=1024)
         args = (s((13, SLAB), jnp.int8), s((4, SLAB), jnp.float32),
-                s((SLAB,), jnp.bool_), s((), jnp.int32))
+                s((SLAB,), jnp.bool_), s((32,), jnp.int32),
+                s((32,), jnp.int32))
     elif kernel == "multi_leaf_histogram":
         def renamed(*a):
             return pallas_histogram.multi_leaf_histogram.__wrapped__(
@@ -405,6 +441,25 @@ def test_plain_chunk_program_compiles_at_the_click_rate_tables_shape(
     for kernel in ("partition_move", "multi_leaf_histogram", "route_rows"):
         assert f"%{kernel}." in text, kernel
     assert "%compact_rows." not in text
+    # the mover's two passes a loop trip (the roofline's reader counts
+    # them in pairs), each walking the trip's pieces: a table of one
+    # piece a block plus one a lane
+    moves = [ln for ln in text.splitlines()
+             if "%partition_move." in ln.split("=")[0]
+             and "custom-call(" in ln]
+    assert len(moves) == 2, moves
+    pieces = f"s32[{n_pad // eng.part_rpb + eng.grow_cfg.leaf_batch}]"
+    assert all(ln.count(pieces) == 5 for ln in moves), pieces
+    # and one blend, which writes the touched blocks into the carried
+    # buffer in place: no copy of the partition's bins is made for it
+    blend = [ln for ln in text.splitlines()
+             if "%partition_blend." in ln.split("=")[0]
+             and "custom-call(" in ln]
+    assert len(blend) == 1 and "output_to_operand_aliasing" in blend[0]
+    bins = f"s8[80,{n_pad}]"
+    assert not [ln for ln in text.splitlines() if "=" in ln
+                and bins in ln.split("=")[1].split("(")[0]
+                and " copy(" in ln], bins
     # the one float32 attribute matrix left is the partition's own pass
     assert f"f32[{n_pad},6]" in text
 

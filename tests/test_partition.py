@@ -33,27 +33,55 @@ def _isolated_obs():
 
 
 # ---------------------------------------------------------------------------
-# unit: the stable front/back move + table updates vs a numpy reference
+# unit: the span-local move + table updates vs a numpy reference
 # ---------------------------------------------------------------------------
 
-def _np_reference_move(leaf, parents, rights):
-    """Reference semantics in plain numpy: rows whose leaf id is a
-    right child of this round move stably to the back; everything else
-    packs stably to the front."""
-    moved = np.isin(leaf, rights)
-    order = np.concatenate([np.flatnonzero(~moved),
-                            np.flatnonzero(moved)])
-    return order, int((~moved).sum())
+def _np_reference_move(leaf, new_leaf, parents):
+    """Reference semantics in plain numpy: inside each split leaf's span
+    the rows that stay in it come first and the rows routed to its right
+    child after them, each in source order; every other row stays where
+    it is. Returns the new order (``order[j]`` = old position now at j)."""
+    order = np.arange(len(leaf))
+    for p in parents:
+        rows = np.flatnonzero(leaf == p)
+        if len(rows):
+            moved = new_leaf[rows] != p
+            order[rows] = np.concatenate([rows[~moved], rows[moved]])
+    return order
+
+
+def _plan(leaf, new_leaf, off, cnt, parents, valid, R):
+    offs = jnp.asarray(off[parents])
+    cnts = jnp.asarray(np.where(valid, cnt[parents], 0).astype(np.int32))
+    return part_ops.plan_split_move(jnp.asarray(new_leaf != leaf), offs,
+                                    cnts, R)
+
+
+def _random_trip(rng, leaf, cnt, num_leaves, L, Kb):
+    """One split batch over a random choice of leaves, a random share of
+    each one's rows routed right (the row -> leaf pass's result)."""
+    active = [lf for lf in range(num_leaves) if cnt[lf] > 1]
+    k = min(Kb, len(active), L - num_leaves)
+    parents = np.asarray(rng.choice(active, size=k, replace=False),
+                         np.int32)
+    new_ids = np.arange(num_leaves, num_leaves + k, dtype=np.int32)
+    new_leaf = leaf.copy()
+    for p, nid in zip(parents, new_ids):
+        rows = np.flatnonzero(leaf == p)
+        take = rng.random(len(rows)) < rng.uniform(0.0, 1.0)
+        new_leaf[rows[take]] = nid
+    return parents, new_ids, new_leaf
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_move_and_tables_invariants(seed):
     """Property-style pin over several random split batches: dest is a
-    permutation, per-leaf spans stay contiguous/disjoint, offsets match
-    the (offset, count) tables, counts sum to n, and within-leaf source
-    order is preserved (stability)."""
+    permutation equal to the span-local reference order, per-leaf spans
+    stay contiguous/disjoint, offsets match the (offset, count) tables,
+    counts sum to n, and within-leaf source order is preserved
+    (stability)."""
     rng = np.random.default_rng(seed)
-    n, L, Kb = 512, 31, 4
+    n, L, Kb, R = 512, 31, 4, 64
     leaf = np.zeros(n, np.int32)
     off = np.zeros(L + 1, np.int32)
     cnt = np.zeros(L + 1, np.int32)
@@ -62,37 +90,23 @@ def test_move_and_tables_invariants(seed):
     tag = np.arange(n, dtype=np.int32)
     num_leaves = 1
     for _ in range(6):
-        active = [lf for lf in range(num_leaves) if cnt[lf] > 1]
-        if not active or num_leaves >= L - Kb:
+        if num_leaves >= L - Kb:
             break
-        k = min(Kb, len(active), L - num_leaves)
-        parents = np.asarray(rng.choice(active, size=k, replace=False),
-                             np.int32)
-        new_ids = np.arange(num_leaves, num_leaves + k, dtype=np.int32)
+        parents, new_ids, new_leaf = _random_trip(rng, leaf, cnt,
+                                                  num_leaves, L, Kb)
+        k = len(parents)
         valid = np.ones(k, bool)
-        # route a random subset of each parent's rows to its right child
-        new_leaf = leaf.copy()
-        for p, nid in zip(parents, new_ids):
-            rows = np.flatnonzero(leaf == p)
-            take = rng.random(len(rows)) < rng.uniform(0.2, 0.8)
-            new_leaf[rows[take]] = nid
-        moved = new_leaf != leaf
-        dest, n_front, cum = part_ops.plan_split_move(
-            jnp.asarray(moved))
-        dest = np.asarray(dest)
-        n_front = int(n_front)
+        plan = _plan(leaf, new_leaf, off, cnt, parents, valid, R)
+        dest = np.asarray(part_ops.row_dest(plan, R))
         # dest is a permutation and matches the stable reference order
         assert sorted(dest.tolist()) == list(range(n))
-        order, ref_front = _np_reference_move(new_leaf, parents,
-                                              new_ids.tolist())
-        assert n_front == ref_front
+        order = _np_reference_move(leaf, new_leaf, parents)
         inv = np.empty(n, np.int64)
         inv[dest] = np.arange(n)
         np.testing.assert_array_equal(inv, order)
         off2, cnt2 = part_ops.update_tables(
-            jnp.asarray(off), jnp.asarray(cnt), cum,
-            jnp.asarray(n_front, jnp.int32), jnp.asarray(parents),
-            jnp.asarray(new_ids), jnp.asarray(valid))
+            jnp.asarray(off), jnp.asarray(cnt), jnp.asarray(parents),
+            jnp.asarray(new_ids), jnp.asarray(valid), plan)
         off, cnt = np.asarray(off2).copy(), np.asarray(cnt2).copy()
         leaf = new_leaf[order]
         tag = tag[order]
@@ -107,6 +121,189 @@ def test_move_and_tables_invariants(seed):
                 assert rows[0] == off[lf]
                 assert rows[-1] == off[lf] + cnt[lf] - 1
                 assert np.all(np.diff(tag[rows]) > 0)
+
+
+def _layout(seed, n=1024, L=15, trips=3, Kb=4):
+    """A leaf-ordered layout a few random trips deep, as the grower
+    leaves it: (leaf per position, off, cnt, tag per position, leaves)."""
+    rng = np.random.default_rng(seed)
+    leaf = np.zeros(n, np.int32)
+    off = np.zeros(L + 1, np.int32)
+    cnt = np.zeros(L + 1, np.int32)
+    cnt[0] = n
+    tag = np.arange(n, dtype=np.int32)
+    num_leaves = 1
+    for _ in range(trips):
+        parents, new_ids, new_leaf = _random_trip(rng, leaf, cnt,
+                                                  num_leaves, L, Kb)
+        order = _np_reference_move(leaf, new_leaf, parents)
+        leaf, tag = new_leaf[order], tag[order]
+        num_leaves += len(parents)
+        for lf in range(num_leaves):
+            rows = np.flatnonzero(leaf == lf)
+            off[lf], cnt[lf] = (rows[0] if len(rows) else 0), len(rows)
+    return rng, leaf, off, cnt, tag, num_leaves
+
+
+@pytest.mark.parametrize("seed", [3, 4, 5])
+def test_move_leaves_unsplit_leaves_in_place(seed):
+    """After one move every leaf the trip does not split keeps its
+    offset, its count and its rows, position for position."""
+    rng, leaf, off, cnt, tag, num_leaves = _layout(seed)
+    parents, new_ids, new_leaf = _random_trip(rng, leaf, cnt, num_leaves,
+                                              15, 2)
+    valid = np.ones(len(parents), bool)
+    plan = _plan(leaf, new_leaf, off, cnt, parents, valid, 128)
+    dest = np.asarray(part_ops.row_dest(plan, 128))
+    off2, cnt2 = (np.asarray(t) for t in part_ops.update_tables(
+        jnp.asarray(off), jnp.asarray(cnt), jnp.asarray(parents),
+        jnp.asarray(new_ids), jnp.asarray(valid), plan))
+    unsplit = [lf for lf in range(num_leaves) if lf not in parents]
+    assert unsplit
+    for lf in unsplit:
+        assert (off2[lf], cnt2[lf]) == (off[lf], cnt[lf])
+        rows = np.arange(off[lf], off[lf] + cnt[lf])
+        np.testing.assert_array_equal(dest[rows], rows)
+
+
+@pytest.mark.parametrize("seed", [3, 4, 5])
+def test_split_leaf_children_sit_in_its_span(seed):
+    """A split leaf's children sit left then right inside its old span,
+    each in source order: the left child keeps the parent's slot and
+    offset, the right child starts where the left one ends."""
+    rng, leaf, off, cnt, tag, num_leaves = _layout(seed)
+    parents, new_ids, new_leaf = _random_trip(rng, leaf, cnt, num_leaves,
+                                              15, 3)
+    valid = np.ones(len(parents), bool)
+    plan = _plan(leaf, new_leaf, off, cnt, parents, valid, 128)
+    dest = np.asarray(part_ops.row_dest(plan, 128))
+    off2, cnt2 = (np.asarray(t) for t in part_ops.update_tables(
+        jnp.asarray(off), jnp.asarray(cnt), jnp.asarray(parents),
+        jnp.asarray(new_ids), jnp.asarray(valid), plan))
+    inv = np.empty_like(dest)
+    inv[dest] = np.arange(len(dest))
+    leaf2, tag2 = new_leaf[inv], tag[inv]
+    for p, nid in zip(parents, new_ids):
+        lo, hi = off[p], off[p] + cnt[p]
+        n_left = int(np.sum(new_leaf[lo:hi] == p))
+        assert (off2[p], cnt2[p]) == (lo, n_left)
+        assert (off2[nid], cnt2[nid]) == (lo + n_left, cnt[p] - n_left)
+        assert np.all(leaf2[lo:lo + n_left] == p)
+        assert np.all(leaf2[lo + n_left:hi] == nid)
+        assert np.all(np.diff(tag2[lo:lo + n_left]) > 0)
+        assert np.all(np.diff(tag2[lo + n_left:hi]) > 0)
+
+
+# split leaves' spans on a 16-block table of 256 rows, and the blocks
+# the mover is handed
+TOUCHED_CASES = {
+    # one leaf inside block 2, one over blocks 5-7
+    "inside_and_across": ([600, 1300], [100, 600], 4),
+    # two leaves share block 1: it is handed once
+    "shared_block": ([300, 400], [100, 200], 2),
+    # the root: every block
+    "root": ([0], [4096], 16),
+    # a lane that splits nothing hands nothing
+    "empty_lane": ([0, 2048], [0, 256], 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TOUCHED_CASES))
+def test_rows_moved_counts_touched_blocks(case):
+    """``partition.rows_moved`` counts the rows of the blocks the mover
+    is handed, a trip: the blocks that hold a row of a split leaf, each
+    once, times the mover's row block."""
+    offs, cnts, want = TOUCHED_CASES[case]
+    n, R = 4096, 256
+    moved = np.zeros(n, bool)
+    moved[1::3] = True
+    plan = part_ops.plan_split_move(
+        jnp.asarray(moved), jnp.asarray(offs, jnp.int32),
+        jnp.asarray(cnts, jnp.int32), R)
+    assert int(plan.touched) == want
+    # the histogram's own block lister agrees
+    _, nb = part_ops.span_blocks(jnp.asarray(offs, jnp.int32),
+                                 jnp.asarray(cnts, jnp.int32), R,
+                                 n // R, n // R)
+    assert int(nb) == want
+    # a piece a (block, split leaf), so a shared block is walked twice
+    live = np.asarray(plan.span) >= 0
+    assert live.sum() == sum((o + c - 1) // R - o // R + 1
+                             for o, c in zip(offs, cnts) if c)
+
+
+# (n, R, trips, Kb, seed, right share): a layout a few trips deep, then
+# the trip the kernels move
+KERNEL_CASES = {
+    "root": (2048, 256, 0, 1, 0, None),
+    "deep": (4096, 256, 3, 4, 1, None),
+    "small_leaves": (2048, 128, 4, 6, 2, None),
+    "all_right": (2048, 256, 2, 3, 3, 1.0),
+    "all_left": (2048, 256, 2, 3, 4, 0.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_move_cols_kernel_matches_xla(case):
+    """The two ``partition_move`` passes walking the plan's pieces
+    (``compact_rows(..., blocks=)``), with their blend, leave the buffer
+    the XLA path's permutation leaves, bit for bit: int8 bins, float32
+    values over 40 binades and an int32 leaf channel. In interpret mode
+    on the CPU, compiled in TPU mode (LGBM_TPU_TESTS=1)."""
+    import contextlib
+    import os
+    from jax.experimental.pallas import tpu as pltpu
+    n, R, trips, Kb, seed, share = KERNEL_CASES[case]
+    rng, leaf, off, cnt, _, num_leaves = _layout(seed, n=n, L=31,
+                                                 trips=trips, Kb=Kb)
+    parents, new_ids, new_leaf = _random_trip(rng, leaf, cnt, num_leaves,
+                                              31, Kb)
+    if share is not None:
+        new_leaf = leaf.copy()
+        for p, nid in zip(parents, new_ids):
+            new_leaf[leaf == p] = nid if share else p
+    # a lane that splits nothing rides along
+    parents = np.append(parents, 31).astype(np.int32)
+    valid = np.append(np.ones(len(parents) - 1, bool), False)
+    plan = _plan(leaf, new_leaf, off, cnt, parents, valid, R)
+    bins = rng.integers(-128, 128, size=(16, n)).astype(np.int8)
+    vals = (rng.normal(size=(3, n))
+            * 10.0 ** rng.integers(-20, 20, size=(3, n))).astype(np.float32)
+    vals = np.concatenate([vals, new_leaf[None].astype(np.float32)])
+    tpu = os.environ.get("LGBM_TPU_TESTS", "") == "1"
+    with (contextlib.nullcontext() if tpu
+          else pltpu.force_tpu_interpret_mode()):
+        kb, kv = part_ops.move_cols_tpu(jnp.asarray(bins), jnp.asarray(vals),
+                                        plan, R)
+    xb, xv = part_ops.move_rows_xla(
+        [jnp.asarray(bins), jnp.asarray(vals)],
+        part_ops.row_dest(plan, R), axis=1)
+    np.testing.assert_array_equal(np.asarray(kb), np.asarray(xb))
+    np.testing.assert_array_equal(np.asarray(kv), np.asarray(xv))
+    order = _np_reference_move(leaf, new_leaf, parents[valid])
+    np.testing.assert_array_equal(np.asarray(kb), bins[:, order])
+
+
+def test_grow_tree_rows_moved_is_blocks_handed():
+    """The grower's ``move_rows``: whole blocks of the mover's row
+    block, fewer than the whole table a trip once the tree has leaves
+    the trips do not split."""
+    from lightgbm_tpu.learner.serial import GrowConfig, grow_tree
+    n, f = 4096, 6
+    rng = np.random.default_rng(2)
+    bins = jnp.asarray(rng.integers(0, 32, size=(n, f)), jnp.uint8)
+    vals = jnp.asarray(np.stack([rng.normal(size=n), np.ones(n),
+                                 np.ones(n)], 1), jnp.float32)
+    cfg = GrowConfig(num_leaves=31, num_bins=32, rows_per_block=256,
+                     leaf_batch=2, min_data_in_leaf=5, partition=True,
+                     part_rpb=256)
+    t, _ = grow_tree(bins, vals, jnp.full(f, 32, jnp.int32),
+                     jnp.zeros(f, bool), jnp.ones(f, bool), cfg)
+    moves = float(t["move_calls"])
+    rows = float(t["move_rows"])
+    assert moves == float(t["hist_calls"]) - 1 > 2
+    assert rows % 256 == 0
+    assert n <= rows < moves * n
 
 
 def _random_spans(seed, n=4096, R=256, K=6):
